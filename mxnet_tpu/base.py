@@ -22,6 +22,8 @@ __all__ = [
     "config",
     "register_config",
     "get_env",
+    "compile_cache_dir",
+    "enable_compile_cache",
     "string_types",
     "numeric_types",
     "integer_types",
@@ -205,6 +207,33 @@ register_config("MXNET_RESILIENCE_STEP_DEADLINE", 0.0, float,
                 "Seconds a single ResilientTrainer step may take before the "
                 "watchdog dumps all thread stacks and fails loud "
                 "(0 = watchdog off).")
+
+
+# ---- persistent compilation cache: the one rule ---------------------------
+# The directory is part of the cache key, so it must never move. When
+# JAX_COMPILATION_CACHE_DIR is set jax reads it itself and no directory is
+# set in code; otherwise the cache lives at one fixed path inside the
+# checkout (git-ignored) — never one built from a temporary name, pid or
+# time. chip_smoke.py, bench.py and the tools all come through here; no
+# other site names a cache directory.
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def compile_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives under the rule
+    above."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent compilation cache on under the rule above and
+    return its directory. Call before the first compile."""
+    path = compile_cache_dir()
+    if path == _CHECKOUT_CACHE:     # the variable, when set, is read by jax
+        import jax
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 class classproperty:  # noqa: N801  (descriptor, lowercase by convention)

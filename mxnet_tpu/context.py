@@ -128,8 +128,11 @@ def _devices_of(platform: str):
 
 
 def _accelerator_devices():
-    devs = [d for d in jax.local_devices() if d.platform != "cpu"]
-    return devs or _devices_of("cpu")
+    """This process's non-CPU devices; empty on a host with no chip — an
+    accelerator context then raises (``Context.jax_device``), like the
+    reference's ``mx.gpu()`` on a GPU-less build, and is never served by
+    the host CPU."""
+    return [d for d in jax.local_devices() if d.platform != "cpu"]
 
 
 def cpu(device_id: int = 0) -> Context:
@@ -151,7 +154,7 @@ def cpu_pinned(device_id: int = 0) -> Context:
 
 
 def num_gpus() -> int:
-    return len([d for d in jax.local_devices() if d.platform != "cpu"])
+    return len(_accelerator_devices())
 
 
 def num_tpus() -> int:
@@ -175,7 +178,6 @@ def current_context() -> Context:
         # default to the accelerator if one exists, else cpu — unlike the
         # reference (default cpu), because on a TPU host that is always what
         # the user means; tests pin JAX_PLATFORMS=cpu so this stays cpu there.
-        accel = [d for d in jax.local_devices() if d.platform != "cpu"]
-        ctx = Context("tpu", 0) if accel else Context("cpu", 0)
+        ctx = Context("tpu", 0) if _accelerator_devices() else Context("cpu", 0)
         Context._default_ctx.value = ctx
     return ctx

@@ -338,7 +338,7 @@ class KVStoreDist(KVStore):
             return super()._send_command_to_servers(head, body)
         client = _dist_client()
         import json as _json
-        seq = _kv_increment(client, "mxtpu_cmd_seq", 1)
+        seq = int(client.key_value_increment("mxtpu_cmd_seq", 1))
         client.key_value_set("mxtpu_cmd/%d" % seq,
                              _json.dumps([int(head), str(body)]),
                              allow_overwrite=True)
@@ -663,7 +663,7 @@ class KVStoreDist(KVStore):
                 order.append(k)
         bound = int(get_env("MXNET_KVSTORE_ASYNC_MAX_STALENESS", 0))
         for k in order:
-            seq = _kv_increment(client, self._as_key("seq", k), 1)
+            seq = int(client.key_value_increment(self._as_key("seq", k), 1))
             client.key_value_set_bytes(self._as_key("push", k, seq),
                                        self._encode_push(k, merged[k]))
             self.comm_stats["bucket_reduces"] += 1
@@ -678,11 +678,7 @@ class KVStoreDist(KVStore):
                 deadline = time.time() + timeout
                 done = 0
                 while True:
-                    try:
-                        done = int(_kv_try_get(client,
-                                               self._as_key("done", k)))
-                    except Exception:
-                        done = 0
+                    done = _kv_counter_read(client, self._as_key("done", k))
                     if seq - done <= bound:
                         break
                     if time.time() >= deadline:
@@ -760,7 +756,7 @@ class KVStoreDist(KVStore):
         dead = 0
         for i in ids:
             try:
-                ts = float(_kv_try_get(client, "mxtpu_hb/%d" % i))
+                ts = float(client.key_value_try_get("mxtpu_hb/%d" % i))
             except Exception:
                 ts = None
             if ts is None or now - ts > timeout:
@@ -964,74 +960,14 @@ def _dist_client():
     return getattr(_jdist.global_state, "client", None)
 
 
-# ---- coordination-client compatibility shims (jax 0.4.x) --------------
-# Newer jaxlib clients grow ``key_value_try_get`` and
-# ``key_value_increment``; the 0.4.x client has neither. Everything the
-# kvstore needs from them is expressible over the 0.4.x primitives, so
-# these shims keep one code path: native method when present, emulation
-# otherwise. Call sites treat any raise as "key absent" — same contract
-# as the real ``try_get``.
-
-def _kv_try_get(client, key: str, timeout_ms: int = 100):
-    """Non-blocking-ish read of ``key``; raises when absent. Emulated
-    with a short bounded blocking get (DEADLINE_EXCEEDED == absent)."""
-    fn = getattr(client, "key_value_try_get", None)
-    if fn is not None:
-        return fn(key)
-    return client.blocking_key_value_get(key, timeout_ms)
-
-
 def _kv_counter_read(client, key: str) -> int:
-    """Current value of a ``_kv_increment`` counter, 0 when never bumped.
-    The emulated counter's authoritative value is its number of claim
-    slots (dense 1..n by construction — every claimer scans upward from
-    the first unclaimed slot), not the plain key, which is only a
-    best-effort high-water cache."""
-    if hasattr(client, "key_value_increment"):
-        try:
-            return int(_kv_try_get(client, key))
-        except Exception:
-            return 0
+    """Current integer value of ``key`` (a ``key_value_increment`` counter
+    or a plainly-set high-water mark), 0 when it was never written
+    (``key_value_try_get`` raises on an absent key)."""
     try:
-        return len(client.key_value_dir_get(key + "/claim/"))
+        return int(client.key_value_try_get(key))
     except Exception:
         return 0
-
-
-_kv_incr_hints: Dict[str, int] = {}
-
-
-def _kv_increment(client, key: str, amount: int = 1) -> int:
-    """Atomic fetch-add returning the post-increment value (first call
-    returns ``amount``). Emulation: ``key_value_set(...,
-    allow_overwrite=False)`` is a cluster-wide compare-and-swap — exactly
-    one process wins each ``<key>/claim/<n>`` slot, and the slot number
-    it wins IS its ticket. A process-local hint plus the claim count
-    seed the scan so it stays O(contenders), not O(history)."""
-    fn = getattr(client, "key_value_increment", None)
-    if fn is not None:
-        return int(fn(key, amount))
-    if amount != 1:
-        raise MXNetError("emulated key_value_increment supports "
-                         "amount=1 only (got %r)" % (amount,))
-    n = max(_kv_incr_hints.get(key, 0), _kv_counter_read(client, key)) + 1
-    while True:
-        try:
-            client.key_value_set("%s/claim/%d" % (key, n), "1",
-                                 allow_overwrite=False)
-            break
-        except Exception as e:
-            if "ALREADY_EXISTS" not in str(e):
-                raise       # real coordination failure, not a lost race
-            n += 1
-    _kv_incr_hints[key] = n
-    try:
-        # best-effort high-water cache for native-API readers; the
-        # emulated reader counts claim slots and never trusts this key
-        client.key_value_set(key, str(n), allow_overwrite=True)
-    except Exception:
-        pass
-    return n
 
 
 _cluster_joined = False

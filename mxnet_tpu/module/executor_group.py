@@ -12,7 +12,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional
 
 from .. import ndarray as nd
-from ..base import MXNetError
+from ..base import MXNetError, logger as _logger
 from ..ndarray import NDArray
 
 __all__ = ["DataParallelExecutorGroup"]
@@ -22,7 +22,7 @@ class DataParallelExecutorGroup:
     def __init__(self, symbol, contexts, workload, data_shapes, label_shapes,
                  param_names, for_training, inputs_need_grad, shared_group=None,
                  logger=None, fixed_param_names=None, grad_req="write",
-                 state_names=None, group2ctx=None):
+                 state_names=None, group2ctx=None, param_shapes=None):
         self.symbol = symbol
         self.contexts = contexts
         self.param_names = param_names
@@ -49,6 +49,13 @@ class DataParallelExecutorGroup:
             shapes.update({l.name: l.shape for l in label_shapes})
         shared_exec = shared_group.execs[0] if shared_group is not None else None
         ctx = contexts[0]
+        if len(contexts) > 1:
+            # said aloud, not hidden: a reference script's --gpus 0,1,2,3
+            # trains on ONE device here
+            _logger.warning(
+                "Module binds one executor on %s; the other %d context(s) "
+                "are not used. Multi-device data parallelism is "
+                "parallel.DataParallelTrainer.", ctx, len(contexts) - 1)
         if shared_exec is not None:
             # bucketing: share argument arrays with the largest-bucket
             # executor; group2ctx rides along so every bucket keeps the
@@ -69,8 +76,12 @@ class DataParallelExecutorGroup:
                         tuple(exec_.arg_dict[name].shape) != tuple(shape):
                     exec_.arg_dict[name] = nd.zeros(shape, ctx=ctx)
         else:
+            # param_shapes: shapes Module inferred on the un-rewritten graph
+            known = {k: v for k, v in (param_shapes or {}).items()
+                     if v is not None}
+            known.update(shapes)
             ex = symbol.simple_bind(ctx, grad_req=self.grad_req,
-                                    group2ctx=group2ctx, **shapes)
+                                    group2ctx=group2ctx, **known)
             exec_ = ex
         self.execs = [exec_]
 

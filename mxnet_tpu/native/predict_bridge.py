@@ -20,6 +20,7 @@ import tempfile
 import threading
 from typing import Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from ..analysis.lockwatch import make_rlock
@@ -50,6 +51,14 @@ def _load_param_bytes(param_bytes: bytes):
         else:
             arg[k] = v
     return arg, aux
+
+
+def device_context(dev_type: int, dev_id: int):
+    """The Context behind the C ABI's device codes: dev_type 1 = cpu
+    (reference c_predict_api.h:66); anything else = the accelerator (TPU
+    here, GPU there)."""
+    from ..context import Context
+    return Context("cpu" if dev_type == 1 else "tpu", dev_id)
 
 
 class Predictor:
@@ -88,10 +97,12 @@ class Predictor:
                 chosen.append(internals[name])
             sym = sym_mod.Group(chosen) if len(chosen) > 1 else chosen[0]
         self._sym = sym
-        # dev_type 1=cpu (reference c_predict_api.h:66); anything else =
-        # the accelerator (TPU here, GPU there)
-        ctx = mx.cpu(dev_id) if dev_type == 1 else mx.context.tpu(dev_id)
+        ctx = device_context(dev_type, dev_id)
         self._ctx = ctx
+        # the executor runs where its arrays live: inputs, parameters and
+        # aux states are all committed to ctx's device (an accelerator
+        # context with no accelerator raises here, it is never the host)
+        self._dev = ctx.jax_device()
         arg_params, aux_params = _load_param_bytes(param_bytes)
 
         self._input_names = list(input_shapes)
@@ -99,15 +110,15 @@ class Predictor:
         for name in sym.list_arguments():
             if name in input_shapes:
                 args[name] = mx.nd.zeros(tuple(int(x) for x in
-                                               input_shapes[name]))
+                                               input_shapes[name]), ctx=ctx)
             elif name in arg_params:
-                args[name] = arg_params[name]
+                args[name] = arg_params[name].as_in_context(ctx)
         missing = [n for n in sym.list_arguments()
                    if n not in args]
         if missing:
             raise ValueError(f"missing parameters for arguments: {missing}")
-        aux = {n: aux_params[n] for n in sym.list_auxiliary_states()
-               if n in aux_params}
+        aux = {n: aux_params[n].as_in_context(ctx)
+               for n in sym.list_auxiliary_states() if n in aux_params}
         self._aux = aux
         self._exec = sym.bind(ctx, args, aux_states=aux if aux else None)
         self._args = args
@@ -124,7 +135,7 @@ class Predictor:
         with self._lock:
             if name not in self._args:
                 raise ValueError(f"unknown input {name!r}")
-            self._args[name]._set_data(arr)
+            self._args[name]._set_data(jax.device_put(arr, self._dev))
             self._outputs = None
 
     def set_input_flat(self, name: str, data: bytes, size: int):
@@ -179,7 +190,7 @@ class Predictor:
                     raise ValueError(
                         f"input {name!r}: shape {tuple(a.shape)} does not "
                         f"match bound shape {bound}")
-                self._args[name]._set_data(a)
+                self._args[name]._set_data(jax.device_put(a, self._dev))
             self._outputs = self._exec.forward(is_train=False)
             # the atomic set->forward->read sequence is this method's
             # whole point; the sync must happen under the handle lock
@@ -197,7 +208,7 @@ class Predictor:
             import mxnet_tpu as mx
             args = dict(self._args)
             for n, s in shapes.items():
-                args[n] = mx.nd.zeros(s)
+                args[n] = mx.nd.zeros(s, ctx=self._ctx)
             clone._args = args
             clone._exec = self._sym.bind(
                 self._ctx, args, aux_states=self._aux if self._aux else None)
@@ -396,10 +407,9 @@ class CExecutor:
     def __init__(self, symbol_json: str, dev_type: int, dev_id: int,
                  input_shapes: Dict[str, Sequence[int]],
                  grad_req: str = "write"):
-        import mxnet_tpu as mx
         from .. import symbol as sym_mod
         sym = sym_mod.load_json(symbol_json)
-        ctx = mx.cpu(dev_id) if dev_type == 1 else mx.context.tpu(dev_id)
+        ctx = device_context(dev_type, dev_id)
         shapes = {k: tuple(int(x) for x in v)
                   for k, v in input_shapes.items()}
         self._exec = sym.simple_bind(ctx, grad_req=grad_req, **shapes)

@@ -64,8 +64,8 @@ register_config("MXNET_MEM_CAPTURE", True, bool,
                 "Attach XLA memory_analysis to lazy-path cost-ledger rows. "
                 "Costs one extra host-side analysis compile per executable "
                 "signature (the compiled program actually dispatched is "
-                "untouched); set 0 on remote-compile tunnels where a "
-                "second compile is minutes, not milliseconds.")
+                "untouched); set 0 where a second compile is minutes, "
+                "not milliseconds.")
 register_config("MXNET_OOM_DIR", "", str,
                 "Directory the mxtpu_oom.json OOM postmortem artifact is "
                 "written to. Empty = current working directory.")

@@ -1,8 +1,7 @@
 """Perf-regression watchdog — compare live/fresh perf facts to baselines.
 
-The bench history (``bench_cache.json``, ``BENCH_*.json``) is the repo's
-measured ground truth; this module turns it into an *enforced* floor
-instead of a number nobody re-reads. Three inputs normalize into one
+A measured bench row is ground truth; this module turns one into an
+*enforced* floor instead of a number nobody re-reads. Three inputs normalize into one
 comparable shape:
 
 - a **bench row** (``{"metric": ..., "value": ...}``) → throughput, mfu,
@@ -40,8 +39,8 @@ __all__ = ["METRIC_DIRECTIONS", "DEFAULT_THRESHOLD_PCT", "normalize",
 
 register_config("MXNET_PERF_BASELINE", "", str,
                 "Default baseline artifact for the perf watchdog (a bench "
-                "row / BENCH_*.json / ledger row). Empty = the repo's "
-                "bench_cache.json.")
+                "row / ledger row). Empty = no default: the watch is "
+                "disarmed unless a baseline is passed.")
 
 # metric -> +1 (higher is better) / -1 (lower is better)
 METRIC_DIRECTIONS: Dict[str, int] = {
@@ -82,14 +81,8 @@ METRIC_DIRECTIONS: Dict[str, int] = {
 DEFAULT_THRESHOLD_PCT = 10.0
 
 
-def _repo_root() -> str:
-    return os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-
-
 def default_baseline_path() -> str:
-    return str(get_env("MXNET_PERF_BASELINE", "") or
-               os.path.join(_repo_root(), "bench_cache.json"))
+    return str(get_env("MXNET_PERF_BASELINE", "") or "")
 
 
 def normalize(doc: Any, source: str = "") -> Optional[Dict[str, Any]]:
@@ -98,7 +91,7 @@ def normalize(doc: Any, source: str = "") -> Optional[Dict[str, Any]]:
     if not isinstance(doc, dict):
         return None
     if "parsed" in doc and isinstance(doc["parsed"], dict):
-        # BENCH_rNN.json wrapper: the driver's parsed final row
+        # driver wrapper: the parsed final row of a bench run
         return normalize(doc["parsed"], source=source)
     if "metrics" in doc and isinstance(doc["metrics"], dict):
         vals: Dict[str, float] = {}
@@ -331,12 +324,11 @@ class PerfWatch:
 
     >>> rt = ResilientTrainer(..., perfwatch={"check_every": 200})
     # every 200 steps the live mxtpu_mfu / samples_per_sec gauges are
-    # compared against bench_cache.json; a breach logs a warning and
+    # compared against the baseline; a breach logs a warning and
     # bumps mxtpu_perf_regressions_total{metric=}.
 
-    ``baseline`` may be a path (bench row / BENCH_*.json / ledger), an
-    already-normalized dict, or None for the default
-    (``MXNET_PERF_BASELINE`` env, else the repo's bench_cache.json). A
+    ``baseline`` may be a path (bench row / ledger), an
+    already-normalized dict, or None for the ``MXNET_PERF_BASELINE`` env. A
     missing baseline disarms the watch with one warning — never an error:
     a fresh clone without bench history must still train.
     """

@@ -24,6 +24,12 @@ backend (override off with ``MXTPU_PALLAS=0``); on CPU the same kernels run
 under the Pallas interpreter when ``MXTPU_PALLAS_INTERPRET=1`` (the unit-test
 path — tests/conftest.py pins the CPU backend), else a pure-jnp reference
 path runs. All three paths share one numerics contract and one test suite.
+
+The package turns ``jax_enable_x64`` on, under which a bare Python ``0`` in a
+BlockSpec index map traces as i64 and Mosaic refuses the kernel. Every
+integer an index map returns is therefore spelled ``_I0`` / built from the
+i32 grid indices; tests/test_chip_bringup.py compiles both kernels for the
+described v5e with the package imported.
 """
 from __future__ import annotations
 
@@ -33,6 +39,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -41,6 +48,7 @@ __all__ = ["flash_attention", "flash_attention_with_lse",
            "softmax_cross_entropy", "use_pallas"]
 
 _NEG_INF = -1e30  # avoid actual -inf inside kernels (exp/max corner cases)
+_I0 = np.int32(0)  # index-map zero: a Python 0 is i64 under jax_enable_x64
 
 
 def _interpret() -> bool:
@@ -128,10 +136,7 @@ def _fa_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _vma_kw(x):
     """Propagate shard_map varying-axes type onto pallas out_shape (jax vma)."""
-    try:
-        vma = jax.typeof(x).vma
-    except Exception:
-        return {}
+    vma = jax.typeof(x).vma
     return {"vma": vma} if vma else {}
 
 
@@ -149,14 +154,14 @@ def _fa_pallas(q, k, v, scale, causal, q_offset, k_offset,
         num_scalar_prefetch=1,   # offs (q/k global offsets) land in SMEM
         grid=(BH, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, iq, ik, offs: (b, iq, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, iq, ik, offs: (b, ik, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, iq, ik, offs: (b, ik, 0)),
+            pl.BlockSpec((1, block_q, D), lambda b, iq, ik, offs: (b, iq, _I0)),
+            pl.BlockSpec((1, block_k, D), lambda b, iq, ik, offs: (b, ik, _I0)),
+            pl.BlockSpec((1, block_k, D), lambda b, iq, ik, offs: (b, ik, _I0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, iq, ik, offs: (b, iq, 0)),
+            pl.BlockSpec((1, block_q, D), lambda b, iq, ik, offs: (b, iq, _I0)),
             pl.BlockSpec((1, block_q, 128),
-                         lambda b, iq, ik, offs: (b, iq, 0)),
+                         lambda b, iq, ik, offs: (b, iq, _I0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
@@ -196,6 +201,11 @@ def _fa_reference(q, k, v, scale, causal, q_offset, k_offset):
 
 
 def _fa_fwd_dispatch(q, k, v, scale, causal, q_offset, k_offset):
+    """The tiling rule: the Pallas kernel takes head dimensions that are a
+    multiple of 128 and sequence lengths that are a multiple of 8; every
+    other shape takes the jnp reference (same numerics, T x T scores in
+    HBM). Callers that must know which ran check the lowered text for
+    ``tpu_custom_call``, as chip_smoke.py does."""
     D = q.shape[-1]
     tile_ok = D % 128 == 0 and q.shape[1] % 8 == 0 and k.shape[1] % 8 == 0
     # the pallas *interpreter* can't run inside a vma-checked shard_map
@@ -287,7 +297,9 @@ def flash_attention(q, k, v, causal: bool = False,
     """Memory-efficient attention. q,k,v: (B, H, T, D) → (B, H, Tq, D).
 
     Differentiable (custom VJP, blockwise backward). On TPU the forward is a
-    Pallas kernel; elsewhere a jnp reference path with identical numerics.
+    Pallas kernel where the shape tiles (D % 128 == 0, T % 8 == 0, see
+    ``_fa_fwd_dispatch``); elsewhere a jnp reference path with identical
+    numerics.
     """
     B, H, Tq, Dh = q.shape
     sc = scale if scale is not None else 1.0 / (Dh ** 0.5)
@@ -322,15 +334,62 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
 # fused softmax cross-entropy
 # ---------------------------------------------------------------------------
 
-def _ce_kernel(logits_ref, lse_ref):
-    # labels stay OUTSIDE the kernel: a (bn, 1) int32 tile is a shape Mosaic
-    # may refuse to legalize, and the label gather is a cheap XLA gather the
-    # compiler fuses with the subtraction anyway. Only the reduction that
-    # would otherwise materialize softmax lives here.
-    x = logits_ref[...].astype(jnp.float32)              # (bn, C)
-    m = jnp.max(x, axis=1, keepdims=True)
-    lse = jnp.log(jnp.sum(jnp.exp(x - m), axis=1, keepdims=True)) + m
-    lse_ref[...] = jnp.broadcast_to(lse, lse_ref.shape)
+_CE_BLOCK_N = 256    # rows per block
+_CE_BLOCK_C = 2048   # classes per block: a (256, 2048) f32 working tile is
+#                      2 MiB, so the double-buffered input plus the f32
+#                      temporaries stay well inside the 16 MiB scoped-VMEM
+#                      limit at any vocabulary width
+
+
+def _ce_kernel(logits_ref, lse_ref, m_ref, l_ref, *, block_c, n_classes,
+               nc_total):
+    """Grid (nN, nC); the class axis is innermost (sequential). Scratch
+    (m, l) carries the running row max / rescaled exp-sum across class
+    blocks; the row log-sum-exp is written at the last class step.
+
+    Labels stay OUTSIDE the kernel: a (bn, 1) int32 tile is a shape Mosaic
+    may refuse to legalize, and the label gather is a cheap XLA gather the
+    compiler fuses with the subtraction anyway. Only the reduction that
+    would otherwise materialize softmax lives here."""
+    ic = pl.program_id(1)
+    neg_inf = jnp.float32(_NEG_INF)
+
+    @pl.when(ic == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, neg_inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    x = logits_ref[...].astype(jnp.float32)              # (bn, bc)
+    if n_classes % block_c:
+        # mask the ragged tail of the class axis (grid pads the last block)
+        col = lax.broadcasted_iota(jnp.int32, x.shape, 1) + ic * block_c
+        x = jnp.where(col < n_classes, x, neg_inf)
+    m_prev = m_ref[...]                                  # (bn, 128)
+    m_new = jnp.maximum(m_prev, jnp.max(x, axis=1, keepdims=True))
+    l_ref[...] = l_ref[...] * jnp.exp(m_prev - m_new) \
+        + jnp.sum(jnp.exp(x - m_new[:, :1]), axis=1, keepdims=True)
+    m_ref[...] = m_new
+
+    @pl.when(ic == nc_total - 1)
+    def _finalize():
+        lse_ref[...] = m_ref[...] + jnp.log(l_ref[...])
+
+
+def _ce_lse_pallas(logits):
+    """Row log-sum-exp of (N, C) logits via pallas_call → (N,) float32."""
+    N, C = logits.shape
+    bn, bc = min(_CE_BLOCK_N, N), min(_CE_BLOCK_C, C)
+    nc = pl.cdiv(C, bc)
+    return pl.pallas_call(
+        functools.partial(_ce_kernel, block_c=bc, n_classes=C, nc_total=nc),
+        grid=(pl.cdiv(N, bn), nc),
+        in_specs=[pl.BlockSpec((bn, bc), lambda i, j: (i, j))],
+        out_specs=pl.BlockSpec((bn, 128), lambda i, j: (i, _I0)),
+        out_shape=jax.ShapeDtypeStruct((N, 128), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bn, 128), jnp.float32),
+                        pltpu.VMEM((bn, 128), jnp.float32)],
+        interpret=_interpret(),
+    )(logits)[:, 0]
 
 
 @jax.custom_vjp
@@ -347,24 +406,12 @@ def _ce_fwd(logits, labels):
     N, C = logits.shape
     labels = labels.astype(jnp.int32)
     if use_pallas() and C % 128 == 0 and N % 8 == 0:
-        bn = min(256, N)
-        lse = pl.pallas_call(
-            _ce_kernel,
-            grid=(pl.cdiv(N, bn),),
-            in_specs=[pl.BlockSpec((bn, C), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((bn, 128), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((N, 128), jnp.float32),
-            interpret=_interpret(),
-        )(logits)[:, 0]
-        picked = jnp.take_along_axis(
-            logits.astype(jnp.float32), labels[:, None], axis=1)[:, 0]
-        loss = lse - picked
+        lse = _ce_lse_pallas(logits)
     else:
-        x = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(x, axis=1)
-        picked = jnp.take_along_axis(x, labels[:, None], axis=1)[:, 0]
-        loss = lse - picked
-    return loss, (logits, labels)
+        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=1)
+    picked = jnp.take_along_axis(
+        logits, labels[:, None], axis=1)[:, 0].astype(jnp.float32)
+    return lse - picked, (logits, labels)
 
 
 def _ce_bwd(res, g):

@@ -29,19 +29,9 @@ def _match_vma(state, ref):
     set as values derived from the inputs; a replicated initial state meeting
     a device-varying input projection (the pipeline-parallel case) needs an
     explicit pvary or the scan type check rejects it."""
-    try:
-        typeof = getattr(jax, "typeof", None)
-        if typeof is None:
-            typeof = jax.core.get_aval
-        want = typeof(ref).vma
-        have = typeof(state).vma
-        extra = tuple(sorted(want - have))
-        if extra:
-            if hasattr(lax, "pcast"):
-                return lax.pcast(state, extra, to="varying")
-            return lax.pvary(state, extra)
-    except (AttributeError, TypeError):
-        pass
+    extra = tuple(sorted(jax.typeof(ref).vma - jax.typeof(state).vma))
+    if extra:
+        return lax.pcast(state, extra, to="varying")
     return state
 
 

@@ -25,7 +25,7 @@ scatter / all-gather ``(n-1)/n``, ppermute ``1x`` of the payload) divided
 by wall time — the unit NCCL/collective benchmarks report, so numbers
 compare across device counts.
 
-CLI: ``tools/collbench.py`` (tunnel-session registered). Docs:
+CLI: ``tools/collbench.py``. Docs:
 ``docs/performance.md`` "Scale-out performance".
 """
 from __future__ import annotations
@@ -37,10 +37,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError, logger

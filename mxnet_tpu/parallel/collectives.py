@@ -18,10 +18,7 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..observability import catalog as _telemetry
@@ -82,10 +79,10 @@ def all_to_all(x, axis_name: str, split_axis: int, concat_axis: int):
 
 # ---- coordination-service fallback ----------------------------------------
 # XLA cross-process collectives need backend support (TPU ICI/DCN, or a
-# CPU/GPU build with cross-host collectives). jax 0.4.x's CPU backend has
-# none — every multiprocess computation raises "Multiprocess computations
-# aren't implemented on the CPU backend" — yet the dist kvstore must still
-# work there (tests/test_dist.py runs real multi-process clusters on CPU).
+# CPU/GPU build with cross-host collectives). Where the backend has none —
+# a multiprocess computation raises "Multiprocess computations aren't
+# implemented on the CPU backend" — the dist kvstore must still work
+# (tests/test_dist.py runs real multi-process clusters on CPU).
 # The coordination service (already joined for barriers/heartbeats) is a
 # correct, slow wire: each rank publishes its host array under a
 # round-numbered key and reads every peer's. Used only when the XLA path

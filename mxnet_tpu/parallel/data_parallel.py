@@ -59,6 +59,12 @@ def sgd_momentum_update(params, grads, state, lr, momentum=0.9, wd=0.0):
     return new_params, new_state
 
 
+@jax.jit
+def _copy_tree(tree):
+    """Every leaf copied into a buffer of its own (same placement)."""
+    return jax.tree_util.tree_map(jnp.copy, tree)
+
+
 def _shape_key(arrays):
     """Exact (shape, dtype) signature of a batch — the unit the AOT
     executable is keyed to, shared by _aot_key/aot_save/aot_load/step."""
@@ -456,7 +462,10 @@ class DataParallelTrainer:
         into the captured layout (sync_to_net applies the inverse)."""
         if self._pass_result is None or \
                 name not in self._pass_result.var_transforms:
-            return value
+            # a copy, never the net's own buffer: the step donates its
+            # state, and placing an array on a mesh that holds its device
+            # aliases it, so the first step would delete the net's value
+            return jax.device_put(value, may_alias=False)
         return jnp.asarray(
             self._pass_result.transform_var(name, jax.device_get(value)))
 
@@ -694,6 +703,11 @@ class DataParallelTrainer:
                                 out_shardings=out_shardings,
                                 donate_argnums=donate)
         self._n_inputs = n_inputs
+        # the first step must see its state where every later step does (on
+        # the mesh, as the step's own outputs are): state still sitting on
+        # the net's device has another type to jit, and the whole step would
+        # be traced and compiled a second time at step two
+        self._place_state()
 
         if self._kv is not None:
             # with a scaler, grad_step takes the live scale as an extra
@@ -773,8 +787,7 @@ class DataParallelTrainer:
     # The compiled fused step can be serialized and reloaded by a LATER
     # process, skipping XLA compilation entirely (the reference's analogue
     # is the cuDNN algo registry persisting autotune results; here we keep
-    # the whole executable). Critical on remote-compile backends where the
-    # ResNet-50 step takes minutes to compile.
+    # the whole executable).
     def _aot_key(self, arrays):
         import jax as _jax
         dev = self._mesh.devices.ravel()[0]
@@ -866,8 +879,8 @@ class DataParallelTrainer:
         the jit path) if the blob is missing or its key does not match.
 
         Trust boundary: the blob is unpickled BEFORE the digest check, so
-        ``path`` must point at a cache this process itself wrote (e.g.
-        ``.bench_aot/`` under the repo) — never at untrusted bytes. An
+        ``path`` must point at a cache this process itself wrote — never
+        at untrusted bytes. An
         attacker who can write the cache file can already write the code
         that loads it, so the boundary is the filesystem, not the format."""
         import os
@@ -896,8 +909,7 @@ class DataParallelTrainer:
             return False
         # strongest check: the blob must come from THIS lowered computation
         # (model graph + loss + baked constants), not merely one with the
-        # same shapes. Lowering is local tracing — seconds, not the
-        # minutes a remote compile costs.
+        # same shapes. Lowering is local tracing — seconds, not a compile.
         dataspec = NamedSharding(self._mesh, P(self._axis))
         placed = [jax.device_put(a, dataspec) for a in arrays]
         lowered = self._step_fn.lower(
@@ -1174,14 +1186,18 @@ class DataParallelTrainer:
                 return jnp.asarray(
                     self._pass_result.inverse_var(n, jax.device_get(v)))
             return v
+        # fresh buffers first: moving a mesh-placed array to a device of the
+        # mesh aliases it, and the next step's donation would then delete
+        # what the net was just given
+        params, aux = _copy_tree((self._params, self._aux))
         for n in self._param_names:
             home = self._pmap[n].list_ctx()[0].jax_device()
             self._pmap[n].data()._set_data(
-                jax.device_put(back(n, self._params[n]), home))
+                jax.device_put(back(n, params[n]), home))
         for n in self._aux_names:
             home = self._pmap[n].list_ctx()[0].jax_device()
             self._pmap[n].data()._set_data(
-                jax.device_put(back(n, self._aux[n]), home))
+                jax.device_put(back(n, aux[n]), home))
 
     def lint(self, *data, suppress=()) -> Any:
         """Trace-lint the fused step against a sample batch (mxlint trace

@@ -19,10 +19,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["pipeline_apply", "GluonPipelineStack", "HeterogeneousPipeline"]

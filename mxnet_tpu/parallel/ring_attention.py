@@ -24,10 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.pallas_kernels import (flash_attention, flash_attention_with_lse,
@@ -37,13 +34,7 @@ __all__ = ["ring_attention", "local_attention", "ring_attention_sharded"]
 
 
 def _pvary(x, axis_name):
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis_name,), to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, (axis_name,))
-    # jax < 0.5: no varying-axis type system inside shard_map — values are
-    # implicitly device-varying, so the cast is the identity
-    return x
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 def local_attention(q, k, v, causal: bool = False, scale: Optional[float] = None,

@@ -21,10 +21,7 @@ from typing import Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["column_parallel_dense", "row_parallel_dense", "tp_mlp",

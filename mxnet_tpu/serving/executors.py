@@ -50,6 +50,22 @@ def _device_kind() -> Tuple[Optional[str], Optional[str]]:
         return None, None
 
 
+def resolve_device(dev_type: Optional[int],
+                   dev_id: Optional[int]) -> Tuple[int, int]:
+    """``(dev_type, dev_id)`` in the predictor's C-ABI codes (1 = cpu,
+    2 = accelerator). ``dev_type=None`` follows
+    :func:`~mxnet_tpu.context.current_context` — the chip when the host has
+    one — so a server built with no device argument serves on the
+    accelerator; an explicit ``dev_type=1`` still means the host CPU."""
+    if dev_type is None:
+        from ..context import current_context
+        ctx = current_context()
+        dev_type = 1 if ctx.device_type.startswith("cpu") else 2
+        if dev_id is None:
+            dev_id = ctx.device_id
+    return int(dev_type), int(dev_id or 0)
+
+
 def default_buckets(model: Optional[str] = None) -> Tuple[Tuple[int, ...], str]:
     """The bucket ladder to serve with, plus its provenance string.
 
@@ -99,7 +115,8 @@ class BucketExecutorCache:
                  input_name: str = "data",
                  feature_shape: Sequence[int],
                  buckets: Sequence[int],
-                 dev_type: int = 1, dev_id: int = 0,
+                 dev_type: Optional[int] = None,
+                 dev_id: Optional[int] = None,
                  output_keys: Optional[List[str]] = None,
                  chips: int = 1, model: Optional[str] = None):
         if not buckets:
@@ -115,7 +132,7 @@ class BucketExecutorCache:
                              % (self.declared_buckets,))
         self._symbol_json = symbol_json
         self._param_bytes = param_bytes
-        self._dev = (int(dev_type), int(dev_id))
+        self._dev = resolve_device(dev_type, dev_id)
         self._output_keys = output_keys
         self._lock = make_lock("serving.executors.BucketExecutorCache._lock")
         self._preds: Dict[int, object] = {}
@@ -125,6 +142,12 @@ class BucketExecutorCache:
         self.buckets = self.declared_buckets
         if int(chips) != 1:
             self.rebind(int(chips))
+
+    @property
+    def device(self):
+        """The ``jax.Device`` every bucket's executor is committed to."""
+        from ..native.predict_bridge import device_context
+        return device_context(*self._dev).jax_device()
 
     @staticmethod
     def effective_buckets(declared: Sequence[int],

@@ -54,7 +54,8 @@ from .breaker import CircuitBreaker
 from .errors import (ChipQuarantined, CircuitOpen, DeadlineExceeded,
                      Draining, ExecutorFault, MemoryBudgetExceeded,
                      Overloaded, Preempted, QuotaExceeded, ServingError)
-from .executors import BucketExecutorCache, default_buckets
+from .executors import (BucketExecutorCache, default_buckets,
+                        resolve_device)
 from .queueing import BoundedRequestQueue, RetryBudget
 
 __all__ = ["ModelConfig", "ModelServer", "PendingResult"]
@@ -208,6 +209,9 @@ class ModelConfig:
     knobs default from the ``MXNET_SERVE_*`` environment; explicit
     ``max_queue=0`` or ``deadline_ms=0`` mean *unbounded* / *no default
     deadline* — both legal, both flagged by mxlint MXL-T214.
+    ``dev_type`` / ``dev_id`` left unset follow ``current_context()`` (the
+    chip when the host has one, see ``executors.resolve_device``);
+    ``dev_type=1`` pins the host CPU.
     """
 
     def __init__(self, name: str, symbol_json: str, param_bytes: bytes = b"",
@@ -219,7 +223,8 @@ class ModelConfig:
                  retries: Optional[int] = None,
                  breaker_threshold: Optional[int] = None,
                  breaker_cooldown_s: Optional[float] = None,
-                 dev_type: int = 1, dev_id: int = 0,
+                 dev_type: Optional[int] = None,
+                 dev_id: Optional[int] = None,
                  output_keys: Optional[List[str]] = None,
                  tier: Optional[str] = None,
                  trace: Optional[bool] = None,
@@ -291,7 +296,7 @@ class ModelConfig:
             raise MXNetError("retry_budget must be in [0, 1] (0 = no "
                              "budget; MXL-T219 flags it), got %r"
                              % (self.retry_budget,))
-        self.dev_type, self.dev_id = int(dev_type), int(dev_id)
+        self.dev_type, self.dev_id = resolve_device(dev_type, dev_id)
         self.output_keys = output_keys
 
 
@@ -1132,6 +1137,7 @@ class ModelServer:
                             "sample": st.cfg.trace_sample,
                             "ring_depth": self.tracer.depth},
                 "chips": st.cache.chips,
+                "device": str(st.cache.device),
                 "hedges": dict(st.hedges),
             }
         out["degraded_rung"] = st.ladder.rung if st.ladder is not None \

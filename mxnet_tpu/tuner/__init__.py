@@ -30,8 +30,7 @@ from . import space
 from . import tuner
 from .ladder import (DEFAULT_VARIANTS, SEED_VARIANTS, VariantSpec,
                      parse_variants, measure_step, run_ladder, run_variant,
-                     profile_step, hlo_audit, imperative_lab,
-                     register_session)
+                     profile_step, hlo_audit, imperative_lab)
 from .model import LinearCorrection, predict_step_ms, roofline_ms
 from .space import Candidate, SearchSpace
 from .tuner import (TRIAL_LABEL, Trial, TuneResult, best_cached,
@@ -41,7 +40,6 @@ __all__ = ["ladder", "model", "space", "tuner",
            "DEFAULT_VARIANTS", "SEED_VARIANTS", "VariantSpec",
            "parse_variants", "measure_step", "run_ladder", "run_variant",
            "profile_step", "hlo_audit", "imperative_lab",
-           "register_session",
            "LinearCorrection", "predict_step_ms", "roofline_ms",
            "Candidate", "SearchSpace",
            "TRIAL_LABEL", "Trial", "TuneResult", "best_cached",

@@ -2,13 +2,13 @@
 
 Mirrors the reference's CI strategy of simulating multi-device on one box
 (SURVEY.md §4.5: tools/launch.py local launcher → here
-xla_force_host_platform_device_count). The real-TPU bench path is exercised
-by bench.py, not the unit suite.
+xla_force_host_platform_device_count). The chip is exercised by
+chip_smoke.py, not the unit suite.
 """
 import os
 
 # MXTPU_REAL_TPU=1 keeps the real accelerator visible (used by
-# tests/tpu/test_parity.py on the bench machine); default CI forces the
+# tests/tpu/test_parity.py on the chip); default CI forces the
 # virtual CPU mesh.
 _REAL = os.environ.get("MXTPU_REAL_TPU") == "1"
 if not _REAL:

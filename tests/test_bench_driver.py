@@ -1,13 +1,13 @@
-"""bench.py driver-contract tests: the metric-line parser, the last-good
-cache, and the degradation marking the driver's machine consumers rely on
-(ADVICE r3: cached re-prints must be machine-distinguishable from live
-measurements).
+"""bench.py driver-contract tests: one process, a chip or a failure. On the
+CPU backend the benchmark refuses to measure — no metric line, a clear
+message, a non-zero exit — in both of its modes.
 """
 import importlib.util
-import json
 import os
 import subprocess
 import sys
+
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -20,32 +20,6 @@ def _load_bench():
     return mod
 
 
-def test_metric_lines_parser():
-    bench = _load_bench()
-    text = "\n".join([
-        "random stderr noise",
-        json.dumps({"metric": "m", "value": 1.0}),
-        '{"not_metric": true}',
-        '{"metric": "m", broken json',
-        "  " + json.dumps({"metric": "m", "value": 2.0}) + "  ",
-    ])
-    lines = bench._metric_lines(text)
-    assert [ln["value"] for ln in lines] == [1.0, 2.0]
-
-
-def test_cache_roundtrip(tmp_path, monkeypatch):
-    bench = _load_bench()
-    monkeypatch.setattr(bench, "CACHE_PATH", str(tmp_path / "cache.json"))
-    assert bench._read_cache() is None
-    bench._write_cache({"metric": "m", "value": 3.0, "unit": "u"})
-    got = bench._read_cache()
-    assert got["value"] == 3.0
-    # corrupt file -> clean None, not an exception
-    with open(bench.CACHE_PATH, "w") as f:
-        f.write("{broken")
-    assert bench._read_cache() is None
-
-
 def test_peak_flops_lookup():
     bench = _load_bench()
     assert bench._peak_flops("TPU v5 lite") == 197e12
@@ -55,83 +29,40 @@ def test_peak_flops_lookup():
     assert bench._peak_flops(None) is None
 
 
-def test_driver_run_emits_final_line_without_tpu(tmp_path):
-    """End-to-end parent run with the TPU skipped: the LAST stdout line
-    must be valid metric JSON, and with no cache the CPU fallback must be
-    marked degraded."""
-    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
-    env.update(JAX_PLATFORMS="cpu", BENCH_SKIP_TPU="1",
-               BENCH_TOTAL_BUDGET="150", HOME=str(tmp_path))
-    # run from a scratch cwd copy of bench.py so the repo cache file is
-    # not consulted (cached-first would mask the degradation path)
-    bench_copy = tmp_path / "bench.py"
-    bench_copy.write_bytes(open(os.path.join(ROOT, "bench.py"), "rb").read())
-    (tmp_path / "mxnet_tpu").symlink_to(os.path.join(ROOT, "mxnet_tpu"))
-    r = subprocess.run([sys.executable, str(bench_copy)],
+@pytest.mark.parametrize("mode", [[], ["--multichip"]],
+                         ids=["train", "multichip"])
+def test_bench_refuses_the_cpu_backend(mode, tmp_path):
+    """No cached row, no CPU fallback, no child: with no accelerator the
+    run exits non-zero, says why, and prints nothing a reader could take
+    for a measurement."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    r = subprocess.run([sys.executable, os.path.join(ROOT, "bench.py")] + mode,
                        capture_output=True, text=True, env=env, timeout=240)
-    lines = [ln for ln in r.stdout.splitlines() if ln.strip()]
-    assert lines, r.stderr[-400:]
-    final = json.loads(lines[-1])
-    assert final["metric"] == "resnet50_train_throughput_per_chip"
-    assert "value" in final and "vs_baseline" in final
-    assert "degraded" in final        # no cache + no TPU => must be flagged
+    assert r.returncode != 0, r.stdout + r.stderr
+    assert "no accelerator found" in r.stderr
+    assert "refusing to measure on the host CPU" in r.stderr
+    assert '"metric"' not in r.stdout and r.stdout.strip() == ""
 
 
-def test_preflight_clear_tunnel_kills_owned_leftovers_only(monkeypatch):
-    """The self-cleaning window: session-registered LEFTOVERS (registration
-    older than BENCH_PREFLIGHT_KILL_AGE) are killed and reported; a
-    just-started owned client (an active warm run) and unregistered
-    (foreign) clients survive and still block; BENCH_PREFLIGHT_KILL=0
-    restores the old skip-only behavior."""
-    import time
-    bench = _load_bench()
-
-    class StubTunnel:
-        def __init__(self):
-            self.killed = []
-
-        def owned_pids(self):
-            return {111: {"role": "aot_warm.py",           # 2h-old, way
-                          "start": time.time() - 7200,     # past its
-                          "expected_s": 1800},             # declared life
-                    333: {"role": "perf_lab.py",
-                          "start": time.time() - 60},      # active run
-                    444: {"role": "perf_lab.py",           # 2h-old but a
-                          "start": time.time() - 7200,     # ladder may run
-                          "expected_s": 3 * 3600}}         # 3h: active
-        def kill(self, pid, grace=8.0):
-            self.killed.append(pid)
-            return "terminated"
-
-    stub = StubTunnel()
-    monkeypatch.setattr(bench, "_tunnel", stub)
-    monkeypatch.delenv("BENCH_PREFLIGHT_KILL", raising=False)
-    monkeypatch.delenv("BENCH_PREFLIGHT_KILL_AGE", raising=False)
-    clients = [{"name": "aot_warm.py", "pid": 111},
-               {"name": "perf_lab.py", "pid": 222},
-               {"name": "perf_lab.py", "pid": 333},
-               {"name": "perf_lab.py", "pid": 444}]
-    remaining, killed = bench._preflight_clear_tunnel(list(clients))
-    assert stub.killed == [111]
-    assert remaining == [{"name": "perf_lab.py", "pid": 222},
-                         {"name": "perf_lab.py", "pid": 333},
-                         {"name": "perf_lab.py", "pid": 444}]
-    assert killed == ["aot_warm.py(pid 111): terminated"]
-
-    monkeypatch.setenv("BENCH_PREFLIGHT_KILL", "0")
-    remaining, killed = bench._preflight_clear_tunnel(list(clients))
-    assert killed == [] and remaining == clients
-
-    # no registry module at all (stripped bench.py copy): skip-only
-    monkeypatch.delenv("BENCH_PREFLIGHT_KILL", raising=False)
-    monkeypatch.setattr(bench, "_tunnel", None)
-    remaining, killed = bench._preflight_clear_tunnel(list(clients))
-    assert killed == [] and remaining == clients
+def test_bench_has_no_second_process():
+    """The parent orchestrator is gone: nothing in bench.py starts a
+    process, reads a cached row or reaches for a fallback."""
+    with open(os.path.join(ROOT, "bench.py")) as f:
+        src = f.read()
+    for gone in ("subprocess", "Popen", "_read_cache", "_write_cache",
+                 "BENCH_FORCE_CPU", "BENCH_SKIP_TPU", "aot_load",
+                 "degraded", "preflight"):
+        assert gone not in src, gone
+    for name in ("bench_cache.json", "VERDICT.md",
+                 os.path.join("tools", "aot_warm.py"),
+                 os.path.join("tools", "perf_results")):
+        assert not os.path.exists(os.path.join(ROOT, name)), name
 
 
 def test_peak_flops_shares_xcost_table():
-    """bench's per-chip peaks now come from the perf layer's single
-    source of truth (observability/xcost.py)."""
+    """bench's per-chip peaks come from the perf layer's single source of
+    truth (observability/xcost.py)."""
     bench = _load_bench()
     from mxnet_tpu.observability import xcost
     for kind in ("TPU v5 lite", "TPU v5p", "TPU v4", "TPU v3"):

@@ -1,0 +1,327 @@
+"""Chip bring-up guards — what CPU tests could not see until the code met
+the TPU's own compiler and a one-chip host.
+
+* Mosaic compiles, for a DESCRIBED v5e (no chip attached; the
+  ``on-chip-measurement`` guide's third rehearsal), of both Pallas kernels at
+  the widths ``chip_smoke.py`` runs and of an ``rtc.PallasModule`` kernel —
+  with ``mxnet_tpu`` imported, so the package's ``jax_enable_x64`` default is
+  on, which is what used to make every one of them fail to legalize. Skipped
+  where the topology cannot be described.
+* the device rules: one compile-cache directory, no accelerator context
+  served by the host, the server's default device, interpret mode only on
+  request, and the trainer's state against the net's on a one-device mesh.
+"""
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu.ops import pallas_kernels as pk
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ------------------------------------------------- compiles for the chip
+@pytest.fixture(scope="module")
+def one_chip():
+    """Sharding on one device of a described v5e 2x2 host."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no libtpu here, or it cannot describe a v5e
+        pytest.skip("TPU topology cannot be described: %r" % (e,))
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *specs):
+    assert jax.config.jax_enable_x64, "the package default must be on"
+    text = jax.jit(fn).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    assert "f64[" not in text
+    return text
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((16, 2048, 128), jnp.bfloat16),
+    ((16, 2048, 128), jnp.float32),
+    ((8, 4096, 128), jnp.bfloat16),
+])
+def test_flash_attention_kernel_compiles_for_v5e(one_chip, no_compile_cache,
+                                                 shape, dtype):
+    s = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    _compile(lambda q, k, v: pk._fa_pallas(q, k, v, 0.088, True, 0, 0),
+             s, s, s)
+
+
+def test_flash_attention_grad_compiles_for_v5e(one_chip, no_compile_cache,
+                                               monkeypatch):
+    """The public entry point, forward kernel plus blockwise backward, at
+    the smoke's shape (use_pallas asks the default backend, which is the CPU
+    here: steered in the test, as the guide says)."""
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    s = jax.ShapeDtypeStruct((2, 8, 2048, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        out = pk.flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), s, s, s)
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((256, 1024), jnp.float32),
+    ((4096, 10240), jnp.float32),     # 20.25 MiB of scoped VMEM before tiling
+    ((4096, 32768), jnp.bfloat16),    # 32.25 MiB before tiling
+    ((4096, 50304), jnp.bfloat16),    # ragged class tail (50304 % 2048 != 0)
+])
+def test_cross_entropy_kernel_compiles_for_v5e(one_chip, no_compile_cache,
+                                               shape, dtype):
+    s = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    _compile(pk._ce_lse_pallas, s)
+
+
+def test_rtc_kernel_compiles_for_v5e(one_chip, no_compile_cache):
+    """A user kernel written as Pallas users write them — Python ints in the
+    index map — through ``rtc.PallasModule``."""
+    from jax.experimental import pallas as pl
+
+    def axpy(x_ref, y_ref, o_ref):
+        o_ref[...] = 2.0 * x_ref[...] + y_ref[...]
+
+    kernel = mx.rtc.PallasModule(axpy).get_kernel("axpy")
+    s = jax.ShapeDtypeStruct((1024, 512), jnp.float32, sharding=one_chip)
+    spec = pl.BlockSpec((256, 512), lambda i: (i, 0))
+    fn = kernel._jitted([s, s], grid=(4,), in_specs=[spec, spec],
+                        out_specs=spec, interpret=False)
+    assert "tpu_custom_call" in fn.lower(s, s).compile().as_text()
+
+
+def test_rtc_does_not_choose_interpret_mode(monkeypatch):
+    """No chip and no request for the interpreter: the launch fails, it does
+    not quietly run the kernel on the host."""
+    monkeypatch.delenv("MXTPU_PALLAS_INTERPRET", raising=False)
+
+    def twice(x_ref, o_ref):
+        o_ref[...] = 2.0 * x_ref[...]
+
+    kernel = mx.rtc.PallasModule(twice).get_kernel("twice")
+    x = mx.nd.ones((8, 128))
+    with pytest.raises(Exception, match="(?i)interpret|cpu"):
+        kernel.launch([x]).asnumpy()
+    np.testing.assert_allclose(
+        kernel.launch([x], interpret=True).asnumpy(), 2.0)
+
+
+# ------------------------------------------------------ compile-cache rule
+def test_compile_cache_honours_env(monkeypatch, tmp_path):
+    from mxnet_tpu import base
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert base.enable_compile_cache() == str(tmp_path)
+    # no directory is set in code when the variable names one
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_fixed_path_in_checkout(monkeypatch):
+    from mxnet_tpu import base
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        first = base.enable_compile_cache()
+        second = base.enable_compile_cache()
+        assert first == second == base.compile_cache_dir() \
+            == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_no_cache_directory_named_outside_the_helper():
+    hits = []
+    for root, dirs, files in os.walk(REPO):
+        dirs[:] = [d for d in dirs if not d.startswith(".")
+                   and d not in ("tests", "chiprun_out", "__pycache__")]
+        for name in files:
+            if name.endswith(".py"):
+                path = os.path.join(root, name)
+                with open(path, errors="replace") as f:
+                    if "jax_compilation_cache_dir" in f.read():
+                        hits.append(os.path.relpath(path, REPO))
+    assert hits == [os.path.join("mxnet_tpu", "base.py")], hits
+
+
+# ------------------------------------------------------------ device rules
+@pytest.mark.parametrize("ctx", [mx.tpu(0), mx.gpu(0)], ids=str)
+def test_accelerator_context_without_accelerator_raises(ctx):
+    assert mx.num_tpus() == 0          # the CPU test mesh
+    with pytest.raises(RuntimeError, match="no accelerator"):
+        ctx.jax_device()
+    with pytest.raises(RuntimeError, match="no accelerator"):
+        mx.nd.zeros((2,), ctx=ctx)
+    assert mx.current_context() == mx.cpu(0)
+
+
+@pytest.mark.parametrize("ctx,explicit,want", [
+    (mx.cpu(0), {}, (1, 0)),
+    (mx.cpu(3), {}, (1, 3)),                   # follows current_context()
+    (mx.tpu(2), {}, (2, 2)),                   # ... the chip when it is one
+    (mx.tpu(2), {"dev_type": 1}, (1, 0)),      # explicit CPU stays CPU
+    (mx.cpu(0), {"dev_type": 2, "dev_id": 1}, (2, 1)),
+], ids=["cpu0", "cpu3", "tpu2", "explicit-cpu", "explicit-accel"])
+def test_model_config_default_device_follows_context(ctx, explicit, want):
+    from mxnet_tpu.serving import ModelConfig, load as sload
+    sym_json, pbytes, feat, _ = sload.tiny_model()
+    with ctx:
+        cfg = ModelConfig("m", sym_json, pbytes, feature_shape=feat,
+                          buckets=(1, 2), **explicit)
+    assert (cfg.dev_type, cfg.dev_id) == want
+
+
+def test_predictor_arrays_live_on_its_context():
+    """The executor runs where its arrays are: all of them on the asked
+    device, not on whatever current_context() happens to be."""
+    from mxnet_tpu.serving import ModelConfig, ModelServer, load as sload
+    sym_json, pbytes, feat, ref = sload.tiny_model()
+    dev = jax.devices()[3]
+    cfg = ModelConfig("m", sym_json, pbytes, feature_shape=feat,
+                      buckets=(1, 2), dev_type=1, dev_id=3)
+    with ModelServer([cfg], drain_on_preemption=False) as srv:
+        x = np.arange(4, dtype="float32")
+        np.testing.assert_allclose(srv.predict("m", x, timeout=30), ref(x),
+                                   rtol=1e-5)
+        assert srv.stats("m")["device"] == str(dev)
+        pred = srv._models["m"].cache.get(1)
+        for arr in list(pred._args.values()) + pred._outputs:
+            assert arr._data.devices() == {dev}
+
+
+def test_module_with_several_contexts_says_it_uses_one(caplog):
+    data = mx.sym.Variable("data")
+    net = mx.sym.SoftmaxOutput(mx.sym.FullyConnected(data, num_hidden=3),
+                               name="softmax")
+    mod = mx.mod.Module(net, context=[mx.cpu(0), mx.cpu(1)])
+    with caplog.at_level("WARNING", logger="mxnet_tpu"):
+        mod.bind(data_shapes=[("data", (4, 5))],
+                 label_shapes=[("softmax_label", (4,))])
+    assert "one executor on cpu(0)" in caplog.text
+
+
+def test_module_binds_a_stride2_stem_under_the_default_passes():
+    """What train_imagenet.py builds: on the Module path the s2d pass
+    rearranges the stem weight in-graph (no re-homing), and simple_bind could
+    not infer the weight's shape back through those reshapes — every
+    ImageNet-stem symbol failed to bind until Module handed the executor the
+    shapes of the un-rewritten graph."""
+    data = mx.sym.Variable("data")
+    net = mx.sym.Convolution(data, num_filter=8, kernel=(7, 7), stride=(2, 2),
+                             pad=(3, 3), no_bias=True, name="conv0")
+    net = mx.sym.Activation(net, act_type="relu")
+    net = mx.sym.Pooling(net, global_pool=True, pool_type="avg",
+                         kernel=(1, 1))
+    net = mx.sym.FullyConnected(mx.sym.Flatten(net), num_hidden=4, name="fc")
+    net = mx.sym.SoftmaxOutput(net, name="softmax")
+    batch = mx.io.DataBatch(
+        [mx.nd.array(np.random.RandomState(0).rand(2, 3, 32, 32))],
+        [mx.nd.zeros((2,))])
+    outs, params = [], None
+    for passes in (None, False):
+        mod = mx.mod.Module(net, context=mx.cpu(), passes=passes)
+        mod.bind(data_shapes=[("data", (2, 3, 32, 32))],
+                 label_shapes=[("softmax_label", (2,))], for_training=False)
+        if params is None:
+            mx.random.seed(0)
+            mod.init_params(mx.init.Xavier())
+            params = mod.get_params()
+            assert mod.passes_provenance()["rewrites"]["s2d"] == 1
+        else:
+            mod.set_params(*params)
+        assert params[0]["conv0_weight"].shape == (8, 3, 7, 7)
+        mod.forward(batch, is_train=False)
+        outs.append(mod.get_outputs()[0].asnumpy())
+    np.testing.assert_allclose(outs[0], outs[1], rtol=1e-5, atol=1e-6)
+
+
+# --------------------------------- trainer state against the net's own
+def _tiny_trainer(n_devices):
+    from mxnet_tpu import gluon, parallel
+    from mxnet_tpu.gluon import nn
+    mx.random.seed(0)
+    net = nn.HybridSequential(prefix="bringup%d_" % n_devices)
+    with net.name_scope():
+        net.add(nn.Dense(8, in_units=4), nn.BatchNorm(in_channels=8),
+                nn.Dense(3, in_units=8))
+    net.initialize(mx.init.Xavier())
+    mesh = parallel.local_mesh("dp", devices=jax.devices()[:n_devices])
+    trainer = parallel.DataParallelTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+        {"learning_rate": 0.05, "momentum": 0.9}, mesh=mesh)
+    x = np.random.RandomState(0).rand(4, 4).astype("float32")
+    y = np.array([0, 1, 2, 0], "float32")
+    return net, trainer, x, y
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_trainer_step_compiles_once(n_devices):
+    """State is placed on the mesh at capture; before, step one saw it on
+    the net's device, jit saw another type at step two, and the whole step
+    was traced and compiled twice."""
+    _, trainer, x, y = _tiny_trainer(n_devices)
+    float(trainer.step(x, y))
+    size = trainer._step_fn._cache_size()
+    for _ in range(2):
+        float(trainer.step(x, y))
+    assert size == trainer._step_fn._cache_size() == 1
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_trainer_never_donates_the_nets_buffers(n_devices):
+    """The step donates its state. Placing an array on a mesh that holds its
+    device aliases it, so the trainer keeps copies: the net's arrays stay
+    readable after a step, and after step / sync_to_net / step."""
+    net, trainer, x, y = _tiny_trainer(n_devices)
+    before = {p.name: p.data().asnumpy()
+              for p in net.collect_params().values()}
+    float(trainer.step(x, y))
+    for p in net.collect_params().values():
+        np.testing.assert_array_equal(p.data().asnumpy(), before[p.name])
+    trainer.sync_to_net()
+    float(trainer.step(x, y))
+    after = {p.name: p.data().asnumpy()
+             for p in net.collect_params().values()}
+    assert any(not np.array_equal(after[k], before[k]) for k in before)
+
+
+# ------------------------------------------------------------ the smoke
+def test_chip_smoke_refuses_the_cpu_backend():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       capture_output=True, text=True, timeout=120, env=env)
+    assert p.returncode != 0, p.stdout + p.stderr
+    last = json.loads(p.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False
+    assert last["device"]["platform"] == "cpu"

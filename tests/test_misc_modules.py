@@ -95,7 +95,7 @@ def test_rtc_pallas_kernel():
     k = mod.get_kernel("axpy_kernel")
     x = mx.nd.array(np.arange(16.0, dtype="float32").reshape(2, 8))
     y = mx.nd.ones((2, 8))
-    out = k.launch([x, y])
+    out = k.launch([x, y], interpret=True)
     np.testing.assert_allclose(out.asnumpy(), 2 * x.asnumpy() + 1)
 
 

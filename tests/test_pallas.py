@@ -88,9 +88,11 @@ def test_flash_attention_grad_interpret(rng, interp):
                                    rtol=2e-4, atol=2e-4)
 
 
-def test_softmax_cross_entropy_interpret(rng, interp):
-    logits = jnp.asarray(rng.randn(16, 128).astype("float32"))
-    labels = jnp.asarray(rng.randint(0, 128, size=16).astype("int32"))
+# 128: one class block; 2176 = 2048 + 128: two blocks with a ragged tail
+@pytest.mark.parametrize("classes", [128, 2176])
+def test_softmax_cross_entropy_interpret(rng, interp, classes):
+    logits = jnp.asarray(rng.randn(16, classes).astype("float32"))
+    labels = jnp.asarray(rng.randint(0, classes, size=16).astype("int32"))
     loss = pk.softmax_cross_entropy(logits, labels)
     ref = -jax.nn.log_softmax(logits, axis=1)[jnp.arange(16), labels]
     np.testing.assert_allclose(np.asarray(loss), np.asarray(ref),

@@ -363,9 +363,13 @@ def test_collbench_algo_bytes():
 def test_scaling_row_shape(tmp_path):
     from mxnet_tpu.observability import xcost
     led = xcost.CostLedger(str(tmp_path / "scale.jsonl"))
+    extra = {"model": "tiny", "provenance": "test"}
     row = collbench.scaling_row(batch_per_chip=8, image=8, steps=2,
-                                warmup=1, ledger=led)
+                                warmup=1, ledger=led, extra=extra)
     assert row["metric"] == "multichip_scaling_efficiency"
+    # the persisted row carries the caller's identity fields too (a ledger
+    # row without them would match any model-filtered reader)
+    assert {k: led.rows()[-1][k] for k in extra} == extra
     assert row["n_devices"] == N_DEV
     assert row["img_s_per_chip_1"] > 0 and row["img_s_per_chip_n"] > 0
     assert row["value"] == round(

@@ -11,6 +11,14 @@ jax.distributed variables) and spawns N copies of the training command.
 
   python tools/launch.py -n 4 python train_imagenet.py --kv-store dist_sync
   python tools/launch.py -n 2 -H hostfile ...   # ssh multi-host
+
+One process per host drives ALL of that host's chips (a chip belongs to one
+process at a time, and ``DataParallelTrainer`` / ``Module(context=[...])``
+span the local devices from inside one process). The local launcher assigns
+no devices to its workers, so ``-n N`` on a single host is the CPU test path
+(``JAX_PLATFORMS=cpu``, what ``tests/test_dist.py`` runs): on a chip host
+every local worker would try to open every chip. Across hosts, launch one
+worker per host (``-H hostfile``).
 """
 from __future__ import annotations
 

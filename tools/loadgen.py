@@ -46,7 +46,6 @@ import time
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
-sys.path.insert(1, os.path.join(HERE, "tools"))
 
 
 def main(argv=None) -> int:
@@ -128,12 +127,6 @@ def main(argv=None) -> int:
                          "positive\n")
         return 2
     qps = args.qps * (args.storm if args.storm else 1.0)
-
-    try:
-        import tunnel_session
-        tunnel_session.register("loadgen.py", expected_s=3600)
-    except Exception:
-        pass
 
     if args.during_rollout and not args.selfhost:
         sys.stderr.write("loadgen: --during-rollout is selfhost-only "

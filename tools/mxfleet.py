@@ -31,7 +31,6 @@ import time
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
-sys.path.insert(1, os.path.join(HERE, "tools"))
 
 
 def _get(url):
@@ -245,12 +244,6 @@ def main(argv=None) -> int:
     p.add_argument("--chaos", choices=("tenant_storm",), default=None)
 
     args = ap.parse_args(argv)
-
-    try:
-        import tunnel_session
-        tunnel_session.register("mxfleet.py", expected_s=3600)
-    except Exception:
-        pass
 
     if args.command == "status":
         return _cmd_status(args)

@@ -37,7 +37,6 @@ import time
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
-sys.path.insert(1, os.path.join(HERE, "tools"))
 
 
 def _get(url):
@@ -322,12 +321,6 @@ def main(argv=None) -> int:
                    default=None)
 
     args = ap.parse_args(argv)
-
-    try:
-        import tunnel_session
-        tunnel_session.register("mxrollout.py", expected_s=3600)
-    except Exception:
-        pass
 
     if args.command == "status":
         return _cmd_status(args)

@@ -32,7 +32,6 @@ import time
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
-sys.path.insert(1, os.path.join(HERE, "tools"))
 
 
 def main(argv=None) -> int:
@@ -99,12 +98,6 @@ def main(argv=None) -> int:
         report = analysis.lint_server(cfg)
         for d in report:
             sys.stderr.write("mxserve: %s\n" % d.render())
-    except Exception:
-        pass
-
-    try:
-        import tunnel_session
-        tunnel_session.register("mxserve.py", expected_s=12 * 3600)
     except Exception:
         pass
 

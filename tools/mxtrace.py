@@ -36,7 +36,6 @@ import time
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
-sys.path.insert(1, os.path.join(HERE, "tools"))
 
 _BAR = 28       # timeline bar width (chars)
 
@@ -220,12 +219,6 @@ def main(argv=None) -> int:
                     help="re-render every N seconds; Ctrl-C to stop — "
                          "exit code reflects the LAST render")
     args = ap.parse_args(argv)
-
-    try:
-        import tunnel_session
-        tunnel_session.register("mxtrace.py", expected_s=600)
-    except Exception:
-        pass
 
     if args.watch > 0:
         rc = 0

@@ -3,17 +3,17 @@
 
 Compares a CURRENT artifact — a bench row, a telemetry snapshot (the live
 ``mxtpu_mfu``/``mxtpu_trainer_samples_per_sec`` gauges), or a cost-ledger
-row/JSONL — against a BASELINE (default: the repo's ``bench_cache.json``;
-also accepts ``BENCH_*.json`` wrappers and ledgers). Any metric present on
+row/JSONL — against a BASELINE (``--baseline``, else the
+``MXNET_PERF_BASELINE`` env; bench rows, ``{"parsed": row}`` driver
+wrappers and ledgers are accepted). Any metric present on
 both sides is checked with direction-aware thresholds (throughput/MFU:
 lower is a regression; FLOPs-per-step/step-time: higher is).
 
 Usage::
 
-    python tools/perfwatch.py /run/metrics.json                # vs cache
-    python tools/perfwatch.py fresh_row.json --baseline BENCH_r04.json
-    python tools/perfwatch.py ledger.jsonl --threshold-pct 5
-    python tools/perfwatch.py snap.json --format json
+    python tools/perfwatch.py fresh_row.json --baseline last_row.json
+    python tools/perfwatch.py ledger.jsonl --baseline old.jsonl --threshold-pct 5
+    python tools/perfwatch.py snap.json --baseline last_row.json --format json
 
 Exit codes (mxlint convention): 0 = parity/improvement, 1 = at least one
 metric regressed past its threshold, 2 = baseline or current artifact
@@ -35,8 +35,8 @@ def main(argv=None) -> int:
     ap.add_argument("current", help="bench row JSON, telemetry snapshot "
                                     "JSON, or cost-ledger JSON/JSONL")
     ap.add_argument("--baseline", default=None,
-                    help="baseline artifact (default: MXNET_PERF_BASELINE "
-                         "env, else <repo>/bench_cache.json)")
+                    help="baseline artifact (default: the "
+                         "MXNET_PERF_BASELINE env)")
     ap.add_argument("--threshold-pct", type=float, default=None,
                     help="regression threshold percent applied to every "
                          "metric (default 10)")
