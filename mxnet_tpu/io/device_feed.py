@@ -34,6 +34,7 @@ from ..base import MXNetError
 from ..ndarray.ndarray import NDArray, _unwrap, _wrap
 from ..observability import catalog as _telemetry
 from ..observability import metrics as _metrics
+from ..observability import spans as _spans
 from .io import DataBatch, DataIter, has_state, _join_producer, _put_or_stop
 
 __all__ = ["prefetch_to_device", "DeviceFeedIter"]
@@ -169,6 +170,7 @@ class DeviceFeedIter(DataIter):
         self._queue: "queue.Queue" = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self._thread = None
+        self._delivered = 0     # batches handed to the consumer so far
         self._start()
 
     # DataDesc passthrough so Module/fit loops see the base iterator's shape
@@ -201,30 +203,39 @@ class DeviceFeedIter(DataIter):
             out.append(_wrap(d))
         return out
 
-    def _producer(self, q, stop):
+    def _producer(self, q, stop, m):
         # q/stop arrive as ARGUMENTS (not re-read from self) so a stale
-        # thread from before a reset() can never touch the new queue
+        # thread from before a reset() can never touch the new queue; m is
+        # the number of the first batch this thread stages (the consumer's
+        # count of deliveries: the spans of one batch share it on both sides)
         try:
             while not stop.is_set():
+                unit = ("batch", m)
                 try:
-                    b = self._base.next()
+                    with _spans.span("feed.base_next", unit=unit):
+                        b = self._base.next()
                 except StopIteration:
                     _put_or_stop(q, _STOP, stop)
                     return
                 state = self._base.state() if self._track_state else None
-                staged = DataBatch(
-                    data=self._put_arrays(b.data, is_label=False),
-                    label=self._put_arrays(b.label, is_label=True),
-                    pad=b.pad, index=b.index,
-                    bucket_key=getattr(b, "bucket_key", None))
-                if not _put_or_stop(q, (staged, state), stop):
-                    return
+                with _spans.span("feed.stage", unit=unit):
+                    staged = DataBatch(
+                        data=self._put_arrays(b.data, is_label=False),
+                        label=self._put_arrays(b.label, is_label=True),
+                        pad=b.pad, index=b.index,
+                        bucket_key=getattr(b, "bucket_key", None))
+                # blocked here, the feed is ahead of the step
+                with _spans.span("feed.put_wait", unit=unit):
+                    if not _put_or_stop(q, (staged, state), stop):
+                        return
+                m += 1
         except Exception as e:
             _put_or_stop(q, e, stop)
 
     def _start(self):
         self._thread = threading.Thread(
-            target=self._producer, args=(self._queue, self._stop),
+            target=self._producer,
+            args=(self._queue, self._stop, self._delivered),
             daemon=True, name="mxtpu-device-feed-iter")
         self._thread.start()
 
@@ -294,7 +305,12 @@ class DeviceFeedIter(DataIter):
             if self._terminal is StopIteration:
                 raise StopIteration
             raise self._terminal
-        item = self._queue.get()
+        # blocked here, the step is starved
+        with _spans.span("feed.get_wait",
+                         unit=("batch", self._delivered)) as wait:
+            item = self._queue.get()
+        if wait.t1 is not None:
+            _telemetry.IO_FEED_STALL_MS.observe(wait.ms)
         if item is _STOP:
             self._terminal = StopIteration
             raise StopIteration
@@ -302,6 +318,7 @@ class DeviceFeedIter(DataIter):
             self._terminal = item
             raise item
         staged, state = item
+        self._delivered += 1
         if state is not None:
             self._last_state = state
         if _metrics.enabled():

@@ -505,7 +505,12 @@ class PrefetchingIter(DataIter):
             if self._terminal is StopIteration:
                 raise StopIteration
             raise self._terminal
+        tel = _metrics.enabled()
+        t0 = time.perf_counter() if tel else 0.0
         item = self._queue.get()
+        if tel:
+            _telemetry.IO_FEED_STALL_MS.observe(
+                (time.perf_counter() - t0) * 1000.0)
         if item is None:
             self._terminal = StopIteration
             raise StopIteration

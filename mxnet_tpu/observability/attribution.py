@@ -9,16 +9,18 @@ ever syncs the device):
 =============  ===========================================================
 bucket          meaning
 =============  ===========================================================
-dispatch        time inside the jitted-step call. Under async dispatch
-                this is enqueue cost — until the device queue fills, at
-                which point XLA's backpressure blocks here and the bucket
-                converges to true device compute time.
-h2d_transfer    time blocked in ``jax.device_put`` staging the batch.
-host_prep       the rest of ``step()``'s body (unwrap, rng fold-in).
+dispatch        the span ``trainer.enqueue``: time inside the jitted-step
+                call. Under async dispatch this is enqueue cost — until
+                the device queue fills, at which point XLA's backpressure
+                blocks here and the bucket converges to true device
+                compute time.
+h2d_transfer    the span ``trainer.put``: ``jax.device_put`` of the batch.
+host_prep       the rest of ``trainer.step`` up to the end of the enqueue
+                (unwrap, capture, rng fold-in).
 feed_stall      time the data pipeline blocked the consumer in ``next()``
-                between our steps — the delta of the PR-4
-                ``mxtpu_io_feed_stall_ms`` histogram, attributed to the
-                step that waited for it.
+                between our steps — the delta of the
+                ``mxtpu_io_feed_stall_ms`` histogram (``feed.get_wait`` in
+                ``DeviceFeedIter``), attributed to the step that waited.
 host_other      remaining time between the previous step's return and this
                 step's entry (user code, metric reads, logging).
 =============  ===========================================================
@@ -118,28 +120,26 @@ class StepAttribution:
         self._win: deque = deque(maxlen=self.window)       # bucket tuples
         self._cadence: deque = deque(maxlen=self.window)   # seconds
         self._busy: deque = deque(maxlen=self.window)      # bools
-        self._prev_entry: Optional[float] = None
         self._prev_exit: Optional[float] = None
         self._prev_loss = None
         self.steps = 0
 
     # ------------------------------------------------------------- feeding
-    def _feed_stall_delta_ms(self) -> float:
-        """New io feed-stall milliseconds since any attribution's last
-        claim (whole-family sum of ``mxtpu_io_feed_stall_ms`` — the PR-4
-        instrumentation point in ResilientDataIter/prefetchers — behind the
-        shared claim cursor so concurrent trainers never double-count)."""
-        return _claim_feed_stall_ms()
-
-    def observe(self, t_entry: float, t_exit: float, *, transfer_ms: float,
+    def observe(self, t_entry: float, t_exit: float, *,
+                cadence_s: Optional[float], transfer_ms: float,
                 dispatch_ms: float, loss_ref=None,
                 flops_per_step: Optional[float] = None) -> None:
-        """Record one step: perf_counter entry/exit marks plus the measured
-        transfer and dispatch segments; ``loss_ref`` is the step's async
-        device scalar (kept one step, polled non-blocking, never synced)."""
+        """Record one step from its spans: ``trainer.step``'s start and
+        ``trainer.enqueue``'s end (perf_counter), the time since the previous
+        step's entry (None on the first), the durations of ``trainer.put``
+        and ``trainer.enqueue``; ``loss_ref`` is the step's async device
+        scalar (kept one step, polled non-blocking, never synced)."""
         total_ms = max(0.0, (t_exit - t_entry) * 1e3)
         host_prep = max(0.0, total_ms - transfer_ms - dispatch_ms)
-        feed = self._feed_stall_delta_ms()
+        # new stall milliseconds of the io iterators (DeviceFeedIter's
+        # ``feed.get_wait``, PrefetchingIter, ResilientDataIter) since any
+        # attribution's last claim
+        feed = _claim_feed_stall_ms()
         if self._prev_exit is not None:
             between = max(0.0, (t_entry - self._prev_exit) * 1e3 - feed)
         else:
@@ -152,9 +152,8 @@ class StepAttribution:
             except Exception:       # deleted buffer on a retry path
                 busy = None
         self._prev_loss = loss_ref
-        if self._prev_entry is not None:
-            self._cadence.append(max(1e-9, t_entry - self._prev_entry))
-        self._prev_entry = t_entry
+        if cadence_s is not None:
+            self._cadence.append(max(1e-9, cadence_s))
         self._prev_exit = t_exit
         self._win.append((dispatch_ms, transfer_ms, host_prep, feed, between))
         if busy is not None:

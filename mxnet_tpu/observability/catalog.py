@@ -18,8 +18,10 @@ from . import metrics as _m
 # --------------------------------------------------------------- trainer
 STEP_MS = _m.histogram(
     "mxtpu_trainer_step_ms",
-    "Wall time of DataParallelTrainer.step (host dispatch + any sync the "
-    "caller's loop forces).")
+    "Time from one DataParallelTrainer.step entry to the next (the wall "
+    "step cadence, which back-pressure makes the device's step time; NOT "
+    "the time to enqueue a step, which is mxtpu_span_ms{span="
+    "\"trainer.enqueue\"}). No sample for a trainer's first step.")
 STEPS_TOTAL = _m.counter(
     "mxtpu_trainer_steps_total", "Fused train steps dispatched.")
 SAMPLES_TOTAL = _m.counter(
@@ -27,7 +29,8 @@ SAMPLES_TOTAL = _m.counter(
     "Training samples consumed (leading batch dim of the first input).")
 SAMPLES_PER_SEC = _m.gauge(
     "mxtpu_trainer_samples_per_sec",
-    "Throughput of the most recent step (samples / step wall time).")
+    "Throughput of the most recent step: samples / the time since the "
+    "previous step's entry (the cadence, not the enqueue time).")
 CAPTURES_TOTAL = _m.counter(
     "mxtpu_trainer_captures_total",
     "Net captures (graph trace + jit rebuild). More than one per input "

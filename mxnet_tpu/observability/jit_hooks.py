@@ -21,9 +21,9 @@ from __future__ import annotations
 
 import threading
 import time
-from collections import deque
 
 from . import metrics as _metrics
+from . import spans as _spans
 
 __all__ = ["install", "installed", "last_compile_ms",
            "recent_compile_events", "JIT_TRACES", "JIT_COMPILES",
@@ -50,11 +50,6 @@ _lock = threading.Lock()
 _installed = False
 _last_compile_ms = None
 
-# timestamped ring of recent trace/compile events (perf_counter seconds):
-# the shared-clock lane Tracer.chrome_trace merges next to serving spans,
-# so the compile that delayed a request lines up with its queue span
-_COMPILE_EVENTS: deque = deque(maxlen=64)
-
 
 def last_compile_ms():
     """Wall time of the most recent XLA backend compile this process
@@ -64,28 +59,27 @@ def last_compile_ms():
 
 
 def recent_compile_events():
-    """Recent jaxpr-trace / backend-compile events as ``{"event",
-    "t0", "dur_s"}`` dicts, ``t0`` in ``time.perf_counter`` seconds —
-    the clock the profiler and the trace ring export against."""
-    return list(_COMPILE_EVENTS)
+    """The span ring's ``jit.trace`` / ``jit.compile`` records as ``{"event",
+    "t0", "dur_s"}`` dicts, ``t0`` in ``time.perf_counter`` seconds — the
+    clock the profiler and the trace ring export against, so the compile
+    that delayed a request lines up with its queue span."""
+    return [{"event": r.name, "t0": r.t0, "dur_s": r.t1 - r.t0}
+            for r in _spans.records() if r.name in ("jit.trace", "jit.compile")]
 
 
 def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
     if not _metrics.enabled():
         return
+    now = time.perf_counter()
     if event == _TRACE_EVENT:
         JIT_TRACES.inc()
-        _COMPILE_EVENTS.append({"event": "jaxpr_trace",
-                                "t0": time.perf_counter() - duration_secs,
-                                "dur_s": duration_secs})
+        _spans.record("jit.trace", now - duration_secs, now)
     elif event == _COMPILE_EVENT:
         global _last_compile_ms
         _last_compile_ms = duration_secs * 1000.0
         JIT_COMPILES.inc()
         JIT_COMPILE_MS.observe(duration_secs * 1000.0)
-        _COMPILE_EVENTS.append({"event": "backend_compile",
-                                "t0": time.perf_counter() - duration_secs,
-                                "dur_s": duration_secs})
+        _spans.record("jit.compile", now - duration_secs, now)
 
 
 def _on_event(event: str, **kwargs) -> None:

@@ -14,7 +14,6 @@ parameters live as a pytree; after training, ``sync_to_net()`` writes back.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -31,6 +30,7 @@ from ..observability import catalog as _telemetry
 from ..observability import flight_recorder as _flight
 from ..observability import memwatch as _memwatch
 from ..observability import metrics as _metrics
+from ..observability import spans as _spans
 from ..observability import xcost as _xcost
 from ..passes import manager as _passes
 from ..resilience import recovery as _recovery
@@ -401,6 +401,7 @@ class DataParallelTrainer:
         # live MFU/device-util gauges. Pure bookkeeping around the step —
         # the jitted program and its HLO are untouched (tier-1 guards it).
         self._attr_cfg = _attribution.attribution_config(step_attribution)
+        self._prev_entry = None     # perf_counter entry of the last step
         _dev0 = self._mesh.devices.ravel()[0]
         self._perf = (_attribution.StepAttribution(
             self._attr_cfg, device_kind=_dev0.device_kind,
@@ -477,7 +478,13 @@ class DataParallelTrainer:
                                   self._pass_info.get("rewrites"))
 
     # ------------------------------------------------------------- capture
+    @_spans.span("trainer.capture")
     def _capture(self, n_inputs: int, sample_arrays=None):
+        """Build the step's program from the net. Children of the span:
+        ``trainer.capture.forward`` (the op-by-op forward that settles
+        deferred shapes), ``trainer.capture.graph`` (symbolic trace, passes,
+        lowering), ``trainer.capture.state`` (params, statistics and
+        optimizer state placed on the mesh)."""
         from .. import symbol as sym_mod
         from .. import autograd
         if _metrics.enabled():
@@ -503,17 +510,20 @@ class DataParallelTrainer:
             # permutes rank-4 arrays back for the init forward only.
             if self._passes is not None:
                 init_arrays = self._passes.init_view(sample_arrays)
-            with autograd.pause():
+            with _spans.span("trainer.capture.forward"), autograd.pause():
                 self._net(*[_wrap(jnp.asarray(jax.device_get(a)))
                             for a in init_arrays[:-1]])
-        data_syms = [sym_mod.Variable(f"__data{i}") for i in range(n_inputs - 1)]
-        label_sym = sym_mod.Variable("__label")
-        out = self._net(*data_syms)
-        if isinstance(out, (list, tuple)):
-            out = out[0]
-        loss_sym = self._loss_block(out, label_sym)
-        loss_sym = self._run_passes(loss_sym, data_syms, init_arrays)
-        lowering = _GraphLowering(loss_sym)
+        with _spans.span("trainer.capture.graph"):
+            data_syms = [sym_mod.Variable(f"__data{i}")
+                         for i in range(n_inputs - 1)]
+            label_sym = sym_mod.Variable("__label")
+            out = self._net(*data_syms)
+            if isinstance(out, (list, tuple)):
+                out = out[0]
+            loss_sym = self._loss_block(out, label_sym)
+            loss_sym = self._run_passes(loss_sym, data_syms, init_arrays)
+            lowering = _GraphLowering(loss_sym)
+            raw_fn = lowering.lower(is_train=True)
         var_names = [n.name for n in loss_sym.topo_nodes() if n.is_var]
         data_names = [s.name for s in data_syms] + ["__label"]
         pmap = {p.name: p for p in self._net.collect_params().values()
@@ -525,18 +535,18 @@ class DataParallelTrainer:
         self._param_names = param_names
         self._aux_names = aux_names
         self._pmap = pmap
-        self._params = {n: self._placed_param(n, _unwrap(pmap[n].data()))
-                        for n in param_names}
-        self._aux = {n: self._placed_param(n, _unwrap(pmap[n].data()))
-                     for n in aux_names}
-        self._opt_state = self._tx.init(self._params)
+        with _spans.span("trainer.capture.state"):
+            self._params = {n: self._placed_param(n, _unwrap(pmap[n].data()))
+                            for n in param_names}
+            self._aux = {n: self._placed_param(n, _unwrap(pmap[n].data()))
+                         for n in aux_names}
+            self._opt_state = self._tx.init(self._params)
         self._guard_state = _guard_init_state()
         if self._scaler_cfg is not None:
             self._guard_state.update(
                 _recovery.scaler_init_state(self._scaler_cfg))
         if self._dynamic_lr:
             self._guard_state["lr_scale"] = jnp.ones((), jnp.float32)
-        raw_fn = lowering.lower(is_train=True)
 
         mesh, axis = self._mesh, self._axis
         repl = NamedSharding(mesh, P())
@@ -707,7 +717,8 @@ class DataParallelTrainer:
         # the mesh, as the step's own outputs are): state still sitting on
         # the net's device has another type to jit, and the whole step would
         # be traced and compiled a second time at step two
-        self._place_state()
+        with _spans.span("trainer.capture.state"):
+            self._place_state()
 
         if self._kv is not None:
             # with a scaler, grad_step takes the live scale as an extra
@@ -950,49 +961,60 @@ class DataParallelTrainer:
         """One fused fwd+bwd+allreduce+update step on a global batch.
         Returns the scalar loss (an async device value; float() to sync).
 
-        Telemetry (``observability``): step wall time, samples/sec and a
-        flight-recorder record per step — all strictly host-side, OUTSIDE
+        Telemetry (``observability``): the step is the span ``trainer.step``
+        (``unit=("step", n)``) over ``trainer.capture`` (first call and
+        re-captures), ``trainer.put``, ``trainer.rng`` and
+        ``trainer.enqueue``; the step-time gauges and the flight-recorder
+        record are fed from those spans — all strictly host-side, OUTSIDE
         the jitted function, so the compiled HLO is identical with
         telemetry on or off, and nothing here syncs the device (the loss
         stays an async value; the recorder resolves it only at dump time).
         """
-        tel = _metrics.enabled()
-        perf = self._perf if tel else None
-        t0 = time.perf_counter() if tel else 0.0
-        arrays = [_unwrap(d) if isinstance(d, NDArray) else jnp.asarray(d)
-                  for d in data]
-        if self._step_fn is None or self._n_inputs != len(arrays):
-            self._capture(len(arrays), sample_arrays=arrays)
-        dataspec = NamedSharding(self._mesh, P(self._axis))
-        tx0 = time.perf_counter() if perf is not None else 0.0
-        arrays = [jax.device_put(a, dataspec) for a in arrays]
-        tx1 = time.perf_counter() if perf is not None else 0.0
-        from .. import random as _random
-        rng = jax.random.fold_in(jax.random.PRNGKey(_random.current_seed()),
-                                 self._rng_counter)
-        self._rng_counter += 1
-        if tel and _xcost.enabled():
-            # once per executable, BEFORE dispatch (params still alive):
-            # lower + cost_analysis + persist the ledger row (host-side
-            # metadata only; the compiled program is untouched)
-            self._maybe_capture_cost(rng, arrays)
-        td0 = time.perf_counter() if perf is not None else 0.0
+        with _spans.span("trainer.step",
+                         unit=("step", self._rng_counter + 1)) as root:
+            arrays = [_unwrap(d) if isinstance(d, NDArray) else jnp.asarray(d)
+                      for d in data]
+            if self._step_fn is None or self._n_inputs != len(arrays):
+                self._capture(len(arrays), sample_arrays=arrays)
+            dataspec = NamedSharding(self._mesh, P(self._axis))
+            with _spans.span("trainer.put") as put:
+                arrays = [jax.device_put(a, dataspec) for a in arrays]
+            from .. import random as _random
+            with _spans.span("trainer.rng"):
+                rng = jax.random.fold_in(
+                    jax.random.PRNGKey(_random.current_seed()),
+                    self._rng_counter)
+            self._rng_counter += 1
+            if _metrics.enabled() and _xcost.enabled():
+                # once per executable, BEFORE dispatch (params still alive):
+                # lower + cost_analysis + persist the ledger row (host-side
+                # metadata only; the compiled program is untouched)
+                self._maybe_capture_cost(rng, arrays)
+            with _spans.span("trainer.enqueue") as enqueue:
+                loss = self._enqueue(rng, arrays)
+            if enqueue.t1 is not None:
+                self._step_telemetry(root, put, enqueue, arrays, loss)
+        return loss
+
+    def _enqueue(self, rng, arrays):
+        """Call the step's program(s): enqueue cost, or back-pressure once
+        the device's queue is full."""
         try:
             if self._kv is not None:
-                loss = self._kv_step(rng, arrays)
-            else:
-                fn = self._step_fn
-                if (self._compiled is not None
-                        and _shape_key(arrays) == self._compiled_shapes):
-                    # the deserialized executable is shape-exact; a batch
-                    # with other shapes (e.g. a ragged final batch) takes
-                    # the jit path for that call only, keeping the
-                    # executable for exact matches
-                    fn = self._compiled
-                    rng = jax.device_put(rng, NamedSharding(self._mesh, P()))
-                (self._params, self._aux, self._opt_state, self._guard_state,
-                 loss) = fn(self._params, self._aux, self._opt_state,
-                            self._guard_state, rng, *arrays)
+                return self._kv_step(rng, arrays)
+            fn = self._step_fn
+            if (self._compiled is not None
+                    and _shape_key(arrays) == self._compiled_shapes):
+                # the deserialized executable is shape-exact; a batch
+                # with other shapes (e.g. a ragged final batch) takes
+                # the jit path for that call only, keeping the
+                # executable for exact matches
+                fn = self._compiled
+                rng = jax.device_put(rng, NamedSharding(self._mesh, P()))
+            (self._params, self._aux, self._opt_state, self._guard_state,
+             loss) = fn(self._params, self._aux, self._opt_state,
+                        self._guard_state, rng, *arrays)
+            return loss
         except Exception as e:
             # the trainer dispatch boundary: a device RESOURCE_EXHAUSTED
             # leaves forensics (mxtpu_oom.json) and re-raises typed;
@@ -1002,35 +1024,41 @@ class DataParallelTrainer:
             if oom is not None:
                 raise oom from e
             raise
-        if tel:
-            t1 = time.perf_counter()
-            dt = t1 - t0
-            ms = dt * 1000.0
-            samples = int(arrays[0].shape[0]) if (
-                arrays and getattr(arrays[0], "ndim", 0)) else 0
-            _telemetry.STEP_MS.observe(ms)
-            _telemetry.STEPS_TOTAL.inc()
+
+    def _step_telemetry(self, root, put, enqueue, arrays, loss) -> None:
+        """Feed the step's gauges from its spans. The step TIME is the
+        entry-to-entry cadence: a step returns as soon as it is enqueued, and
+        once the device's queue is full back-pressure makes the cadence the
+        device's step time. The first step of a trainer has no cadence."""
+        cadence = (root.t0 - self._prev_entry
+                   if self._prev_entry is not None else None)
+        self._prev_entry = root.t0
+        samples = int(arrays[0].shape[0]) if (
+            arrays and getattr(arrays[0], "ndim", 0)) else 0
+        _telemetry.STEPS_TOTAL.inc()
+        if samples:
+            _telemetry.SAMPLES_TOTAL.inc(samples)
+        if cadence:
+            _telemetry.STEP_MS.observe(cadence * 1e3)
             if samples:
-                _telemetry.SAMPLES_TOTAL.inc(samples)
-                if dt > 0:
-                    _telemetry.SAMPLES_PER_SEC.set(samples / dt)
-            if perf is not None:
-                # FLOPs are per-executable: resolve THIS signature's ledger
-                # row (a second batch shape is a different program with
-                # different FLOPs — MFU must never mix them)
-                row = self._cost_rows.get(tuple(_shape_key(arrays)))
-                self._flops_per_step = (
-                    float(row["flops"]) if row and row.get("flops")
-                    else None)
-                # host-side decomposition + live MFU; the loss reference is
-                # kept one step and polled non-blocking, never synced
-                perf.observe(t0, t1, transfer_ms=(tx1 - tx0) * 1e3,
-                             dispatch_ms=(t1 - td0) * 1e3, loss_ref=loss,
-                             flops_per_step=self._flops_per_step)
-            # rng_counter just advanced: it IS the completed-step count
-            # (ResilientTrainer.step_count tracks the same number)
-            _flight.record_step(self._rng_counter, loss=loss, step_ms=ms)
-        return loss
+                _telemetry.SAMPLES_PER_SEC.set(samples / cadence)
+        if self._perf is not None:
+            # FLOPs are per-executable: resolve THIS signature's ledger
+            # row (a second batch shape is a different program with
+            # different FLOPs — MFU must never mix them)
+            row = self._cost_rows.get(tuple(_shape_key(arrays)))
+            self._flops_per_step = (
+                float(row["flops"]) if row and row.get("flops") else None)
+            # host-side decomposition + live MFU; the loss reference is
+            # kept one step and polled non-blocking, never synced
+            self._perf.observe(root.t0, enqueue.t1, cadence_s=cadence,
+                               transfer_ms=put.ms, dispatch_ms=enqueue.ms,
+                               loss_ref=loss,
+                               flops_per_step=self._flops_per_step)
+        # rng_counter just advanced: it IS the completed-step count
+        # (ResilientTrainer.step_count tracks the same number)
+        _flight.record_step(self._rng_counter, loss=loss,
+                            step_ms=cadence * 1e3 if cadence else None)
 
     def _maybe_capture_cost(self, rng, arrays) -> None:
         """Persist this step's cost-ledger row (once per input signature).

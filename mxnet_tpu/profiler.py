@@ -44,12 +44,17 @@ class _ProfilerState:
         self.aggregate = False
         self.xla_trace_dir: Optional[str] = None
         self.t0 = time.perf_counter()
+        self.anchor_us: Optional[float] = None   # _ANCHOR's start, see start()
 
     def us(self):
         return (time.perf_counter() - self.t0) * 1e6
 
 
 _prof = _ProfilerState()
+# the span both streams hold: start() emits it into the XLA trace and notes
+# its time on this profiler's clock, so the merge can put the device lanes
+# on the host events' clock
+_ANCHOR = "mx.profiler.anchor"
 
 
 # ---- server-process profiling over the kvstore control channel -----------
@@ -158,6 +163,8 @@ def start():
                                      profiler_options=opts)
         except Exception:
             jax.profiler.start_trace(_prof.xla_trace_dir)
+        with jax.profiler.TraceAnnotation(_ANCHOR):
+            _prof.anchor_us = _prof.us()
 
 
 def stop():
@@ -182,8 +189,11 @@ def _merge_xla_trace(trace_dir: str) -> int:
     the same merged view (src/profiler/profiler.h:556).
 
     jax.profiler.stop_trace writes plugins/profile/<run>/<host>.trace.json.gz
-    (TensorBoard layout); we take the newest run, shift its timestamps to
-    this profiler's zero, and keep its pid/tid lane metadata."""
+    (TensorBoard layout); we take the newest run, shift its timestamps so
+    that the anchor span ``start()`` emitted lands where this profiler's own
+    clock saw it (host spans and device lanes then share one clock), and
+    keep its pid/tid lane metadata. A trace without the anchor (host tracer
+    off) falls back to putting its first event at zero."""
     import glob
     import gzip
     paths = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
@@ -201,7 +211,12 @@ def _merge_xla_trace(trace_dir: str) -> int:
                and e.get("ph") != "M"]
     if not stamped:
         return 0
-    t_min = min(e["ts"] for e in stamped)
+    anchor = [e["ts"] for e in stamped if e.get("name") == _ANCHOR]
+    if anchor and _prof.anchor_us is not None:
+        # the annotation opens a moment before anchor_us is read inside it
+        shift = anchor[0] - _prof.anchor_us
+    else:
+        shift = min(e["ts"] for e in stamped)
     merged = 0
     with _lock:
         for e in evs:
@@ -213,7 +228,7 @@ def _merge_xla_trace(trace_dir: str) -> int:
             if isinstance(e.get("pid"), int):
                 e["pid"] = e["pid"] + (1 << 20)
             if isinstance(e.get("ts"), (int, float)) and e.get("ph") != "M":
-                e["ts"] = e["ts"] - t_min
+                e["ts"] = e["ts"] - shift
             e.setdefault("args", {})
             if e.get("ph") != "M":
                 e["args"]["lane"] = "xla-device"
