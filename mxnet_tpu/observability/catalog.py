@@ -47,6 +47,17 @@ STEP_RETRIES = _m.counter(
     "mxtpu_trainer_step_retries_total",
     "Transient step failures retried by ResilientTrainer.")
 
+
+# ------------------------------------------------------------------- ops
+CONV_S2D_LOWERED = _m.counter(
+    "mxtpu_conv_s2d_lowered_total",
+    "Convolution ops computed through the exact space-to-depth form, row "
+    "pairs folded into channels (stride-2 channel-last convs over <= 4 "
+    "input channels with an even height: a stem). "
+    "Counted when the op is TRACED, so it moves with captures and "
+    "compiles, never with steps; a stem net whose counter stays 0 reached "
+    "the op in a shape the lowering does not take (NCHW, odd height).")
+
 # -------------------------------------------------------------------- io
 IO_BATCHES = _m.counter(
     "mxtpu_io_batches_total",

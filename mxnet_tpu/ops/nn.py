@@ -57,6 +57,65 @@ def _conv_layout(nd, layout):
     return lhs, rhs
 
 
+#: a conv is stem-shaped when folding row pairs into channels still leaves it
+#: thin on the contraction axis (3 -> 6 channels); past this the MXU is fed
+#: well enough and the rearrangement only adds data movement
+_S2D_MAX_IN_CHANNELS = 4
+
+
+def _s2d_eligible(data_shape, weight_shape, lhs, stride, dilate, num_group):
+    """Whether a convolution has a stem's signature: channel-last 2-D, stride
+    (2,2), no dilation or groups, few input channels, kernel >= 2 in both
+    dims, even height (so the padded height is even too). Decided from what
+    the op sees, no knob."""
+    if lhs != "NHWC" or stride != (2, 2) or dilate != (1, 1) \
+            or int(num_group) != 1 or min(weight_shape[1:3]) < 2:
+        return False
+    return data_shape[3] <= _S2D_MAX_IN_CHANNELS and data_shape[1] % 2 == 0
+
+
+def _rows_to_depth2(x, n):
+    """(A, 2n, W, C) -> (A, n, W, 2C): row 2i+r lands in block row i at
+    channel r*C+c."""
+    a, _, w, c = x.shape
+    return x.reshape(a, n, 2, w, c).transpose(0, 1, 3, 2, 4) \
+            .reshape(a, n, w, 2 * c)
+
+
+def _conv_s2d(data, weight, pad):
+    """The stride-2 NHWC convolution of a few-channel input, computed exactly
+    as a stride-(1,2) convolution over the space-to-depth of the data's ROWS:
+    (B,H,W,C) -> (B,H/2,W,2C) against a (O,ceil(kh/2),kw,2C) kernel.
+
+    Rows only, because that costs the image nothing: with the batch in the
+    lanes and W in the sublanes (XLA:TPU's layout for a stem's input) pairing
+    rows regroups outer dimensions, a bitcast, while pairing columns
+    de-interleaves sublanes, a pass and a half over the image (PERF.md,
+    PR 25). The image is not padded either: the convolution pads, rows in
+    blocks. Tap u reads row 2i+u-p, so with p % 2 zero taps in FRONT of the
+    kernel (an odd pad shifts the blocks by one tap) and zeros behind it up
+    to an even extent, W'[o,du,v,r*C+c] = Wpad[o,2du+r,v,c] under a low
+    padding of ceil(p/2) blocks (tests/test_s2d_stem.py pins the algebra).
+    The weight stays the caller's (O,kh,kw,C) array: its rearrangement is
+    traced, so its gradient comes back in that shape and no padded tap is
+    ever a parameter. The barrier keeps XLA from folding the rearrangement
+    back into the strided weight-gradient convolution, which it otherwise
+    does (the same 1.74 ms op as without the lowering)."""
+    (ph, pw), h, kh = pad, data.shape[1], weight.shape[1]
+    front = ph % 2
+    kh2 = (kh + front + 1) // 2
+    lo, n_out = (ph + 1) // 2, (h + 2 * ph - kh) // 2 + 1
+    w = lax.pad(weight, jnp.zeros((), weight.dtype),
+                [(0, 0, 0), (front, 2 * kh2 - kh - front, 0), (0, 0, 0),
+                 (0, 0, 0)])
+    return lax.conv_general_dilated(
+        _rows_to_depth2(data, h // 2),
+        lax.optimization_barrier(_rows_to_depth2(w, kh2)),
+        window_strides=(1, 2),
+        padding=[(lo, n_out - 1 + kh2 - lo - h // 2), (pw, pw)],
+        dimension_numbers=("NHWC", "OHWI", "NHWC"))
+
+
 @register("Convolution", arg_names=("data", "weight", "bias"))
 def _convolution(data, weight, bias=None, kernel=(), stride=(), dilate=(), pad=(),
                  num_filter=1, num_group=1, no_bias=False, workspace=1024,
@@ -66,12 +125,20 @@ def _convolution(data, weight, bias=None, kernel=(), stride=(), dilate=(), pad=(
     dilate = _pair(dilate, nd)
     pad = _pair(pad, nd) if pad else (0,) * nd
     lhs, rhs = _conv_layout(nd, layout)
-    dn = lax.conv_dimension_numbers(data.shape, weight.shape, (lhs, rhs, lhs))
-    out = lax.conv_general_dilated(
-        data, weight, window_strides=stride,
-        padding=[(p, p) for p in pad],
-        rhs_dilation=dilate, dimension_numbers=dn,
-        feature_group_count=int(num_group))
+    if _s2d_eligible(data.shape, weight.shape, lhs, stride, dilate, num_group):
+        # runs when the op is traced: once per trace of a stem, not per step
+        from ..observability import catalog as _catalog, metrics as _metrics
+        if _metrics.enabled():
+            _catalog.CONV_S2D_LOWERED.inc()
+        out = _conv_s2d(data, weight, pad)
+    else:
+        dn = lax.conv_dimension_numbers(data.shape, weight.shape,
+                                        (lhs, rhs, lhs))
+        out = lax.conv_general_dilated(
+            data, weight, window_strides=stride,
+            padding=[(p, p) for p in pad],
+            rhs_dilation=dilate, dimension_numbers=dn,
+            feature_group_count=int(num_group))
     if not no_bias and bias is not None:
         bshape = tuple(-1 if a == "C" else 1 for a in lhs)
         out = out + bias.reshape(bshape)

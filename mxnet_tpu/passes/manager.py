@@ -20,13 +20,15 @@ Pipeline semantics:
   it to the parameter values (and its inverse on ``sync_to_net``), so the
   user-visible net keeps its original layout.
 * ``MXNET_PASSES`` selects the default pipeline: ``"0"``/``"off"`` disables
-  it, ``"layout,fusion"`` runs exactly those passes, ``"-s2d"`` runs the
+  it, ``"layout,fusion"`` runs exactly those passes, ``"-fold"`` runs the
   default set minus a pass.
 
 Pass catalog (docs/passes.md): ``fold`` (constant folding + dead-branch
-elimination), ``layout`` (automatic NCHW→NHWC propagation), ``s2d``
-(space-to-depth stem rewrite for stride-2 input convs), ``fusion``
-(transpose/cast reordering so XLA fuses across layout boundaries).
+elimination), ``layout`` (automatic NCHW→NHWC propagation), ``fusion``
+(transpose/cast reordering so XLA fuses across layout boundaries), and, by
+name only, ``s2d`` (space-to-depth stem rewrite that re-homes the weight;
+the default path gets the same form from the Convolution op itself, which
+keeps the parameter as the model declares it).
 """
 from __future__ import annotations
 
@@ -44,12 +46,15 @@ __all__ = ["Pass", "PassContext", "PassResult", "PassManager",
 register_config(
     "MXNET_PASSES", "", str,
     "Default graph-pass pipeline for Module/DataParallelTrainer capture. "
-    "Empty = the built-in default (fold,layout,s2d,fusion); '0'/'off' "
-    "disables it; 'layout,fusion' runs exactly those; '-s2d' runs the "
+    "Empty = the built-in default (fold,layout,fusion); '0'/'off' "
+    "disables it; 'layout,fusion' runs exactly those; '-fold' runs the "
     "default minus a pass.")
 
-#: canonical order; also the default pipeline contents
-DEFAULT_PIPELINE = ("fold", "layout", "s2d", "fusion")
+#: the default pipeline contents, in order. ``s2d`` is NOT in it: its
+#: re-homed (k/2,k/2,4C) weight trains padded taps the model does not have;
+#: ``ops/nn.py`` lowers the stem through space-to-depth inside the
+#: Convolution op instead, exactly, on the traced weight
+DEFAULT_PIPELINE = ("fold", "layout", "fusion")
 
 #: name -> Pass subclass (populated by the pass modules at import)
 PASS_REGISTRY: Dict[str, type] = {}

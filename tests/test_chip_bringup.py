@@ -119,6 +119,35 @@ def test_rtc_kernel_compiles_for_v5e(one_chip, no_compile_cache):
     assert "tpu_custom_call" in fn.lower(s, s).compile().as_text()
 
 
+def test_stem_weight_gradient_stays_space_to_depth_on_v5e(one_chip,
+                                                          no_compile_cache):
+    """The Convolution op's stem lowering at the cells' size: the chip's
+    compiler keeps the weight gradient a convolution that yields the
+    (64,4,7,6) rows-to-depth twin. Without the barrier in ``_conv_s2d`` it
+    folds the traced rearrangement back into the strided (64,7,7,3) form
+    (``rhs_dilate=2x2``), the 1.74 ms op the lowering is there to replace
+    (PERF.md, PR 25)."""
+    from mxnet_tpu.ops import get_op
+    conv = get_op("Convolution").fn
+
+    def loss(x, w, cot):
+        y = conv(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16), None,
+                 kernel=(7, 7), stride=(2, 2), pad=(3, 3), num_filter=64,
+                 no_bias=True, layout="NHWC")
+        return jnp.sum((y * cot).astype(jnp.float32))
+
+    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for shape, dtype in (((256, 224, 224, 3), jnp.float32),
+                                  ((64, 7, 7, 3), jnp.float32),
+                                  ((256, 112, 112, 64), jnp.bfloat16))]
+    grad = jax.jit(jax.grad(loss, argnums=1)).lower(*specs).compile()
+    convs = [l for l in grad.as_text().splitlines() if " convolution(" in l]
+    assert len(convs) == 1 and "bf16[64,4,7,6]" in convs[0].split("=")[1]
+    # rows are stride 1 (the blocks), columns keep the stem's stride 2
+    assert "rhs_dilate=1x2" in convs[0] and "pad=2_1x3_" in convs[0]
+    assert tuple(grad.out_info.shape) == (64, 7, 7, 3)
+
+
 def test_rtc_does_not_choose_interpret_mode(monkeypatch):
     """No chip and no request for the interpreter: the launch fails, it does
     not quietly run the kernel on the host."""
@@ -232,11 +261,14 @@ def test_module_with_several_contexts_says_it_uses_one(caplog):
 
 
 def test_module_binds_a_stride2_stem_under_the_default_passes():
-    """What train_imagenet.py builds: on the Module path the s2d pass
-    rearranges the stem weight in-graph (no re-homing), and simple_bind could
-    not infer the weight's shape back through those reshapes — every
+    """What train_imagenet.py builds. Under the default passes the layout
+    pass hands the stem to the Convolution op channel-last and the op lowers
+    it through space-to-depth itself; under the by-name s2d pass the stem
+    weight is rearranged in-graph (Module never re-homes), and simple_bind
+    could not infer the weight's shape back through those reshapes — every
     ImageNet-stem symbol failed to bind until Module handed the executor the
     shapes of the un-rewritten graph."""
+    from mxnet_tpu.observability import catalog
     data = mx.sym.Variable("data")
     net = mx.sym.Convolution(data, num_filter=8, kernel=(7, 7), stride=(2, 2),
                              pad=(3, 3), no_bias=True, name="conv0")
@@ -249,7 +281,7 @@ def test_module_binds_a_stride2_stem_under_the_default_passes():
         [mx.nd.array(np.random.RandomState(0).rand(2, 3, 32, 32))],
         [mx.nd.zeros((2,))])
     outs, params = [], None
-    for passes in (None, False):
+    for passes in (None, "fold,layout,s2d,fusion", False):
         mod = mx.mod.Module(net, context=mx.cpu(), passes=passes)
         mod.bind(data_shapes=[("data", (2, 3, 32, 32))],
                  label_shapes=[("softmax_label", (2,))], for_training=False)
@@ -257,13 +289,20 @@ def test_module_binds_a_stride2_stem_under_the_default_passes():
             mx.random.seed(0)
             mod.init_params(mx.init.Xavier())
             params = mod.get_params()
-            assert mod.passes_provenance()["rewrites"]["s2d"] == 1
         else:
             mod.set_params(*params)
+        if passes is not False:
+            assert mod.passes_provenance()["rewrites"].get("s2d", 0) == \
+                (0 if passes is None else 1)
         assert params[0]["conv0_weight"].shape == (8, 3, 7, 7)
+        lowered = catalog.CONV_S2D_LOWERED.value()
         mod.forward(batch, is_train=False)
+        # the op lowers the stem only where the layout pass made it NHWC and
+        # no pass had already turned it into a stride-1 convolution
+        assert (catalog.CONV_S2D_LOWERED.value() > lowered) is (passes is None)
         outs.append(mod.get_outputs()[0].asnumpy())
-    np.testing.assert_allclose(outs[0], outs[1], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(outs[0], outs[2], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(outs[1], outs[2], rtol=1e-5, atol=1e-6)
 
 
 # --------------------------------- trainer state against the net's own

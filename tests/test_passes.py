@@ -74,7 +74,11 @@ def test_pipeline_spec_grammar():
     assert passes.default_names("0") == ()
     assert passes.default_names("off") == ()
     assert passes.default_names("layout,fusion") == ("layout", "fusion")
+    assert passes.DEFAULT_PIPELINE == ("fold", "layout", "fusion")
     assert passes.default_names("-s2d") == ("fold", "layout", "fusion")
+    assert passes.default_names("-fold") == ("layout", "fusion")
+    assert passes.default_names("fold,layout,s2d,fusion") == \
+        ("fold", "layout", "s2d", "fusion")
     with pytest.raises(MXNetError):
         passes.default_names("nope")
     assert passes.resolve(False) is None
@@ -336,23 +340,54 @@ def test_trainer_equivalence_matrix_fused(rng, spec, dtype):
 
 @pytest.mark.parametrize("dtype", [None, "bfloat16"])
 def test_trainer_s2d_first_step_exact_then_rehomed_space(rng, dtype):
-    """The full default pipeline (s2d included) computes the EXACT same
-    first-step loss as passes=False — the s2d rewrite is a forward
-    reparameterization — and from step 2 on trains in the re-homed stem
-    space (the hand-flag twin's trajectory, not the 7x7 one)."""
+    """The s2d PASS (by name only since PR 25: it is not in the default)
+    computes the EXACT same first-step loss as passes=False — the rewrite
+    is a forward reparameterization — and from step 2 on trains in the
+    re-homed stem space (the hand-flag twin's trajectory, not the 7x7
+    one), which is why the default path lowers the stem in the op."""
     x, y = _batch(rng)
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
     losses = []
-    for pas in (None, False):
+    for pas in ("fold,layout,s2d,fusion", False):
         net = _conv_net("NCHW", "eqs2d_", stem=True)
         tr = parallel.DataParallelTrainer(
             net, loss_fn, "sgd", {"learning_rate": 0.1},
             compute_dtype=dtype, passes=pas)
         losses.append(float(tr.step(x, y)))
-        if pas is None:
+        if pas:
             assert tr.passes_provenance()["rewrites"].get("s2d") == 1
     tol = 2e-2 if dtype else 1e-5
     np.testing.assert_allclose(losses[0], losses[1], rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("dtype", [None, "bfloat16"])
+def test_trainer_default_trains_the_models_own_stem(rng, dtype):
+    """The default pipeline on a net with a 7x7/s2 stem follows passes=False
+    for three momentum steps, parameters included: the stem is lowered
+    through space-to-depth inside the Convolution op, so there is no padded
+    tap to train (the check PERF.md 7.1 failed under the old default)."""
+    from mxnet_tpu.observability import catalog
+    x, y = _batch(rng)
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    losses, stems = [], []
+    for pas in (None, False):
+        net = _conv_net("NCHW", "dflt3_", stem=True)
+        tr = parallel.DataParallelTrainer(
+            net, loss_fn, "sgd", {"learning_rate": 0.1, "momentum": 0.9},
+            compute_dtype=dtype, passes=pas)
+        lowered = catalog.CONV_S2D_LOWERED.value()
+        losses.append([float(tr.step(x, y)) for _ in range(3)])
+        # NCHW without the layout pass never reaches the lowering
+        assert (catalog.CONV_S2D_LOWERED.value() > lowered) is (pas is None)
+        if pas is None:
+            assert "s2d" not in tr.passes_provenance()["rewrites"]
+        tr.sync_to_net()
+        stems.append(net.collect_params()["dflt3_c0_weight"].data().asnumpy())
+    tol = 2e-2 if dtype else 1e-5
+    np.testing.assert_allclose(losses[0], losses[1], rtol=tol, atol=tol)
+    assert stems[0].shape == (8, 3, 7, 7)
+    if dtype is None:
+        np.testing.assert_allclose(stems[0], stems[1], rtol=1e-4, atol=1e-6)
 
 
 @pytest.mark.parametrize("dtype", [None, "bfloat16"])
@@ -384,9 +419,11 @@ def test_trainer_default_rewrites_conv_net(rng):
     prov = tr.passes_provenance()
     assert prov["enabled"] and "layout" in prov["applied"]
     assert prov["rewrites"]["layout"] >= 3
-    assert prov["rewrites"].get("s2d", 0) == 1     # the 7x7/s2 stem
-    # trainer params live re-homed; sync_to_net restores the net layout
-    assert tr._params["dflt_c0_weight"].shape == (8, 4, 4, 12)
+    # the 7x7/s2 stem is the op's to lower: no pass re-homes it to 4x4x12
+    assert prov["rewrites"].get("s2d", 0) == 0
+    # trainer params live re-homed (OIHW -> OHWI); sync_to_net restores the
+    # net layout
+    assert tr._params["dflt_c0_weight"].shape == (8, 7, 7, 3)
     tr.sync_to_net()
     w = net.collect_params()["dflt_c0_weight"].data()
     assert tuple(w.shape) == (8, 3, 7, 7)

@@ -112,7 +112,7 @@ def main(argv=None):
     ap.add_argument("--classes", type=int, default=1000)
     ap.add_argument("--passes", default=None,
                     help="pipeline spec (MXNET_PASSES grammar), e.g. "
-                         "'layout,fusion' or '-s2d'; default = the "
+                         "'layout,fusion' or '-fold'; default = the "
                          "default pipeline")
     ap.add_argument("--shape", action="append", metavar="NAME:D1,D2,...",
                     help="input shapes (like simple_bind kwargs); "
