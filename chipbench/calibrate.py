@@ -28,7 +28,7 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from chipbench import check, harness, traffic  # noqa: E402
+from chipbench import check, harness, rounding, traffic  # noqa: E402
 
 
 def main(argv, bench_path=None, root=ROOT, require_chip=harness.require_chip):
@@ -54,7 +54,7 @@ def main(argv, bench_path=None, root=ROOT, require_chip=harness.require_chip):
     t0 = time.perf_counter()
     say = lambda what: harness.stderr(  # noqa: E731
         "calibrate: %7.1f s  %s" % (time.perf_counter() - t0, what))
-    n_items = cfg["batch_per_chip"] * chips
+    n_rows = cfg["batch_per_chip"] * chips
     steps = mix["followed_steps"]
     # spread over the whole range the driver draws from
     seeds = [args.first_seed + n * 178956971 for n in range(args.seeds)]
@@ -73,10 +73,11 @@ def main(argv, bench_path=None, root=ROOT, require_chip=harness.require_chip):
         for who, c in variants[:1 if n >= args.variant_seeds else None]:
             net, trainer, mesh, trainable = harness.build_program(c, ref, seed, devices)
             sharding = NamedSharding(mesh, PartitionSpec("dp"))
-            pool = traffic.make_pool(dict(mix, pool=steps), c, seed, n_items, sharding)
+            pool = traffic.make_pool(dict(mix, pool=steps), c, seed, n_rows, sharding)
             feed = traffic.make_feed(mix, pool, sharding)
             sides[seed, who] = harness.follow_program(
-                net, trainer, feed, trainable, c["optimizer"]["learning_rate"], steps)
+                net, trainer, feed, trainable, c["optimizer"]["learning_rate"], steps,
+                c.get("weight_layout"))
             feed.close()
             del net, trainer, pool, feed
             free()
@@ -107,16 +108,16 @@ def main(argv, bench_path=None, root=ROOT, require_chip=harness.require_chip):
         out.write(json.dumps(lines[-1]) + "\n")
         out.flush()
 
-    part = n_items // 2 if chips == 1 else n_items // chips
+    part = n_rows // 2 if chips == 1 else n_rows // chips
     readers = [("reference", len(seeds), {}),
-               ("control_float8_e4m3", args.controls, {"rounding": ref.FLOAT8_E4M3}),
+               ("control_float8_e4m3", args.controls, {"rounding": rounding.FLOAT8_E4M3}),
                ("fault_batch_part", args.faults, {"rows": slice(0, part)}),
-               ("witness_reference_bfloat16", args.witnesses, {"rounding": ref.BFLOAT16})]
+               ("witness_reference_bfloat16", args.witnesses, {"rounding": rounding.BFLOAT16})]
     for who, count, kw in readers:
         memo = {}
         for seed in seeds[:count]:
             sides[seed, who] = harness.follow_reference(
-                cfg, mix, ref, seed, n_items, sharding, steps, memo=memo, **kw)
+                cfg, mix, ref, seed, n_rows, sharding, steps, memo=memo, **kw)
             say("%s seed %d: losses %s" % (who, seed, sides[seed, who]["loss"]))
             for side in ([v for v, _c in variants if (seed, v) in sides]
                          if who == "reference" else [who]):

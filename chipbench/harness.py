@@ -22,6 +22,10 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 SPANS = ("feed.next", "trainer.step", "watcher.wait")
+# the program's own spans on the step's thread and the consumer's side of the
+# feed (PERF.md section 3): what a traced run's idle gaps are named by
+PROGRAM_SPANS = ("trainer.step", "trainer.put", "trainer.rng", "trainer.enqueue",
+                 "trainer.capture", "feed.get_wait")
 TRACE_SECONDS = 3.0   # the traced tail: some tens of steps, a file of ~50 MB
 
 
@@ -55,22 +59,25 @@ def find_cell(bench, workload, root=ROOT):
     base = os.path.join(root, bench["paths"][0])
     mix = load_json(base, "traffic", cell["traffic"] + ".json")
     limits = load_json(base, "limits", workload + ".json")["limits"]
-    ref = load_module(os.path.join(HERE, "reference", cfg["reference"] + ".py"),
+    ref = load_module(_own_or_shared(base, "reference", cfg["reference"] + ".py"),
                       "chipbench_reference_" + cfg["reference"])
     return cell, cfg, mix, limits, ref
 
 
+def _own_or_shared(base, *parts):
+    """The bench's own file, or (a test bench borrows them) this one's."""
+    path = os.path.join(base, *parts)
+    return path if os.path.exists(path) else os.path.join(HERE, *parts)
+
+
 def metric_readers(bench, workload, root=ROOT):
     """{metric name: read(run)} for the per-layer metrics of this cell."""
-    base = os.path.join(root, bench["paths"][0], "metrics")
+    base = os.path.join(root, bench["paths"][0])
     out = {}
     for m in bench["per_layer"]:
         if "workloads" in m and workload not in m["workloads"]:
             continue
-        path = os.path.join(base, m["name"] + ".py")
-        if not os.path.exists(path):     # a test bench borrows the readers
-            path = os.path.join(HERE, "metrics", m["name"] + ".py")
-        mod = load_module(path,
+        mod = load_module(_own_or_shared(base, "metrics", m["name"] + ".py"),
                           "chipbench_metric_" + m["name"].replace(".", "_"))
         out[m["name"]] = mod.read
     return out
@@ -109,10 +116,18 @@ def enable_cache():
 
 # ----------------------------------------------------------------- program
 def _to_program(leaf, weight_layout):
-    """Reference layouts (HWIO) to the program's conv weight layout."""
-    if leaf.ndim != 4:
+    """Reference layouts (HWIO) to the program's conv weight layout, where
+    the configuration gives one."""
+    if leaf.ndim != 4 or weight_layout is None:
         return leaf
     return leaf.transpose({"OHWI": (3, 0, 1, 2), "OIHW": (3, 2, 0, 1)}[weight_layout])
+
+
+def _from_program(leaf, weight_layout):
+    """The program's conv weight layout back to the reference's (HWIO)."""
+    if leaf.ndim != 4 or weight_layout is None:
+        return leaf
+    return leaf.transpose({"OHWI": (1, 2, 3, 0), "OIHW": (2, 3, 1, 0)}[weight_layout])
 
 
 def build_program(cfg, ref, seed, devices):
@@ -130,7 +145,7 @@ def build_program(cfg, ref, seed, devices):
     # type the program keeps it in; set_data also settles deferred shapes, so
     # no forward pass is spent on initialisation
     specs = ref.leaf_specs(cfg)
-    make = jax.jit(lambda k: [_to_program(l, cfg["weight_layout"])
+    make = jax.jit(lambda k: [_to_program(l, cfg.get("weight_layout"))
                               for l in ref.init(cfg, k)])
     leaves = make(traffic.seed_key(seed))
     params = list(net.collect_params().values())
@@ -148,7 +163,8 @@ def build_program(cfg, ref, seed, devices):
     opt = dict(cfg["optimizer"])
     mesh = parallel.local_mesh("dp", devices=list(devices))
     trainer = parallel.DataParallelTrainer(
-        net, getattr(gluon.loss, cfg["loss"])(), opt.pop("name"), opt,
+        net, getattr(gluon.loss, cfg["loss"])(**cfg.get("loss_kwargs", {})),
+        opt.pop("name"), opt,
         compute_dtype=cfg["compute_dtype"], mesh=mesh,
         **cfg.get("trainer_kwargs", {}))
     return net, trainer, mesh, [t for _k, _s, t in specs]
@@ -166,10 +182,11 @@ def host_norms(a, b, scale=1.0):
         for x, y in zip(a, b)]
 
 
-def follow_program(net, trainer, feed, trainable, lr, steps):
+def follow_program(net, trainer, feed, trainable, lr, steps, weight_layout=None):
     """The program's first steps through the window's own call and feed:
-    each loss, every trainable leaf's first update over lr and its change
-    after the last step, and the first step's change of every other leaf
+    each loss, every trainable leaf's first update and its change after the
+    last step (their norms, the first over lr, and whole, as host float32 in
+    the reference's layout), and the first step's change of every other leaf
     (the running statistics). The same trainer goes on into the window."""
     import numpy as np
 
@@ -177,8 +194,11 @@ def follow_program(net, trainer, feed, trainable, lr, steps):
         return ([l for l, t in zip(leaves, trainable) if t],
                 [l.astype(np.float64) for l, t in zip(leaves, trainable) if not t])
 
+    def changes(w):
+        return [_from_program(b - a, weight_layout) for a, b in zip(w0, w)]
+
     w0, s0 = split(host_leaves(net))
-    losses, grad1, state1 = [], None, None
+    losses, grad1, update1, state1 = [], None, None, None
     for n in range(steps):
         x, y = feed.next()
         losses.append(float(trainer.step(x, y)))
@@ -187,29 +207,25 @@ def follow_program(net, trainer, feed, trainable, lr, steps):
             w, s = split(host_leaves(net))
             if n == 0:
                 grad1 = host_norms(w0, w, 1.0 / lr)
+                update1 = changes(w)
                 state1 = [b - a for a, b in zip(s0, s)]
     return {"loss": losses, "grad1": grad1, "dparam": host_norms(w0, w),
-            "state1": state1}
+            "update1": update1, "change": changes(w), "state1": state1}
 
 
 # ------------------------------------------------------------------ window
 class Spans:
-    """Harness spans in memory: name -> [seconds]; with ``annotate`` also
-    written into the profiler's trace under the same name."""
+    """The harness's own spans, in memory only: name -> [seconds]. They
+    count the window's calls for ``program_spans.select``; the trace is
+    annotated by the program's spans."""
 
     def __init__(self):
         self.seconds = {n: [] for n in SPANS}
-        self.annotate = False
 
     @contextlib.contextmanager
     def span(self, name):
-        ctx = contextlib.nullcontext()
-        if self.annotate:
-            import jax
-            ctx = jax.profiler.TraceAnnotation(name)
         t0 = time.perf_counter()
-        with ctx:
-            yield
+        yield
         self.seconds[name].append(time.perf_counter() - t0)
 
 
@@ -265,7 +281,6 @@ def traced_tail(trainer, feed, in_flight, spans):
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
-    spans.annotate = True
     jax.profiler.start_trace(tmp, profiler_options=opts)
     t0 = time.perf_counter()
     try:
@@ -273,7 +288,6 @@ def traced_tail(trainer, feed, in_flight, spans):
         window_s = time.perf_counter() - t0
     finally:
         jax.profiler.stop_trace()
-        spans.annotate = False
     files = glob.glob(os.path.join(tmp, "plugins", "profile", "*", "*.xplane.pb"))
     return (files[0] if files else None), window_s, tmp
 
@@ -295,7 +309,7 @@ def memory_peak_bytes(devices):
 
 
 # --------------------------------------------------------------- reference
-def follow_reference(cfg, mix, ref, seed, n_items, sharding, steps,
+def follow_reference(cfg, mix, ref, seed, n_rows, sharding, steps,
                      rounding=None, rows=None, memo=None):
     """The plain reference over the same weights and the same first batches,
     made anew from the seed. ``rounding`` / ``rows`` are the control's lower
@@ -307,19 +321,17 @@ def follow_reference(cfg, mix, ref, seed, n_items, sharding, steps,
     trainable = [t for _k, _s, t in ref.leaf_specs(cfg)]
     memo = {} if memo is None else memo
     if "step" not in memo:
-        def batch(key, i):
-            x, y = traffic.batch_u8(mix, cfg, key, i, n_items)
-            return x.astype("float32") * mix["scale"], y
-
         memo["init"] = jax.jit(lambda k: ref.init(cfg, k))
-        memo["batch"] = jax.jit(batch, out_shardings=(sharding, sharding))
+        memo["batch"] = jax.jit(
+            lambda key, i: traffic.reference_batch(mix, cfg, key, i, n_rows),
+            out_shardings=(sharding, sharding))
         memo["step"] = follow.make_step(
             functools.partial(ref.loss_fn, cfg, rounding=rounding, rows=rows),
             trainable, cfg["optimizer"])
     key = traffic.seed_key(seed)
     batches = (memo["batch"](key, i) for i in range(steps))
-    return follow.sgd_follow(memo["step"], memo["init"](key), trainable, batches,
-                             cfg["optimizer"]["learning_rate"])
+    return follow.follow(*memo["step"], memo["init"](key), trainable, batches,
+                         cfg["optimizer"]["learning_rate"])
 
 
 def percentile(values, q):

@@ -2,9 +2,10 @@
 reference framework's Gluon model zoo builds it, in straightforward
 ``jax.numpy`` and float32.
 
-It imports nothing of the program under test. It makes its own weights from
-the seed (the harness hands the same weights to the program), follows the
-first training steps, and returns the numbers the comparison needs.
+It imports nothing of the program under test (``chipbench.flops`` and
+``chipbench.rounding`` are the benchmark's own arithmetic). It makes its own
+weights from the seed (the harness hands the same weights to the program),
+and its ``loss_fn`` is what ``follow.py`` steps.
 
 Departures from the paper, all taken from the zoo's v1 definition and noted
 so that nobody "fixes" them: the bottleneck strides on its FIRST 1x1
@@ -18,6 +19,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from chipbench import flops
+from chipbench.rounding import fake_quant as _fake_quant
 
 BN_EPS = 1e-5
 BN_MOMENTUM = 0.9
@@ -69,6 +73,12 @@ def conv_layers(cfg):
     return out
 
 
+def train_flops_per_item(cfg):
+    """FLOPs one image requires of a training step: 6 x the multiply-adds
+    of every convolution and dense layer (``chipbench/flops.py``)."""
+    return flops.train_flops_per_item(conv_layers(cfg))
+
+
 def leaf_specs(cfg):
     """Every parameter leaf in the zoo's creation order:
     (kind, shape, trainable). kind: conv | dense | bias | gamma | beta |
@@ -116,34 +126,6 @@ def init(cfg, key):
 
 
 # -------------------------------------------------------------- forward
-FLOAT8_E4M3 = (4, 3)     # (exponent bits, mantissa bits): the control
-BFLOAT16 = (8, 7)        # the witness
-
-
-def _fake_quant(x, rounding):
-    """x rounded to a float of ``rounding`` = (exponent bits, mantissa bits)
-    and back, gradient straight through: the lower-precision control and the
-    bfloat16 witness. ``jax.lax.reduce_precision`` is the operation XLA keeps
-    for this; a cast there and back is one it may drop (on the chip it drops
-    it in a small program and keeps it in a large one). A format with fewer
-    exponent bits than float32 gets a per-tensor scale to its largest finite
-    value and is clipped to it, so nothing overflows."""
-    if rounding is None:
-        return x
-    ebits, mbits = rounding
-
-    @jax.custom_vjp
-    def q(v):
-        if ebits >= 8:
-            return jax.lax.reduce_precision(v, ebits, mbits)
-        top = (2.0 - 2.0 ** -mbits) * 2.0 ** (2 ** (ebits - 1) - 1)
-        s = jnp.maximum(jnp.max(jnp.abs(v)), 1e-30) / top
-        return jax.lax.reduce_precision(jnp.clip(v / s, -top, top), ebits, mbits) * s
-
-    q.defvjp(lambda v: (q(v), None), lambda _, g: (g,))
-    return q(x)
-
-
 def _conv(x, w, stride, rounding):
     pad = (w.shape[0] - 1) // 2
     return jax.lax.conv_general_dilated(

@@ -18,7 +18,7 @@ import sys  # noqa: E402
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from chipbench import check, flops, harness, trace, traffic  # noqa: E402
+from chipbench import check, harness, trace, traffic  # noqa: E402
 
 
 def run(argv, bench_path=None, root=ROOT, require_chip=harness.require_chip):
@@ -43,17 +43,19 @@ def run(argv, bench_path=None, root=ROOT, require_chip=harness.require_chip):
 
     # ---- set-up: program, inputs, the followed first steps (which compile
     # and warm the one shape the cell uses), warm-up
-    n_items = cfg["batch_per_chip"] * chips
+    n_rows = cfg["batch_per_chip"] * chips
+    n_items = n_rows * cfg.get("items_per_row", 1)   # what the rate counts
     net, trainer, mesh, trainable = harness.build_program(
         cfg, ref, args.seed, devices)
     sharding = NamedSharding(mesh, PartitionSpec("dp"))
     stage("program built, making the pool")
-    pool = traffic.make_pool(mix, cfg, args.seed, n_items, sharding)
+    pool = traffic.make_pool(mix, cfg, args.seed, n_rows, sharding)
     feed = traffic.make_feed(mix, pool, sharding)
     steps = mix["followed_steps"]
     stage("following the first %d steps (the first compiles)" % steps)
     prog = harness.follow_program(net, trainer, feed, trainable,
-                                  cfg["optimizer"]["learning_rate"], steps)
+                                  cfg["optimizer"]["learning_rate"], steps,
+                                  cfg.get("weight_layout"))
     stage("followed, warming up")
     for _ in range(mix["warmup_steps"]):
         loss = trainer.step(*feed.next())
@@ -72,7 +74,7 @@ def run(argv, bench_path=None, root=ROOT, require_chip=harness.require_chip):
         path, window_s, tmp = harness.traced_tail(
             trainer, feed, mix["in_flight"], harness.Spans())
         if path:
-            reduced = trace.reduce(trace.read(path), spans=harness.SPANS)
+            reduced = trace.reduce(trace.read(path), spans=harness.PROGRAM_SPANS)
         shutil.rmtree(tmp, ignore_errors=True)
     peak = harness.memory_peak_bytes(devices)
 
@@ -83,18 +85,18 @@ def run(argv, bench_path=None, root=ROOT, require_chip=harness.require_chip):
     jax.clear_caches()   # the step's executable holds its 9 GB of scratch
     stage("program freed, following the reference")
     t_ref = time.perf_counter()
-    refd = harness.follow_reference(cfg, mix, ref, args.seed, n_items,
+    refd = harness.follow_reference(cfg, mix, ref, args.seed, n_rows,
                                     sharding, steps)
     nums = check.numbers(prog, refd)
     rows, correct = check.judge(nums, limits)
     reference_s = time.perf_counter() - t_ref
 
-    layers = ref.conv_layers(cfg)
     run_ = {"cfg": cfg, "mix": mix, "chips": chips, "peaks": peaks,
-            "window": win, "n_items": n_items, "spans": spans.seconds,
+            "window": win, "n_rows": n_rows, "n_items": n_items,
+            "spans": spans.seconds,
             "compiles_window": compiles_window, "compiles_setup": compiles_setup,
             "trace": reduced, "traced_window_s": window_s,
-            "flops_per_item": flops.train_flops_per_item(layers),
+            "flops_per_item": ref.train_flops_per_item(cfg),
             "memory_peak_bytes": peak}
     rate = win["steps"] * n_items / win["seconds"] / chips
     if args.trace:
@@ -119,8 +121,13 @@ def run(argv, bench_path=None, root=ROOT, require_chip=harness.require_chip):
         device["window_s"] = window_s
         result["breakdown"] = {"device_ops": reduced["device_ops"],
                                "idle_gaps": reduced["idle_gaps"]}
+    slow = max(range(win["steps"]), key=win["step_s"].__getitem__)
     result["info"] = {"workload": args.workload, "seed": args.seed,
                       "steps": win["steps"], "window_s": win["seconds"],
+                      # a rate that reads far off: one stall (and when in the
+                      # window), or every step slow?
+                      "longest_step_ms": 1e3 * win["step_s"][slow],
+                      "longest_step_ends_s": sum(win["step_s"][:slow + 1]),
                       "items_per_s_per_chip": rate, "setup_s": setup_s,
                       "reference_s": reference_s, "program": prog["loss"],
                       "reference": refd["loss"],

@@ -9,6 +9,7 @@ import pytest
 from chipbench import harness
 
 ROOT = harness.ROOT
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 BENCH = harness.load_json(ROOT, "BENCHMARK.json")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -50,17 +51,56 @@ def test_names_and_units():
         assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
 
 
-@pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
-def test_every_cell_resolves_to_files(workload):
-    cell, cfg, mix, limits, ref = harness.find_cell(BENCH, workload)
-    assert cfg["name"] == cell["config"] and cfg["reduced"] == []
+# every name check.numbers can emit: a cell's limits file holds some of them
+NUMBERS = ({"loss%d" % n for n in (1, 2, 3)}
+           | {"%s_%s_leaf" % (n, w) for n in ("grad1", "dparam", "ddiff")
+              for w in ("median", "worst")}
+           | {"state1_median_leaf", "sign1_median_leaf"})
+TOKENS = harness.load_json(DATA, "tokenbench", "BENCHMARK.json")
+CELLS = ([(BENCH, ROOT, w["name"]) for w in BENCH["workloads"]]
+         + [(TOKENS, DATA, w["name"]) for w in TOKENS["workloads"]])
+
+
+@pytest.mark.parametrize("bench,root,workload", CELLS, ids=[c[2] for c in CELLS])
+def test_every_cell_resolves_to_files(bench, root, workload):
+    """The real cells, and the token rehearsal's: a configuration cut to one
+    chip's share passes the same rules."""
+    cell, cfg, mix, limits, ref = harness.find_cell(bench, workload, root)
+    entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    assert cfg["name"] == cell["config"]
+    assert os.path.exists(os.path.join(root, entry["file"]))
+    # a cut is stated: the same keys as the entry's, each with its published
+    # value, and the deployment whose one chip the cell is
+    assert cfg["reduced"] == entry["reduced"]
+    assert set(cfg.get("published", {})) == set(cfg["reduced"])
+    for key in cfg["reduced"]:
+        assert cfg[key] != cfg["published"][key], key
+    if cfg["reduced"]:
+        assert cfg["deployment"]["chips_sharing_a_layer"] >= 1
+        assert cfg["deployment"]["how"]
+    assert cfg.get("items_per_row", 1) >= 1 and cfg["item"]
     assert mix["pool"] >= mix["followed_steps"]      # followed rows all differ
-    assert set(limits) == {"grad1_median_leaf", "dparam_median_leaf", "state1_median_leaf"}
-    readers = harness.metric_readers(BENCH, workload)
+    assert limits and set(limits) <= NUMBERS
+    readers = harness.metric_readers(bench, workload, root)
     assert "step.mfu" in readers and "device.idle_share" in readers
-    assert ref.leaf_specs(cfg)
-    for c in BENCH["configs"]:
-        assert os.path.exists(os.path.join(ROOT, c["file"]))
+    assert ref.leaf_specs(cfg) and ref.train_flops_per_item(cfg) > 0
+
+
+def test_the_three_cells_hold_what_they_held():
+    for w in BENCH["workloads"]:
+        _cell, cfg, _mix, limits, _ref = harness.find_cell(BENCH, w["name"])
+        assert limits == {"grad1_median_leaf": 0.05, "dparam_median_leaf": 0.07,
+                          "state1_median_leaf": 0.006}
+        assert cfg["reduced"] == []
+
+
+def test_no_harness_line_names_a_configuration_a_family_or_a_model():
+    words = {c["name"] for c in BENCH["configs"] + TOKENS["configs"]}
+    words |= {"resnet", "vgg", "gated_mlp", "tiny_lm", "kanana", "deepseek"}
+    for name in ("run.py", "harness.py", "traffic.py", "follow.py", "check.py"):
+        with open(os.path.join(harness.HERE, name)) as f:
+            text = f.read().lower()
+        assert not [w for w in words if w.lower() in text], name
 
 
 def test_files_under_paths_keep_to_the_name_characters():

@@ -27,7 +27,7 @@ def test_recorded_trace_busy_idle_and_categories():
     # the two long gaps are the sleeps, when no harness span was open
     gaps = r["idle_gaps"]
     assert gaps[0][1] > 3e-3 and gaps[1][1] > 3e-3 and gaps[2][1] < 1e-4
-    assert gaps[0][0] == "no harness span open"
+    assert gaps[0][0] == "no span open"
     assert r["device_ops"][0][0].endswith("[convolution fusion]")
 
 

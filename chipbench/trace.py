@@ -182,8 +182,8 @@ def reduce(planes, step_module="jit_train_step", spans=()):
 
     Per device: busy_ps (union of its op intervals), per-step time by
     category for the runs of ``step_module``, exposed collective time.
-    ``spans`` names the harness's host annotations; each long idle gap is
-    attributed to the one open at its midpoint.
+    ``spans`` names host annotations (the program's spans); each long idle
+    gap is attributed to those open at its midpoint.
     """
     devices, host = [], None
     for p in planes:
@@ -244,12 +244,13 @@ def reduce(planes, step_module="jit_train_step", spans=()):
 
 def _gaps(busy, host, spans):
     """The ten longest idle gaps between the first and the last op, each
-    named by the harness span open on the host at its midpoint."""
+    named by the spans of ``spans`` open on the host at its midpoint."""
     open_spans = []
     if host is not None:
         for evs in host["lines"].values():
             for s, d, mid in evs:
-                nm = host["meta"].get(mid, ("",))[0]
+                # a step annotation may carry its number behind a '#'
+                nm = host["meta"].get(mid, ("",))[0].split("#", 1)[0]
                 if nm in spans:
                     open_spans.append((s, s + d, nm))
     gaps = sorted(((b[0] - a[1], a[1], b[0])
@@ -258,5 +259,5 @@ def _gaps(busy, host, spans):
     for length, s, e in gaps:
         mid = (s + e) // 2
         names = sorted({nm for a, b, nm in open_spans if a <= mid < b})
-        out.append(["+".join(names) or "no harness span open", length / 1e12])
+        out.append(["+".join(names) or "no span open", length / 1e12])
     return out
