@@ -5,8 +5,10 @@ its simplest policy-gradient form, with the environment implemented inline
 
 What it exercises: a stochastic policy head sampled OUTSIDE autograd, the
 log-prob trick (loss = -sum log pi(a|s) * return) recorded inside, reward
-normalization, and episodic training where batch size varies per episode
-(dynamic host-side loop around static per-step graphs).
+normalization, and episodic training where the episode length varies: the
+update runs on the episode padded to the environment's 200-step cap with
+return 0 on the padding (which adds 0 to the loss), so every recorded op
+keeps one shape and compiles once instead of once per new length.
 
 Reference parity: /root/reference/example/reinforcement-learning/
 parallel_actor_critic/ (policy-gradient loss over episode returns).
@@ -20,6 +22,8 @@ from mxnet_tpu.gluon import nn
 
 class CartPole:
     """Classic cart-pole dynamics (Barto-Sutton-Anderson), 200-step cap."""
+
+    MAX_STEPS = 200
 
     def __init__(self, rng):
         self.rng = rng
@@ -45,7 +49,7 @@ class CartPole:
                            th + self.dt * thd, thd + self.dt * thacc])
         self.t += 1
         done = (abs(self.s[0]) > 2.4 or abs(self.s[2]) > 0.21
-                or self.t >= 200)
+                or self.t >= self.MAX_STEPS)
         return self.s.copy(), 1.0, done
 
 
@@ -85,6 +89,9 @@ def train(episodes=120, gamma=0.99, lr=0.01, seed=0, verbose=True):
             acc = 1.0 + gamma * acc
             rets[t] = acc
         rets = (rets - rets.mean()) / (rets.std() + 1e-6)
+        pad = env.MAX_STEPS - T
+        states = np.pad(states, ((0, pad), (0, 0)))
+        actions, rets = np.pad(actions, (0, pad)), np.pad(rets, (0, pad))
         with autograd.record():
             logits = net(mx.nd.array(states))
             logp = mx.nd.log_softmax(logits, axis=1)

@@ -85,9 +85,18 @@ def train(epochs=15, batch_size=64, lr=8.0, seed=0, verbose=True):
             # row_sparse gradient: only rows for features present in the
             # batch — X^T (p - y) restricted to touched feature ids
             touched = np.unique(np.nonzero(xb)[1])
-            gw_rows = mx.nd.array(xb[:, touched]).T @ err
-            grad = sp.row_sparse_array(
-                (gw_rows.asnumpy(), touched.astype("int64")), shape=(dim, 1))
+            # the number of touched rows differs in every batch, and each new
+            # width would compile the matmul and the update again: pad it to
+            # the next power of two with a repeated row id whose gradient is
+            # zero, which the lazy update merges away
+            n = len(touched)
+            ids = np.full(1 << (n - 1).bit_length(), touched[0], "int64")
+            ids[:n] = touched
+            cols = np.zeros((len(sel), len(ids)), "float32")
+            cols[:, :n] = xb[:, touched]
+            gw_rows = mx.nd.array(cols).T @ err
+            grad = sp.row_sparse_array((gw_rows.asnumpy(), ids),
+                                       shape=(dim, 1))
             updater(0, grad, w)                      # lazy: touched rows only
             updater(1, mx.nd.array([float(err.asnumpy().sum())]), b)
     last = accuracy()

@@ -13,6 +13,7 @@ its C++ threaded engine stays hidden here (SURVEY.md stage 3 / hard part #2).
 """
 from __future__ import annotations
 
+import functools
 import inspect
 from typing import Any, Dict, Sequence
 
@@ -20,7 +21,8 @@ import jax
 
 from . import random as _random
 from .base import MXNetError
-from .ops.registry import OpDef, get_op, jitted_op, normalize_attrs
+from .ops.registry import (OpDef, get_op, jitted_op, jitted_op_vjp,
+                           normalize_attrs, op_vjp, pullback)
 
 __all__ = ["invoke", "invoke_raw"]
 
@@ -35,10 +37,9 @@ def _op_signature_flags(opdef: OpDef):
     return opdef._sig_flags
 
 
-def invoke_raw(op_name: str, inputs: Sequence[Any], attrs: Dict[str, Any],
-               is_train: bool = None):
-    """Run an op on raw jax arrays, returning raw jax array(s)."""
-    opdef = get_op(op_name)
+def _prepare(opdef: OpDef, inputs, attrs, is_train: bool = None):
+    """The one preparation of an op call, recorded or not: fill ``is_train``
+    and ``rng``, normalise the attrs. Returns ``(attr key, rng kwargs)``."""
     accepts_train, accepts_rng = _op_signature_flags(opdef)
     attrs = dict(attrs)
     if accepts_train and "is_train" not in attrs:
@@ -47,27 +48,38 @@ def invoke_raw(op_name: str, inputs: Sequence[Any], attrs: Dict[str, Any],
     if accepts_rng and attrs.get("rng") is None:
         attrs["rng"] = _random.next_key()
     rng = attrs.pop("rng", None)
-    if rng is not None:
-        for v in inputs:
-            if hasattr(v, "devices"):
-                rng = jax.device_put(rng, list(v.devices())[0])
-                break
-    key = normalize_attrs(attrs)
-    if opdef.host:
-        # host op: no fixed-shape XLA lowering exists; run eagerly
-        if rng is not None:
-            return opdef.fn(*inputs, rng=rng, **dict(key))
-        return opdef.fn(*inputs, **dict(key))
-    fn = jitted_op(opdef.name, key)
-    try:
-        if rng is not None:
-            return fn(*inputs, rng=rng)
-        return fn(*inputs)
-    except TypeError:
-        # attrs that aren't jit-static-friendly: fall back to eager
-        if rng is not None:
-            return opdef.fn(*inputs, rng=rng, **dict(key))
-        return opdef.fn(*inputs, **dict(key))
+    if rng is None:
+        return normalize_attrs(attrs), {}
+    for v in inputs:
+        if hasattr(v, "devices"):
+            rng = jax.device_put(rng, list(v.devices())[0])
+            break
+    return normalize_attrs(attrs), {"rng": rng}
+
+
+def _dispatch(opdef: OpDef, key, inputs, kw, diff_idx=None):
+    """Run a prepared op through its cached executable. Returns its outputs,
+    or ``(outputs, pullback)`` w.r.t. ``inputs[diff_idx]`` when recording."""
+    if not opdef.host:   # a host op has no fixed-shape XLA lowering
+        try:
+            if diff_idx is None:
+                return jitted_op(opdef.name, key)(*inputs, **kw)
+            out, vjp = jitted_op_vjp(opdef.name, key, diff_idx)(inputs, kw)
+            return out, functools.partial(pullback, vjp)
+        except TypeError:
+            pass         # attrs or values jit cannot take: run eagerly
+    fn = functools.partial(opdef.fn, **dict(key))
+    if diff_idx is None:
+        return fn(*inputs, **kw)
+    return op_vjp(fn, diff_idx)(inputs, kw)
+
+
+def invoke_raw(op_name: str, inputs: Sequence[Any], attrs: Dict[str, Any],
+               is_train: bool = None):
+    """Run an op on raw jax arrays, returning raw jax array(s)."""
+    opdef = get_op(op_name)
+    key, kw = _prepare(opdef, inputs, attrs, is_train)
+    return _dispatch(opdef, key, inputs, kw)
 
 
 def invoke(op_name: str, inputs, attrs, out=None):
@@ -83,7 +95,7 @@ def invoke(op_name: str, inputs, attrs, out=None):
     t0 = profiler._prof.us() if profiling else 0.0
 
     if autograd.is_recording() and opdef.differentiable:
-        out_data = autograd._record_invoke(opdef, inputs, in_datas, dict(attrs))
+        out_data = autograd._record_invoke(opdef, inputs, in_datas, attrs)
     else:
         out_data = invoke_raw(op_name, in_datas, attrs)
 

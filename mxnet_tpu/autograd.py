@@ -5,11 +5,12 @@ predict_mode/backward/grad/Function) over ``src/imperative/imperative.cc``
 (``RecordOp`` :191, ``Backward`` :278, AGInfo tagging).
 
 TPU-first: instead of building an NNVM gradient graph and scheduling it on a
-C++ engine, each recorded op captures its ``jax.vjp`` closure (forward runs
-exactly once; the closure holds XLA-resident residuals). ``backward()`` walks
-the tape in reverse creation order accumulating cotangents — every vjp call
-is itself a cached XLA executable, so the backward pass is a sequence of
-async device dispatches just like forward.
+C++ engine, each recorded op runs the cached executable that returns its
+outputs and its ``jax.vjp`` pullback (``ops.registry.jitted_op_vjp``: forward
+runs exactly once; the pullback holds XLA-resident residuals). ``backward()``
+walks the tape in reverse creation order accumulating cotangents — every
+pullback call is itself a cached XLA executable, so the backward pass is a
+sequence of async device dispatches just like forward.
 """
 from __future__ import annotations
 
@@ -20,7 +21,6 @@ import jax
 import jax.numpy as jnp
 
 from .base import MXNetError
-from . import random as _random
 
 __all__ = ["record", "pause", "train_mode", "predict_mode", "is_recording",
            "is_training", "set_recording", "set_training", "backward", "grad",
@@ -132,37 +132,25 @@ def _float_ok(x) -> bool:
 
 
 def _record_invoke(opdef, inputs, in_datas, attrs):
-    """Run ``opdef`` under jax.vjp, record a tape node. Called from
-    _imperative.invoke while recording."""
+    """Run ``opdef`` through its cached (outputs, pullback) executable and
+    record a tape node. Called from _imperative.invoke while recording."""
+    from ._imperative import _prepare, _dispatch
     st = _st()
-    from ._imperative import _op_signature_flags
-    accepts_train, accepts_rng = _op_signature_flags(opdef)
-    if accepts_train and "is_train" not in attrs:
-        attrs["is_train"] = st.training
-    if accepts_rng and attrs.get("rng") is None:
-        attrs["rng"] = _random.next_key()
-    rng = attrs.pop("rng", None)
-
-    diff_idx = [i for i, d in enumerate(in_datas)
-                if hasattr(d, "dtype") and _float_ok(d)]
-    nondiff = {i: d for i, d in enumerate(in_datas) if i not in diff_idx}
+    key, kw = _prepare(opdef, in_datas, attrs)
+    diff_idx = tuple(i for i, d in enumerate(in_datas)
+                     if hasattr(d, "dtype") and _float_ok(d))
+    if not diff_idx:
+        st.pending_nodes = None
+        return _dispatch(opdef, key, in_datas, kw)
+    out, vjp_fn = _dispatch(opdef, key, in_datas, kw, diff_idx)
 
     def closed(*diff_args):
         full = list(in_datas)
-        for j, i in enumerate(diff_idx):
-            full[i] = diff_args[j]
-        kw = dict(attrs)
-        if rng is not None:
-            kw["rng"] = rng
-        return opdef.fn(*full, **kw)
+        for i, d in zip(diff_idx, diff_args):
+            full[i] = d
+        return _dispatch(opdef, key, full, kw)
 
     diff_args = [in_datas[i] for i in diff_idx]
-    if not diff_args:
-        out = closed()
-        st.pending_nodes = None
-        return out
-    out, vjp_fn = jax.vjp(closed, *diff_args)
-
     parents, slots = [], []
     for i in diff_idx:
         node = getattr(inputs[i], "_ag_node", None)

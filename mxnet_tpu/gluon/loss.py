@@ -115,8 +115,8 @@ class KLDivLoss(Loss):
 
 class CTCLoss(Loss):
     """Connectionist temporal classification (reference gluon/loss.py:CTCLoss
-    over src/operator/contrib/ctc_loss.cc; here optax.ctc_loss provides the
-    log-domain DP as XLA while-loops)."""
+    over src/operator/contrib/ctc_loss.cc; here the registered ``CTCLoss`` op
+    provides the log-domain DP as XLA while-loops)."""
 
     def __init__(self, layout="NTC", label_layout="NT", weight=None, **kwargs):
         super().__init__(weight, 0, **kwargs)
@@ -125,49 +125,7 @@ class CTCLoss(Loss):
 
     def hybrid_forward(self, F, pred, label, pred_lengths=None, label_lengths=None,
                        sample_weight=None):
-        import jax.numpy as jnp
-        import optax
-        from ..ndarray.ndarray import NDArray, _wrap, _unwrap
-        from .. import autograd as ag
-
-        if isinstance(pred, NDArray):
-            logits = _unwrap(pred)
-            labels = _unwrap(label).astype(jnp.int32)
-            if self._layout == "TNC":
-                logits = jnp.swapaxes(logits, 0, 1)
-            if self._label_layout == "TN":
-                labels = labels.T
-            b, t, c = logits.shape
-            logit_pad = jnp.zeros((b, t)) if pred_lengths is None else (
-                jnp.arange(t)[None, :] >= _unwrap(pred_lengths)[:, None]).astype(jnp.float32)
-            lmax = labels.shape[1]
-            if label_lengths is not None:
-                lab_pad = (jnp.arange(lmax)[None, :] >=
-                           _unwrap(label_lengths)[:, None]).astype(jnp.float32)
-            else:
-                lab_pad = (labels < 0).astype(jnp.float32)
-            # gluon convention: index alphabet_size-1 is the blank
-            # (reference gluon/loss.py:475 blank_label='last'), labels are
-            # 0-based and must never equal the blank id
-            blank = c - 1
-            if ag.is_recording():
-                import jax as _jax
-                out, vjp = _jax.vjp(lambda lg: optax.ctc_loss(
-                    lg, logit_pad, jnp.maximum(labels, 0), lab_pad,
-                    blank_id=blank), logits)
-                st = ag._st()
-                node = ag._Node(lambda ct: vjp(ct), [getattr(pred, "_ag_node", None)],
-                                [getattr(pred, "_ag_slot", 0)], 1, st.counter, "CTCLoss")
-                st.counter += 1
-                st.tape.append(node)
-                w = _wrap(out)
-                w._ag_node = node
-                w._ag_slot = 0
-                return w
-            return _wrap(optax.ctc_loss(logits, logit_pad,
-                                        jnp.maximum(labels, 0), lab_pad,
-                                        blank_id=blank))
-        # symbolic path: route through the registered CTCLoss op (TNC
+        # one route, imperative or symbolic: the registered CTCLoss op (TNC
         # layout, gluon blank-last convention, -1 label padding)
         p = pred if self._layout == "TNC" else F.transpose(pred,
                                                            axes=(1, 0, 2))
@@ -175,9 +133,8 @@ class CTCLoss(Loss):
             label, axes=(1, 0))
         # the op's positional arg list is fixed; unused length slots get
         # zero placeholders the kernel ignores (use_*_lengths=False)
-        import mxnet_tpu.symbol as _sym
-        pl = pred_lengths if pred_lengths is not None else _sym.zeros((1,))
-        ll = label_lengths if label_lengths is not None else _sym.zeros((1,))
+        pl = pred_lengths if pred_lengths is not None else F.zeros((1,))
+        ll = label_lengths if label_lengths is not None else F.zeros((1,))
         return F.CTCLoss(p, lab, pl, ll, blank_label="last",
                          use_data_lengths=pred_lengths is not None,
                          use_label_lengths=label_lengths is not None)

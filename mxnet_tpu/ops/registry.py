@@ -186,3 +186,35 @@ def jitted_op(name: str, attr_items: Tuple[Tuple[str, Any], ...]):
     attrs = dict(attr_items)
     fn = functools.partial(opdef.fn, **attrs)
     return jax.jit(fn)
+
+
+def op_vjp(fn, diff_idx: Tuple[int, ...]):
+    """``(inputs, kw) -> (outputs, pullback)`` of ``fn(*inputs, **kw)`` with
+    respect to ``inputs[i] for i in diff_idx``; the pullback is a pytree of
+    residuals, so the pair can cross a ``jit`` boundary."""
+    def pair(inputs, kw):
+        def closed(*diff):
+            full = list(inputs)
+            for i, d in zip(diff_idx, diff):
+                full[i] = d
+            return fn(*full, **kw)
+        return jax.vjp(closed, *(inputs[i] for i in diff_idx))
+    return pair
+
+
+@functools.lru_cache(maxsize=16384)
+def jitted_op_vjp(name: str, attr_items: Tuple[Tuple[str, Any], ...],
+                  diff_idx: Tuple[int, ...]):
+    """The recorded form of ``jitted_op``: forward and residuals of one op as
+    ONE cached executable per (op, attrs, differentiable slots, shapes), so
+    a steady-state step under ``autograd.record()`` neither traces nor
+    compiles. ``pullback`` below runs what it returns."""
+    return jax.jit(op_vjp(jitted_op(name, attr_items), diff_idx))
+
+
+@jax.jit
+def pullback(vjp, ct):
+    """Run a pullback that ``jitted_op_vjp`` returned. Its treedef (the
+    backward jaxpr) is the same object on every call of one compiled
+    forward, so this too compiles once per (op, attrs, shapes)."""
+    return vjp(ct)

@@ -528,6 +528,20 @@ class Test(Optimizer):
         weight._set_data(_unwrap(weight) - self.lr * _unwrap(grad) * self.rescale_grad)
 
 
+@jax.jit
+def _lazy_sgd_rows(w, idx, vals, lr, wd, rescale, clip):
+    """SGD on the rows ``idx`` of ``w`` only, one executable per shape."""
+    # merge duplicate indices first — the raw (values, indices) ctor permits
+    # them, and todense() sums them, so the lazy path must too. ``size=``
+    # keeps the shape static whatever the number of duplicates; the fill is
+    # out of range, so the scatter drops it
+    uniq, inv = jnp.unique(idx, return_inverse=True, size=idx.shape[0],
+                           fill_value=w.shape[0])
+    g = jnp.clip(jnp.zeros_like(vals).at[inv].add(vals) * rescale, -clip, clip)
+    rows = w[uniq]
+    return w.at[uniq].set(rows - lr * (g + wd * rows), mode="drop")
+
+
 class Updater:
     """Closure applying an optimizer with per-index states (reference
     optimizer.py:Updater; serialized to KVStore servers via get_states)."""
@@ -566,23 +580,12 @@ class Updater:
                 and getattr(opt, "lazy_update", True)
                 and not getattr(opt, "multi_precision", False)):
             return False
-        import jax.numpy as jnp
         opt._update_count(index)
-        lr = opt._get_lr(index)
-        wd = opt._get_wd(index)
-        # merge duplicate indices first — the raw (values, indices) ctor
-        # permits them, and todense() sums them, so the lazy path must too
-        idx = jnp.asarray(grad._indices).astype(jnp.int32)
-        vals = jnp.asarray(grad._values)
-        uniq, inv = jnp.unique(idx, return_inverse=True)
-        g = jnp.zeros((uniq.shape[0],) + vals.shape[1:],
-                      vals.dtype).at[inv].add(vals)
-        g = g * opt.rescale_grad
-        if getattr(opt, "clip_gradient", None):
-            g = jnp.clip(g, -opt.clip_gradient, opt.clip_gradient)
-        w = weight._data
-        rows = w[uniq]
-        weight._set_data(w.at[uniq].set(rows - lr * (g + wd * rows)))
+        if grad._indices.shape[0]:
+            weight._set_data(_lazy_sgd_rows(
+                weight._data, grad._indices.astype(jnp.int32), grad._values,
+                opt._get_lr(index), opt._get_wd(index), opt.rescale_grad,
+                getattr(opt, "clip_gradient", None) or math.inf))
         return True
 
     def get_states(self, dump_optimizer=False):

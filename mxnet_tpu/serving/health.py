@@ -640,7 +640,8 @@ class HedgeMonitor:
     def start(self) -> "HedgeMonitor":
         if self._thread is not None and self._thread.is_alive():
             return self
-        self._stopped = False
+        with self._cond:
+            self._stopped = False
         t = threading.Thread(target=self._run, daemon=True,
                              name="mxserve-hedge")
         self._thread = t

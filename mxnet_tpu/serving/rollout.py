@@ -277,6 +277,7 @@ class RolloutManager:
         self._lock = make_lock("serving.rollout.RolloutManager._lock")
         self._rollouts: Dict[str, Rollout] = {}
         self._live: Dict[str, str] = {}     # model -> promoted version id
+        self._shadows: set = set()          # live shadow-dispatch threads
         self._next_tick = 0.0
         server._rollout = self
 
@@ -454,8 +455,29 @@ class RolloutManager:
         executable on the same input, score top-1 agreement. The canary
         NEVER answers the request — a shadow failure is evidence,
         not an error the client sees."""
-        threading.Thread(target=self._shadow_run, args=(ro, req),
-                         daemon=True, name="mxserve-shadow").start()
+        t = threading.Thread(target=self._shadow_thread, args=(ro, req),
+                             daemon=True, name="mxserve-shadow")
+        with self._lock:
+            self._shadows.add(t)
+        t.start()
+
+    def _shadow_thread(self, ro: Rollout, req) -> None:
+        try:
+            self._shadow_run(ro, req)
+        finally:
+            with self._lock:
+                self._shadows.discard(threading.current_thread())
+
+    def join_shadows(self, timeout: float) -> None:
+        """Wait (bounded) for the shadow dispatches in flight. The server's
+        close calls it: a daemon thread still inside the canary's XLA
+        executable when the interpreter exits aborts the process (the
+        forced unwind of a finalizing interpreter meets C++ frames)."""
+        deadline = time.monotonic() + timeout
+        with self._lock:
+            threads = list(self._shadows)
+        for t in threads:
+            t.join(max(0.0, deadline - time.monotonic()))
 
     def _shadow_run(self, ro: Rollout, req) -> None:
         can = ro.canary
@@ -481,11 +503,12 @@ class RolloutManager:
             return      # incumbent never answered ok: nothing to compare
         inc_top = int(np.argmax(np.atleast_1d(
             np.asarray(value).ravel())))
+        data = np.asarray(req.data)     # host transfer: not under the lock
         with self._lock:
             ro.shadow_n += 1
             ro.agree.append(1 if canary_top == inc_top else 0)
             del ro.agree[:-_AGREE_WINDOW]
-            ro.shadow_inputs.append(np.asarray(req.data))
+            ro.shadow_inputs.append(data)
             del ro.shadow_inputs[:-_SHADOW_BUFFER]
         self._publish_agreement(ro)
 

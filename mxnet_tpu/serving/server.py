@@ -143,18 +143,22 @@ class PendingResult:
 
     def outcome(self) -> Optional[str]:
         """'ok' | 'shed' | 'expired' | 'error' once completed."""
-        return self._outcome
+        with self._win:
+            return self._outcome
 
     def error(self) -> Optional[BaseException]:
         self._ev.wait()
-        return self._error
+        with self._win:
+            return self._error
 
     def result(self, timeout: Optional[float] = None) -> np.ndarray:
         if not self._ev.wait(timeout):
             raise TimeoutError("result not ready")
-        if self._error is not None:
-            raise self._error
-        return self._value
+        with self._win:
+            error, value = self._error, self._value
+        if error is not None:
+            raise error
+        return value
 
     def _claim(self, value=None, error=None, outcome="ok") -> bool:
         """Atomically claim the result WITHOUT waking waiters — the
@@ -497,6 +501,9 @@ class ModelServer:
                 self._complete(st, req, error=Draining(
                     "server closed before this request was dispatched"),
                     outcome="shed", reason="draining")
+        if self._rollout is not None:
+            # after the sweep above: a shadow waits on its request's answer
+            self._rollout.join_shadows(timeout)
         self._stopped = True
         if self._guard is not None:
             from ..resilience import preemption

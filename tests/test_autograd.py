@@ -139,3 +139,66 @@ def test_exception_surfaces_at_sync(rng):
     with pytest.raises(Exception):
         z = nd.dot(x, y)  # incompatible shapes
         z.wait_to_read()
+
+
+def test_recorded_step_compiles_once_and_matches_plain_jax_grad():
+    """A steady-state imperative step traces and compiles nothing: every
+    recorded op, the scan-holding RNN and CTCLoss among them, runs its cached
+    (outputs, pullback) executable. The reference is the same computation written as
+    one plain jax.grad over the ops' raw fns."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import gluon
+    from mxnet_tpu.observability.jit_hooks import JIT_COMPILES, JIT_TRACES
+    from mxnet_tpu.ops.registry import get_op
+
+    T, B, I, H, C = 6, 3, 5, 8, 4
+
+    class Net(gluon.Block):
+        def __init__(self):
+            super().__init__()
+            with self.name_scope():
+                self.rnn = gluon.rnn.GRU(H, layout="NTC", input_size=I)
+                self.out = gluon.nn.Dense(C, flatten=False, in_units=H)
+
+        def forward(self, x):
+            return self.out(self.rnn(x))
+
+    def reference(p, x, label):
+        flat = jnp.concatenate([p["l0_i2h_weight"].ravel(),
+                                p["l0_h2h_weight"].ravel(),
+                                p["l0_i2h_bias"], p["l0_h2h_bias"]])
+        seq = get_op("RNN").fn(
+            jnp.swapaxes(x, 0, 1), flat, jnp.zeros((1, B, H)), state_size=H,
+            num_layers=1, mode="gru", state_outputs=True, is_train=True)[0]
+        logits = get_op("FullyConnected").fn(
+            seq, p["weight"], p["bias"], num_hidden=C, flatten=False)
+        return get_op("CTCLoss").fn(logits, label, blank_label="last").sum()
+
+    mx.random.seed(3)
+    net = Net()
+    net.initialize(mx.init.Xavier())
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1})
+    ctc = gluon.loss.CTCLoss()
+    rs = np.random.RandomState(0)
+    x = nd.array(rs.randn(B, T, I))
+    label = nd.array([[0, 1, -1], [2, 2, 1], [1, -1, -1]])
+    params = {re.sub(r"^.*(gru|dense)\d+_", "", k): v
+              for k, v in net.collect_params().items()}
+    for step in range(3):
+        before = {k: v.data()._data for k, v in params.items()}
+        n0 = (JIT_TRACES.value(), JIT_COMPILES.value())
+        with autograd.record():
+            L = ctc(net(x), label)
+        L.backward()
+        trainer.step(B)
+        loss = L.asnumpy().sum()
+        grads = {k: v.data().grad.asnumpy() for k, v in params.items()}
+    assert (JIT_TRACES.value(), JIT_COMPILES.value()) == n0
+    ref_loss, ref_grads = jax.value_and_grad(reference)(
+        before, x._data, label._data)
+    assert_almost_equal(loss, np.asarray(ref_loss), rtol=1e-6, atol=1e-6)
+    for k, g in grads.items():
+        assert_almost_equal(g, np.asarray(ref_grads[k]), rtol=1e-6, atol=1e-6)

@@ -17,7 +17,7 @@ import pytest
 
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.observability import catalog
-from mxnet_tpu.serving import (MemoryBudgetExceeded, ModelConfig,
+from mxnet_tpu.serving import (Draining, MemoryBudgetExceeded, ModelConfig,
                                ModelServer, RolloutManager,
                                ServingEndpoints)
 from mxnet_tpu.serving import chaos as schaos
@@ -72,7 +72,16 @@ def _pump(srv, payload, n, model="m", rng=None):
     shape = np.asarray(payload).shape
     mk = (lambda: payload) if rng is None \
         else (lambda: rng.randn(*shape).astype(np.float32))
-    futs = [srv.submit(model, mk()) for _ in range(n)]
+    def submit():
+        # a submit that races a version's retirement gets the documented
+        # typed Draining at admission; like a client it retries, and the
+        # splitter by then points at the live version
+        try:
+            return srv.submit(model, mk())
+        except Draining:
+            return srv.submit(model, mk())
+
+    futs = [submit() for _ in range(n)]
     out = {"ok": 0, "error": 0}
     for f in futs:
         try:
@@ -153,8 +162,12 @@ def test_happy_path_auto_promotes_to_100_and_hot_swaps(tiny):
     before = catalog.SERVE_REQUESTS.value(model="m", outcome="ok")
     try:
         mgr = RolloutManager.attach(srv)
+        # identical weights: a p99 gap between the two versions is the
+        # box's load, not the canary's doing — the latency gate has its
+        # own test (test_latency_storm_canary_trips_p99_gate), so here
+        # its slack is wide enough that only the ramp is under test
         ro = mgr.start("m", "v2", dwell_s=0.05, min_shadow=3,
-                       min_requests=2, shadow_sample=0.5)
+                       min_requests=2, shadow_sample=0.5, p99_slack=1e3)
         _wait_serving(srv)
         submitted = ok = 0
         deadline = time.monotonic() + 60.0
