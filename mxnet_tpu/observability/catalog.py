@@ -58,6 +58,17 @@ CONV_S2D_LOWERED = _m.counter(
     "compiles, never with steps; a stem net whose counter stays 0 reached "
     "the op in a shape the lowering does not take (NCHW, odd height).")
 
+POOL_BWD_LOWERED = _m.counter(
+    "mxtpu_pool_bwd_lowered_total",
+    "Max pools whose backward scatters the gradient from the saved winning "
+    "tap of each window and not through select-and-scatter (a TPU process; "
+    "2-D, <= 9 taps, bfloat16/float32; per device a multiple of 128 rows, "
+    "channels of 32, height and width of the strides). Counted when a "
+    "DIFFERENTIATED pool is traced: once per capture of a training step, "
+    "never per step and never for an inference trace; a net whose counter "
+    "stays 0 reached the op in a shape, or in a program whose split of the "
+    "batch the op cannot see, that keeps reduce_window's own gradient.")
+
 # -------------------------------------------------------------------- io
 IO_BATCHES = _m.counter(
     "mxtpu_io_batches_total",

@@ -12,6 +12,8 @@ the same HLO computation. The cuDNN algo-selection registry
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -182,6 +184,148 @@ def _deconvolution(data, weight, bias=None, kernel=(), stride=(), dilate=(), pad
 
 
 # ---------------------------------------------------------------- Pooling
+#: a window of this many taps or fewer keeps its winner in one int8; every
+#: zoo net's pool is 3x3 or 2x2, a global pool's window is the whole map
+_POOL_MAX_TAPS = 9
+
+
+def _pool_batch_split():
+    """How ``jit`` splits the batch of a pool traced here, as far as the op
+    can see: ``()`` where the op holds its own rows (a mesh of one device;
+    inside a ``shard_map``, every axis Manual; no mesh named in a process
+    with a single device), ``(mesh, axis)`` on a named mesh with ONE axis of
+    several devices (``DataParallelTrainer`` names its mesh around its
+    gradient), None where the op cannot tell: several such axes, of which it
+    cannot see the one that holds the batch, or no mesh named where there
+    are several devices. A Mosaic kernel is not partitioned automatically,
+    so where the op cannot tell it keeps ``reduce_window``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return () if jax.device_count() == 1 else None
+    split = [a for a in mesh.axis_names
+             if mesh.shape[a] > 1 and a not in mesh.manual_axes]
+    if not split:
+        return ()
+    if len(split) > 1 or mesh.manual_axes:
+        return None
+    return mesh, split[0]
+
+
+def _pool_tap_eligible(data, lhs, kernel, stride, global_pool):
+    """Whether a max pool's backward scatters from the saved winning tap
+    (``_max_pool_taps``) and not through ``select-and-scatter``: a 2-D window
+    of few taps over bfloat16/float32 where the Pallas kernels run (a TPU
+    process, or their interpreter), with whole blocks for them in EACH
+    device's share of the batch (a multiple of 128 rows, channels of 32,
+    height and width of the strides, the row blocks inside VMEM). Decided
+    from what the op sees, no knob; everything else keeps ``reduce_window``'s
+    own gradient. So does a value that varies over the axes of a ``shard_map``
+    that checks them: the kernels' constants would not type there."""
+    if global_pool or len(kernel) != 2 \
+            or kernel[0] * kernel[1] > _POOL_MAX_TAPS \
+            or data.dtype not in (jnp.bfloat16, jnp.float32) \
+            or jax.typeof(data).vma:
+        return False
+    from . import pallas_kernels as _pk
+    split = _pool_batch_split() if _pk.use_pallas() else None
+    if split is None:
+        return False
+    h, w, c, n = (data.shape[lhs.index(a)] for a in "HWCN")
+    shards = split[0].shape[split[1]] if split else 1
+    return n % shards == 0 and _pk.pool_eligible(
+        (h, w, c, n // shards), kernel, stride, data.dtype.itemsize)
+
+
+def _per_shard(kernel_fn, lhs, split):
+    """``kernel_fn`` on each device's own rows where ``jit`` splits the batch
+    over a mesh axis (``_pool_batch_split``): every argument and result
+    carries the batch on the same dimension."""
+    if not split:
+        return kernel_fn
+    from jax.sharding import PartitionSpec
+    mesh, axis = split
+    spec = PartitionSpec(*(axis if a == "N" else None for a in lhs))
+    return jax.shard_map(kernel_fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
+
+
+@functools.lru_cache(maxsize=None)
+def _max_pool_taps(lhs, kernel, stride, padding, split=()):
+    """Max pooling whose backward, on the TPU, needs neither the input nor
+    ``select-and-scatter``. The value is ``reduce_window``'s. Under
+    differentiation the forward also keeps, per OUTPUT element, the first tap
+    in row-major window order that equals the maximum (int8: the element
+    ``select-and-scatter`` with ``ge`` picks, so ties go where they went), and
+    the backward gives each input position the dy of the windows whose saved
+    tap it is, summed in float32: two Pallas kernels (PERF.md, PR 28), each
+    device on its own rows (``split``, as the op saw it when it was traced).
+    Where the program is lowered for another platform (a CPU context in a
+    TPU process) both are ``reduce_window``'s own, from the input."""
+    axes = [lhs.index("H"), lhs.index("W")]
+    window = tuple(kernel[axes.index(d)] if d in axes else 1 for d in range(4))
+    strides = tuple(stride[axes.index(d)] if d in axes else 1 for d in range(4))
+    hwcn = tuple(lhs.index(a) for a in "HWCN")
+    back = tuple(hwcn.index(d) for d in range(4))
+    geometry = (kernel, stride, (padding[axes[0]][0], padding[axes[1]][0]))
+    from . import pallas_kernels as _pk
+
+    def route(on_chip, elsewhere, *args):
+        """By the platform the program is lowered FOR, not the default
+        backend; under the Pallas interpreter (the unit tests) the kernels."""
+        if _pk._interpret():
+            return on_chip(*args)
+        return lax.platform_dependent(*args, tpu=on_chip, default=elsewhere)
+
+    def plain(x):
+        return lax.reduce_window(x, -jnp.inf, lax.max, window, strides, padding)
+
+    def fwd_kernel(x):
+        n_out = tuple((x.shape[a] + sum(padding[a]) - k) // s + 1
+                      for a, k, s in zip(axes, kernel, stride))
+        out, idx = _pk.max_pool_fwd(x.transpose(hwcn), n_out, *geometry)
+        return out.transpose(back), idx.transpose(back)
+
+    def fwd_plain(x):
+        out = plain(x)
+        return out, jnp.zeros(out.shape, jnp.int8)      # read by nothing
+
+    def fwd(x):
+        # runs when a differentiated pool is traced: once per trace
+        from ..observability import catalog as _catalog, metrics as _metrics
+        if _metrics.enabled():
+            _catalog.POOL_BWD_LOWERED.inc()
+        out, idx = route(_per_shard(fwd_kernel, lhs, split), fwd_plain, x)
+        # x is for the other platforms' gradient: on the TPU nothing reads
+        # it after the forward, and inside one program XLA lets it go
+        return out, (idx, x)
+
+    def bwd(res, dy):
+        idx, x = res
+
+        def bwd_kernel(idx, dy):
+            # dy as its producer makes it, then the bitcast: without the
+            # barrier XLA writes the transpose INTO the convolution fusion
+            # that produces dy and tiles it worse (+1.0 ms a step at
+            # [512,56,56,64]; PERF.md, PR 28)
+            dy = lax.optimization_barrier(dy)
+            dx = _pk.max_pool_bwd(
+                idx.transpose(hwcn), dy.transpose(hwcn),
+                (x.shape[axes[0]], x.shape[axes[1]]), *geometry)
+            return dx.transpose(back)
+
+        def on_chip(idx, x, dy):
+            return _per_shard(bwd_kernel, lhs, split)(idx, dy)
+
+        def bwd_plain(idx, x, dy):
+            return jax.vjp(plain, x)[1](dy)[0]
+
+        return (route(on_chip, bwd_plain, idx, x, dy),)
+
+    pool = jax.custom_vjp(plain)
+    pool.defvjp(fwd, bwd)
+    return pool
+
+
 @register("Pooling", arg_names=("data",))
 def _pooling(data, kernel=(), pool_type="max", global_pool=False, stride=(), pad=(),
              pooling_convention="valid", cudnn_off=False, p_value=2,
@@ -214,8 +358,12 @@ def _pooling(data, kernel=(), pool_type="max", global_pool=False, stride=(), pad
     strides = tuple(strides)
     padding = tuple(padding)
     if pool_type == "max":
-        init = -jnp.inf
-        out = lax.reduce_window(data, init, lax.max, window, strides, padding)
+        if _pool_tap_eligible(data, lhs, kernel, stride, global_pool):
+            out = _max_pool_taps(lhs, kernel, stride, padding,
+                                 _pool_batch_split())(data)
+        else:
+            out = lax.reduce_window(data, -jnp.inf, lax.max, window, strides,
+                                    padding)
     elif pool_type in ("avg", "sum"):
         out = lax.reduce_window(data, 0.0, lax.add, window, strides, padding)
         if pool_type == "avg":

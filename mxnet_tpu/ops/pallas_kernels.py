@@ -45,7 +45,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
-           "softmax_cross_entropy", "use_pallas"]
+           "softmax_cross_entropy", "max_pool_fwd", "max_pool_bwd",
+           "use_pallas"]
 
 _NEG_INF = -1e30  # avoid actual -inf inside kernels (exp/max corner cases)
 _I0 = np.int32(0)  # index-map zero: a Python 0 is i64 under jax_enable_x64
@@ -55,9 +56,13 @@ def _interpret() -> bool:
     return os.environ.get("MXTPU_PALLAS_INTERPRET", "0") == "1"
 
 
+def pallas_off() -> bool:
+    return os.environ.get("MXTPU_PALLAS", "1") == "0"
+
+
 def use_pallas() -> bool:
     """Whether the Pallas kernel path is active for the current backend."""
-    if os.environ.get("MXTPU_PALLAS", "1") == "0":
+    if pallas_off():
         return False
     if _interpret():
         return True
@@ -422,3 +427,274 @@ def _ce_bwd(res, g):
 
 
 softmax_cross_entropy.defvjp(_ce_fwd, _ce_bwd)
+
+
+# ---------------------------------------------------------------------------
+# max pooling that keeps its winning taps (PERF.md, PR 28)
+# ---------------------------------------------------------------------------
+# Both kernels see (H, W, C, N): batch in the lanes, channels in the sublanes,
+# so rows and columns are untiled and striding or interleaving them is
+# addressing, not a relayout. That is the order XLA:TPU itself keeps a conv
+# net's activations in from batch 128 up, so the caller's transposes to and
+# from it are bitcasts. The grid walks (batch blocks, channel blocks, rows)
+# with the rows innermost and in order, and every source row is read from HBM
+# ONCE: a step that needs rows of earlier steps finds them in a VMEM scratch
+# (`hist`), and one that needs a later row runs that many steps late.
+_POOL_LANES = 128      # batch elements per block: the lane axis
+_POOL_SUBLANES = 32    # channels per block, at least: one int8 tile
+_POOL_VMEM_LIMIT = 32 << 20   # of v5e's 128 MiB; the default scope is 16
+_POOL_VMEM_BYTES = 24 << 20   # what a pool's blocks may take of it
+
+
+def _pool_bwd_plan(k, s, lo):
+    """One spatial axis of the scatter, seen from the input: position
+    ``s*m + r`` (r < s) of the UNPADDED input is padded position
+    ``s*m + r + lo``, which window ``m + d`` holds as tap ``i`` for every
+    ``(d, i)`` in ``plan[r]``. Static, from the window alone."""
+    plan = []
+    for r in range(s):
+        delta, rr = divmod(r + lo, s)
+        plan.append([(delta - q, rr + s * q) for q in range((k - 1) // s + 1)
+                     if rr + s * q < k])
+    return plan
+
+
+def _reach(offsets):
+    """(ahead, behind): how many steps late a row kernel runs so that its
+    furthest source row has arrived, and how many earlier rows it keeps."""
+    return max(0, max(offsets)), max(0, -min(offsets))
+
+
+def _source(cur, hist, back, depth):
+    """The ref that holds the block loaded ``back`` steps ago."""
+    return cur if back == 0 else hist.at[depth - back]
+
+
+def _remember(hists, curs, depth):
+    for hist, cur in zip(hists, curs):
+        for p in range(depth - 1):
+            hist[p] = hist[p + 1]
+        hist[depth - 1] = cur[...]
+
+
+def _pool_fwd_kernel(x_ref, out_ref, idx_ref, *hist, kernel, stride, pad_lo,
+                     h, w, n_h, ahead, depth):
+    """One step loads input rows [s_h*j, s_h*j + s_h) and writes output row
+    o = j - ahead with the winning tap of each of its windows. A later tap
+    wins only by being GREATER, so the first among equals is kept: what
+    select-and-scatter with ``ge`` picks. A tap outside the map holds -inf;
+    only taps that can fall outside are masked. The k_w - s_w columns a
+    window shares with the next one are carried, not loaded again."""
+    (kh, kw), (sh, sw), (lo_h, lo_w) = kernel, stride, pad_lo
+    n_w = out_ref.shape[1]
+    o = pl.program_id(2) - ahead
+    rows = []
+    for i in range(kh):
+        block, r = divmod(i - lo_h, sh)
+        e = o * sh - lo_h + i
+        inside = i - lo_h >= 0 and (n_h - 1) * sh - lo_h + i < h
+        rows.append((_source(x_ref, hist[0] if hist else None, ahead - block,
+                             depth), r,
+                     None if inside else (e >= 0) & (e < h)))
+    shared = max(kw - sw, 0)
+    tile = out_ref.shape[2:]
+
+    def value(ref, r, row_ok, j, ow=None):
+        """Tap value as float32, -inf outside the map (``row_ok`` None: this
+        tap's row never leaves it; ``ow`` None: the first window's)."""
+        col = j - lo_w + (0 if ow is None else ow * sw)
+        if ow is None and not 0 <= col < w:
+            return jnp.full(tile, -jnp.inf, jnp.float32)
+        ok = row_ok
+        # over all windows this tap's column runs from j - lo_w to:
+        if ow is not None and not (j - lo_w >= 0
+                                   and (n_w - 1) * sw - lo_w + j < w):
+            col_ok = (col >= 0) & (col < w)
+            ok = col_ok if ok is None else ok & col_ok
+            col = jnp.clip(col, 0, w - 1)
+        v = ref[r, col].astype(jnp.float32)
+        return v if ok is None else jnp.where(ok, v, -jnp.inf)
+
+    def column(ow, carried):
+        best, tap, keep = None, jnp.zeros(tile, jnp.int32), []
+        for i, (ref, r, row_ok) in enumerate(rows):
+            vals = list(carried[i * shared:(i + 1) * shared])
+            for j in range(shared, kw):
+                vals.append(value(ref, r, row_ok, j, ow))
+            keep += vals[sw:sw + shared]
+            for j, v in enumerate(vals):
+                if best is None:
+                    best = v
+                else:
+                    better = v > best
+                    best = jnp.where(better, v, best)
+                    tap = jnp.where(better, i * kw + j, tap)
+        out_ref[0, ow] = best.astype(out_ref.dtype)
+        idx_ref[0, ow] = tap.astype(jnp.int8)
+        return tuple(keep)
+
+    @pl.when(o >= 0)
+    def _():
+        first = tuple(value(ref, r, row_ok, j)
+                      for ref, r, row_ok in rows for j in range(shared))
+        lax.fori_loop(0, n_w, column, first)
+
+    _remember(hist, [x_ref], depth)
+
+
+def _pool_bwd_kernel(dy_ref, idx_ref, out_ref, *hist, plan_h, plan_w, n_h,
+                     n_w, kw, ahead, depth):
+    """One step loads row j of dy and of the index and writes the s_h input
+    rows of block m = j - ahead: each position sums, in float32, the dy of
+    the windows whose saved tap it is. A window outside the map holds no tap
+    at all. A source column that the next block of columns needs too is
+    carried, not loaded again."""
+    m = pl.program_id(2) - ahead
+    offs_h = sorted({d for terms in plan_h for d, _ in terms})
+    offs_w = sorted({d for terms in plan_w for d, _ in terms})
+    src = {d: (_source(dy_ref, hist[0] if hist else None, ahead - d, depth),
+               _source(idx_ref, hist[1] if hist else None, ahead - d, depth),
+               (m + d >= 0) & (m + d < n_h)) for d in offs_h}
+    s_w = len(plan_w)
+    tile = out_ref.shape[2:]
+    again = [dw for dw in offs_w if dw - 1 in offs_w]   # seen as dw-1 next
+
+    def source(dh, ow):
+        """(dy as float32, tap as int32 or -1 outside the map) of source
+        column ``ow``, a traced index or, before the loop, a Python int."""
+        dy_r, idx_r, ok = src[dh]
+        if isinstance(ow, int):
+            if not 0 <= ow < n_w:
+                return (jnp.zeros(tile, jnp.float32),
+                        jnp.full(tile, -1, jnp.int32))
+        else:
+            ok = ok & (ow >= 0) & (ow < n_w)
+            ow = jnp.clip(ow, 0, n_w - 1)
+        return (dy_r[0, ow].astype(jnp.float32),
+                jnp.where(ok, idx_r[0, ow].astype(jnp.int32), -1))
+
+    def column(w, carried):
+        tiles, it = {}, iter(carried)
+        for dh in offs_h:
+            for dw in offs_w:
+                if dw + 1 in again:
+                    tiles[dh, dw] = (next(it), next(it))
+                else:
+                    tiles[dh, dw] = source(dh, w + dw)
+        for rh, terms_h in enumerate(plan_h):
+            for rw, terms_w in enumerate(plan_w):
+                acc = None
+                for dh, i in terms_h:
+                    for dw, j in terms_w:
+                        g, ix = tiles[dh, dw]
+                        term = jnp.where(ix == i * kw + j, g, 0.0)
+                        acc = term if acc is None else acc + term
+                if acc is None:     # a position no window reaches
+                    acc = jnp.zeros(tile, jnp.float32)
+                out_ref[rh, w * s_w + rw] = acc.astype(out_ref.dtype)
+        return tuple(v for dh in offs_h for dw in again for v in tiles[dh, dw])
+
+    @pl.when(m >= 0)
+    def _():
+        first = tuple(v for dh in offs_h for dw in again
+                      for v in source(dh, dw - 1))
+        lax.fori_loop(0, out_ref.shape[1] // s_w, column, first)
+
+    _remember(hist, [dy_ref, idx_ref], depth)
+
+
+def _channel_block(hwcn, kernel, stride, itemsize):
+    """Channels per block: 64 where they divide the channels and the blocks
+    fit (1.88 -> 1.73 ms and 1.83 -> 1.62 ms a step for the two kernels at
+    [512,112,112,64] bfloat16; larger blocks, or unrolling the column loop,
+    gave nothing more: PERF.md, PR 28), else 32, one int8 tile; None where
+    even that does not fit. What has to fit is the larger kernel's blocks,
+    the forward's: s_h input rows double buffered, as many again kept, a row
+    of output and of taps."""
+    _, w, c, _ = hwcn
+    keep = -(-kernel[0] // stride[0])
+    column = _POOL_LANES * ((2 + keep) * stride[0] * w * itemsize
+                            + 2 * -(-w // stride[1]) * (itemsize + 1))
+    for cb in (2 * _POOL_SUBLANES, _POOL_SUBLANES):
+        if c % cb == 0 and cb * column <= _POOL_VMEM_BYTES:
+            return cb
+    return None
+
+
+def pool_eligible(hwcn, kernel, stride, itemsize):
+    """Whether the two kernels take an input of spatial-first shape
+    (H, W, C, N): whole lane and sublane blocks that fit in VMEM, whole row
+    and column blocks."""
+    h, w, c, n = hwcn
+    return (n % _POOL_LANES == 0 and c % _POOL_SUBLANES == 0
+            and h % stride[0] == 0 and w % stride[1] == 0
+            and _channel_block(hwcn, kernel, stride, itemsize) is not None)
+
+
+def _pool_call(name, kernel_fn, grid, in_specs, out_specs, out_shape, scratch,
+               *args):
+    # traced with x64 off (the package turns it on): every index in the
+    # kernel and its index maps is then i32, which is all Mosaic takes.
+    # The name is the custom call's in a device trace.
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            kernel_fn, name=name, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_POOL_VMEM_LIMIT),
+            interpret=_interpret())(*args)
+
+
+def max_pool_fwd(x, out_hw, kernel, stride, pad_lo):
+    """Max pool of (H, W, C, N) and the winning tap of each window, in one
+    read of the input: (out, idx), both (n_h, n_w, C, N), idx int8 in
+    row-major window order, first among equals."""
+    h, w, c, n = x.shape
+    (n_h, n_w), (kh, _), (sh, _) = out_hw, kernel, stride
+    cb = _channel_block(x.shape, kernel, stride, x.dtype.itemsize)
+    nb = _POOL_LANES
+    ahead, behind = _reach([(i - pad_lo[0]) // sh for i in range(kh)])
+    depth = ahead + behind
+    dst = pl.BlockSpec((1, n_w, cb, nb), lambda b, ch, j: (
+        jnp.clip(j - ahead, 0, n_h - 1), 0, ch, b))
+    return _pool_call(
+        "max_pool_fwd",
+        functools.partial(_pool_fwd_kernel, kernel=kernel, stride=stride,
+                          pad_lo=pad_lo, h=h, w=w, n_h=n_h, ahead=ahead,
+                          depth=depth),
+        (n // nb, c // cb, n_h + ahead),
+        [pl.BlockSpec((sh, w, cb, nb), lambda b, ch, j: (
+            jnp.minimum(j, h // sh - 1), 0, ch, b))],
+        [dst, dst],
+        [jax.ShapeDtypeStruct((n_h, n_w, c, n), x.dtype, **_vma_kw(x)),
+         jax.ShapeDtypeStruct((n_h, n_w, c, n), jnp.int8, **_vma_kw(x))],
+        [pltpu.VMEM((depth, sh, w, cb, nb), x.dtype)] if depth else [], x)
+
+
+def max_pool_bwd(idx, dy, in_hw, kernel, stride, pad_lo):
+    """dx (H, W, C, N) of a 2-D max pool from the winning tap of each window
+    (``idx`` int8 and ``dy``, both (n_h, n_w, C, N)): reads dy and the index
+    once, writes dx, touches nothing else."""
+    n_h, n_w, c, n = dy.shape
+    (h, w), (kh, kw), (sh, sw) = in_hw, kernel, stride
+    plan_h = _pool_bwd_plan(kh, sh, pad_lo[0])
+    plan_w = _pool_bwd_plan(kw, sw, pad_lo[1])
+    cb = _channel_block((h, w, c, n), kernel, stride, dy.dtype.itemsize)
+    nb = _POOL_LANES
+    ahead, behind = _reach([d for terms in plan_h for d, _ in terms])
+    depth = ahead + behind
+    src = pl.BlockSpec((1, n_w, cb, nb), lambda b, ch, j: (
+        jnp.minimum(j, n_h - 1), 0, ch, b))
+    return _pool_call(
+        "max_pool_bwd",
+        functools.partial(_pool_bwd_kernel, plan_h=plan_h, plan_w=plan_w,
+                          n_h=n_h, n_w=n_w, kw=kw, ahead=ahead, depth=depth),
+        (n // nb, c // cb, h // sh + ahead),
+        [src, src],
+        pl.BlockSpec((sh, w, cb, nb), lambda b, ch, j: (
+            jnp.clip(j - ahead, 0, h // sh - 1), 0, ch, b)),
+        jax.ShapeDtypeStruct((h, w, c, n), dy.dtype, **_vma_kw(dy)),
+        [pltpu.VMEM((depth, 1, n_w, cb, nb), dy.dtype),
+         pltpu.VMEM((depth, 1, n_w, cb, nb), jnp.int8)] if depth else [],
+        dy, idx)

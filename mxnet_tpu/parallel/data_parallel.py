@@ -13,6 +13,7 @@ parameters live as a pytree; after training, ``sync_to_net()`` writes back.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -551,6 +552,14 @@ class DataParallelTrainer:
         mesh, axis = self._mesh, self._axis
         repl = NamedSharding(mesh, P())
         dataspec = NamedSharding(mesh, P(axis))
+        # the mesh the step is partitioned over, named while the net is
+        # differentiated: an op that holds a Mosaic kernel (which jit cannot
+        # partition by itself) sees from it how its batch will be split.
+        # Not where another axis than the data's has devices: the name would
+        # not say which axis holds the batch
+        over_mesh = functools.partial(jax.sharding.use_abstract_mesh,
+                                      mesh.abstract_mesh) \
+            if mesh.size == mesh.shape[axis] else contextlib.nullcontext
         cdtype = self._compute_dtype
         tx = self._tx
         guard_cfg = self._guard_cfg
@@ -676,8 +685,9 @@ class DataParallelTrainer:
                     run = jax.checkpoint(run, policy=self._remat_policy)
                 return run(ins)
 
-            (loss, aux_updates), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(params)
+            with over_mesh():
+                (loss, aux_updates), grads = jax.value_and_grad(
+                    loss_of, has_aux=True)(params)
             grads, loss, aux_updates = _unscale_grads(
                 grads, loss, aux_updates, scale, cdtype is not None)
             grads = _reduce_grads(grads)
@@ -746,8 +756,9 @@ class DataParallelTrainer:
                         run = jax.checkpoint(run, policy=self._remat_policy)
                     return run(ins)
 
-                (loss, aux_updates), grads = jax.value_and_grad(
-                    loss_of, has_aux=True)(params)
+                with over_mesh():
+                    (loss, aux_updates), grads = jax.value_and_grad(
+                        loss_of, has_aux=True)(params)
                 # kv grads always go to f32 before they touch the wire
                 grads, loss, aux_updates = _unscale_grads(
                     grads, loss, aux_updates, scale, True)

@@ -103,6 +103,41 @@ def test_cross_entropy_kernel_compiles_for_v5e(one_chip, no_compile_cache,
     _compile(pk._ce_lse_pallas, s)
 
 
+@pytest.mark.parametrize("shape,layout,dtype", [
+    ((512, 112, 112, 64), "NHWC", jnp.bfloat16),   # resnet34_v1.train's pool
+    ((256, 112, 112, 64), "NHWC", jnp.bfloat16),   # resnet50_v1.train's
+    ((128, 64, 56, 56), None, jnp.float32),        # NCHW, float32, 2x2/2
+    ((128, 112, 112, 64), "NHWC", jnp.float32),    # 32-channel blocks: VMEM
+])
+def test_max_pool_backward_compiles_for_v5e(one_chip, no_compile_cache,
+                                            monkeypatch, shape, layout, dtype):
+    """The Pooling op's gradient at the cells' shapes, traced as a TPU process
+    that names its one-device mesh traces it: the two kernels are in the
+    program, ``select-and-scatter`` is not, and the transposes to the kernels'
+    (H, W, C, N) order cost no copy of the activation (PR 28)."""
+    from mxnet_tpu.ops import get_op
+    pool = get_op("Pooling").fn
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    window = dict(kernel=(3, 3), stride=(2, 2), pad=(1, 1)) if layout \
+        else dict(kernel=(2, 2), stride=(2, 2))
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x):
+        y = pool(jnp.maximum(x, 0) * 2, pool_type="max", layout=layout,
+                 **window)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    with jax.sharding.use_abstract_mesh(
+            jax.sharding.AbstractMesh((1,), ("dp",))):
+        text = _compile(jax.grad(loss), x)
+    assert "select-and-scatter" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    entry = text[text.index("ENTRY"):]
+    assert layout is None or " transpose(" not in entry \
+        and " copy(" not in entry, \
+        "the activation is relaid around the kernel"
+
+
 def test_rtc_kernel_compiles_for_v5e(one_chip, no_compile_cache):
     """A user kernel written as Pallas users write them — Python ints in the
     index map — through ``rtc.PallasModule``."""

@@ -64,6 +64,71 @@ def test_batchnorm_relu_pool_parity():
     check_consistency(net, _ctx_list(data=(2, 3, 8, 8)))
 
 
+_POOL_WINDOWS = {
+    "3x3s2p1": dict(kernel=(3, 3), stride=(2, 2), pad=(1, 1)),   # the ResNets'
+    "3x3s2p0": dict(kernel=(3, 3), stride=(2, 2)),       # AlexNet, Inception
+    "2x2s2": dict(kernel=(2, 2), stride=(2, 2)),                        # VGG
+    "3x3s1p1": dict(kernel=(3, 3), stride=(1, 1), pad=(1, 1)),
+    "3x3s2full": dict(kernel=(3, 3), stride=(2, 2), pooling_convention="full"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_POOL_WINDOWS))
+def test_max_pool_kernels_parity(name):
+    """A max pool in the shape that keeps its winning taps (batch 128,
+    channels 32; PR 28): on the TPU both Pallas kernels, in the CPU context
+    of the same process ``reduce_window`` and its own gradient; value and
+    gradient must agree, windows of zeros and all. float32 on both sides:
+    rounding the data to bfloat16 makes new ties and moves the gradient to
+    another element of the window. (With several chips and no mesh named the
+    op keeps ``reduce_window`` on both sides.)"""
+    net = sym.Pooling(sym.Activation(sym.Variable("data"), act_type="relu"),
+                      pool_type="max", name="pool", **_POOL_WINDOWS[name])
+    check_consistency(net, _ctx_list(data=(128, 32, 12, 12)))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", sorted(_POOL_WINDOWS))
+def test_max_pool_kernels_equal_reduce_windows_gradient_on_the_chip(
+        monkeypatch, name, dtype):
+    """The same op twice on the TPU, from the same data in the same type:
+    through the two kernels, and with Pallas switched off as
+    ``reduce_window`` and ``select-and-scatter``. The same output to the bit,
+    the same element of every window, and gradients equal up to the order of
+    at most nine float32 additions."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.observability import catalog
+    from mxnet_tpu.ops import get_op, pallas_kernels as pk
+    pool = get_op("Pooling").fn
+    rs = np.random.RandomState(3)
+    x = jnp.asarray(np.maximum(rs.randn(128, 12, 12, 32), 0), dtype)
+    dy = jnp.asarray(rs.randn(128, 12, 12, 32), dtype)
+
+    def run():
+        before = catalog.POOL_BWD_LOWERED.value()
+        # a mesh of one device, named: on a host with several chips the op
+        # could not otherwise see that its batch stays whole
+        with jax.sharding.use_abstract_mesh(
+                jax.sharding.AbstractMesh((1,), ("dp",))):
+            out, vjp = jax.vjp(lambda x: pool(
+                x, pool_type="max", layout="NHWC", **_POOL_WINDOWS[name]), x)
+            g = vjp(dy[:, :out.shape[1], :out.shape[2]])[0]
+        return (np.asarray(out.astype(jnp.float32)),
+                np.asarray(g.astype(jnp.float32)),
+                catalog.POOL_BWD_LOWERED.value() - before)
+
+    out_k, g_k, lowered = run()
+    assert lowered == 1
+    monkeypatch.setattr(pk, "pallas_off", lambda: True)
+    out_x, g_x, lowered = run()
+    assert lowered == 0
+    np.testing.assert_array_equal(out_k, out_x)
+    np.testing.assert_array_equal(g_k != 0, g_x != 0)
+    tol = 1e-6 if dtype == "float32" else 2.0 ** -8
+    np.testing.assert_allclose(g_k, g_x, rtol=tol, atol=tol)
+
+
 def test_softmax_ce_parity():
     net = sym.SoftmaxOutput(
         sym.FullyConnected(sym.Variable("data"), num_hidden=10),
