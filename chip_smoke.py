@@ -129,10 +129,10 @@ def net_weights(net, trainer, prefix=""):
 
 
 def make_trainer(net, mesh, **kw):
-    """bench.py's trainer configuration, except the learning rate: 0.1 with
-    no warm-up overshoots on a repeated batch (7.8 -> 13.0 at step 3 in the
-    CPU rehearsal), and "the loss falls" has to be a check that means
-    something."""
+    """The benchmark cells' trainer configuration with a small learning
+    rate: 0.1 with no warm-up overshoots on a repeated batch (7.8 -> 13.0 at
+    step 3 in the CPU rehearsal), and "the loss falls" has to be a check
+    that means something."""
     from mxnet_tpu import gluon, parallel
     return parallel.DataParallelTrainer(
         net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",

@@ -214,7 +214,7 @@ register_config("MXNET_RESILIENCE_STEP_DEADLINE", 0.0, float,
 # JAX_COMPILATION_CACHE_DIR is set jax reads it itself and no directory is
 # set in code; otherwise the cache lives at one fixed path inside the
 # checkout (git-ignored) — never one built from a temporary name, pid or
-# time. chip_smoke.py, bench.py and the tools all come through here; no
+# time. chip_smoke.py, chipbench and the tools all come through here; no
 # other site names a cache directory.
 _CHECKOUT_CACHE = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")

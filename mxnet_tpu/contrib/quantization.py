@@ -292,66 +292,6 @@ def _trace_gluon(net):
     return out, arg_params, aux_params
 
 
-def quantized_resnet_bench(net, x, steps=20):
-    """Int8-vs-bf16 inference throughput of a gluon net on the current
-    default device (the VERDICT-r2 'prove int8 end-to-end' measurement;
-    reference driver: benchmark/python/quantization/benchmark_op.py).
-
-    Returns diagnostic fields for bench.py's JSON line:
-    ``int8_infer_img_s_per_chip``, ``bf16_infer_img_s_per_chip``,
-    ``int8_vs_bf16`` (speedup ratio).
-    """
-    import time as _time
-
-    import jax
-    import jax.numpy as jnp
-
-    import mxnet_tpu as mx
-
-    x = jnp.asarray(getattr(x, "_data", x))
-    batch = int(x.shape[0])
-    on_accel = jax.devices()[0].platform != "cpu"
-    ctx = mx.tpu() if on_accel else mx.cpu()
-
-    sym, arg_params, aux_params = _trace_gluon(net)
-
-    def _timed(exe, feed, n):
-        outs = exe.forward(**feed)   # compile + warm
-        outs[0].wait_to_read()
-        t0 = _time.perf_counter()
-        for _ in range(n):
-            outs = exe.forward(**feed)
-        outs[0].wait_to_read()
-        return n * batch / (_time.perf_counter() - t0)
-
-    from ..ndarray import array as _arr
-
-    # bf16 baseline: cast params and data so convs hit the MXU in bf16
-    # (on CPU keep f32 — this path is only a correctness/driver fallback)
-    def cast(a):
-        a = jnp.asarray(getattr(a, "_data", a))
-        return _arr(a.astype(jnp.bfloat16) if on_accel else a)
-    fargs = {k: cast(v) for k, v in arg_params.items()}
-    fargs["data"] = cast(x)
-    faux = {k: cast(v) for k, v in aux_params.items()}
-    fexe = sym.bind(ctx, fargs, grad_req="null", aux_states=faux)
-    bf16_ips = _timed(fexe, {}, steps)
-
-    qsym, qarg, qaux = quantize_model(sym, arg_params, aux_params,
-                                      data_names=("data",),
-                                      calib_mode="none")
-    qarg = dict(qarg)
-    qarg["data"] = _arr(x.astype(jnp.float32))
-    qexe = qsym.bind(ctx, qarg, grad_req="null", aux_states=qaux or None)
-    int8_ips = _timed(qexe, {}, steps)
-
-    return {
-        "int8_infer_img_s_per_chip": round(int8_ips, 2),
-        "bf16_infer_img_s_per_chip": round(bf16_ips, 2),
-        "int8_vs_bf16": round(int8_ips / bf16_ips, 3) if bf16_ips else None,
-    }
-
-
 def quantize_model(sym, arg_params, aux_params, data_names=("data",),
                    excluded_sym_names=(), calib_mode="none", calib_data=None,
                    num_calib_examples=None, quantized_dtype="int8",

@@ -153,60 +153,20 @@ class BottleneckV2(HybridBlock):
         return x + residual
 
 
-class SpaceToDepthStem(HybridBlock):
-    """The 7x7/s2 stem conv reformulated for the MXU (MLPerf ResNet trick):
-    pad 3 -> space-to-depth(2) -> 4x4/s1 VALID conv over 12 input channels.
-
-    Mathematically the SAME linear map: with W'[o,du,dv,(r,s,c)] =
-    W[o,2du+r,2dv+s,c] (zero where 2du+r > 6) the output equals the
-    original conv exactly — see tests/test_s2d_stem.py. The point: C_in=3
-    wastes the MXU's 128-deep contraction lanes; C_in=12 with a 4x4 kernel
-    quadruples the stem's arithmetic intensity. NHWC only.
-    """
-
-    def __init__(self, channels, prefix=None, params=None):
-        super().__init__(prefix, params)
-        with self.name_scope():
-            self.conv = nn.Conv2D(channels, 4, 1, 0, use_bias=False,
-                                  in_channels=12, layout="NHWC")
-
-    def hybrid_forward(self, F, x):
-        x = F.pad(x, mode="constant",
-                  pad_width=(0, 0, 3, 3, 3, 3, 0, 0))
-        x = F.reshape(x, shape=(0, -4, -1, 2, -4, -1, 2, 0))
-        x = F.transpose(x, axes=(0, 1, 3, 2, 4, 5))
-        x = F.reshape(x, shape=(0, 0, 0, -1))
-        return self.conv(x)
-
-
 class ResNetV1(HybridBlock):
     def __init__(self, block, layers, channels, classes=1000, thumbnail=False,
-                 layout="NCHW", stem_s2d=False, **kwargs):
+                 layout="NCHW", **kwargs):
         super().__init__(**kwargs)
         assert len(layers) == len(channels) - 1
         self._layout = layout
         ax = _bn_axis(layout)
-        if stem_s2d and layout != "NHWC":
-            from ....base import MXNetError
-            raise MXNetError("stem_s2d requires layout='NHWC'")
         with self.name_scope():
             self.features = nn.HybridSequential(prefix="")
             if thumbnail:
                 self.features.add(_conv3x3(channels[0], 1, 0, layout))
             else:
-                if stem_s2d:
-                    # prefix="" so the stem conv keeps the plain stem's
-                    # parameter name (resnetvXY_conv0_weight): the s2d net
-                    # differs from its NCHW/NHWC twins only in that
-                    # parameter's SHAPE, which keeps param orderings (and
-                    # thus lowered-HLO argument order) identical across
-                    # the hand-flag and graph-pass routes
-                    self.features.add(SpaceToDepthStem(channels[0],
-                                                       prefix=""))
-                else:
-                    self.features.add(nn.Conv2D(channels[0], 7, 2, 3,
-                                                use_bias=False,
-                                                layout=layout))
+                self.features.add(nn.Conv2D(channels[0], 7, 2, 3,
+                                            use_bias=False, layout=layout))
                 self.features.add(nn.BatchNorm(axis=ax))
                 self.features.add(nn.Activation("relu"))
                 self.features.add(nn.MaxPool2D(3, 2, 1, layout=layout))

@@ -22,7 +22,7 @@ class DataParallelExecutorGroup:
     def __init__(self, symbol, contexts, workload, data_shapes, label_shapes,
                  param_names, for_training, inputs_need_grad, shared_group=None,
                  logger=None, fixed_param_names=None, grad_req="write",
-                 state_names=None, group2ctx=None, param_shapes=None):
+                 state_names=None, group2ctx=None):
         self.symbol = symbol
         self.contexts = contexts
         self.param_names = param_names
@@ -76,13 +76,8 @@ class DataParallelExecutorGroup:
                         tuple(exec_.arg_dict[name].shape) != tuple(shape):
                     exec_.arg_dict[name] = nd.zeros(shape, ctx=ctx)
         else:
-            # param_shapes: shapes Module inferred on the un-rewritten graph
-            known = {k: v for k, v in (param_shapes or {}).items()
-                     if v is not None}
-            known.update(shapes)
-            ex = symbol.simple_bind(ctx, grad_req=self.grad_req,
-                                    group2ctx=group2ctx, **known)
-            exec_ = ex
+            exec_ = symbol.simple_bind(ctx, grad_req=self.grad_req,
+                                       group2ctx=group2ctx, **shapes)
         self.execs = [exec_]
 
     # ------------------------------------------------------------- data flow

@@ -129,26 +129,12 @@ class Module(BaseModule):
             self._label_shapes = []
         shared_group = shared_module._exec_group if shared_module else None
         bind_symbol = self._run_passes()
-        param_shapes = None
-        if bind_symbol is not self._symbol:
-            # a rewrite may put reshapes between a parameter and the op
-            # whose rule derives its shape (the s2d stem's in-graph weight
-            # rearrangement), which simple_bind cannot infer back through.
-            # Parameters keep their shapes on this path (no re-homing), so
-            # the ORIGINAL graph names them all.
-            feed = {d.name: tuple(d.shape) for d in self._data_shapes}
-            feed.update({l.name: tuple(l.shape)
-                         for l in self._label_shapes})
-            arg_shapes, _, aux_shapes = self._symbol.infer_shape(**feed)
-            param_shapes = dict(zip(self._symbol.list_arguments(),
-                                    arg_shapes))
-            param_shapes.update(zip(self._aux_names, aux_shapes))
         self._exec_group = DataParallelExecutorGroup(
             bind_symbol, self._context, None, self._data_shapes,
             self._label_shapes, self._param_names, for_training,
             inputs_need_grad, shared_group=shared_group,
             fixed_param_names=self._fixed_param_names, grad_req=grad_req,
-            group2ctx=self._group2ctx, param_shapes=param_shapes)
+            group2ctx=self._group2ctx)
         self.binded = True
         self.for_training = for_training
 

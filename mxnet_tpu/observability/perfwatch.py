@@ -196,9 +196,9 @@ def normalize(doc: Any, source: str = "") -> Optional[Dict[str, Any]]:
                 "provenance": doc.get("provenance")}
     if doc.get("label") == "quant" and (
             doc.get("int8_ms") is not None or doc.get("f32_ms") is not None):
-        # quantization ledger row (quant.compare_latency / bench.py int8
-        # diagnostic): latencies down-is-good, speedup and int8 accuracy
-        # up-is-good — int8 regressions guard exactly like serving ones
+        # quantization ledger row (quant.compare_latency): latencies
+        # down-is-good, speedup and int8 accuracy up-is-good — int8
+        # regressions guard exactly like serving ones
         vals = {}
         for k in ("int8_ms", "f32_ms", "int8_vs_f32", "int8_acc"):
             if doc.get(k) is not None:

@@ -2,9 +2,8 @@
 
 Every compiled train step carries a free, exact self-description: XLA's
 ``cost_analysis()`` knows the FLOPs, the bytes moved through HBM and the
-transcendental count of the whole fused program. Until now that data was
-extracted once, in ``bench.py``, printed to stderr and lost. This module
-makes it a first-class, persistent artifact:
+transcendental count of the whole fused program. This module makes it a
+first-class, persistent artifact:
 
 - :func:`analyze_cost` turns a raw ``cost_analysis()`` dict into a row with
   derived quantities — arithmetic intensity (FLOPs/byte), the device's
@@ -16,8 +15,8 @@ makes it a first-class, persistent artifact:
   ``aot_key`` and the executable's StableHLO digest — the same fingerprint
   ``aot_save``/``aot_load`` trust, so a ledger row provably describes a
   specific compiled program;
-- :func:`capture` is the one-call tap the trainer and ``bench.py`` use at
-  compile time: lowered computation in, analyzed + persisted row out.
+- :func:`capture` is the one-call tap the trainer uses at compile time:
+  lowered computation in, analyzed + persisted row out.
 
 The ledger is the feature store the ROADMAP-1 autotuner reads ("A Learned
 Performance Model for TPUs" builds its feature vectors from exactly these

@@ -459,9 +459,9 @@ class DataParallelTrainer:
 
     def _placed_param(self, name, value):
         """A net parameter's value as the REWRITTEN graph expects it: the
-        pass pipeline may have re-homed the variable (NHWC weight, s2d
-        stem), in which case the recorded transform maps the net's value
-        into the captured layout (sync_to_net applies the inverse)."""
+        pass pipeline may have re-homed the variable (NHWC weight), in
+        which case the recorded transform maps the net's value into the
+        captured layout (sync_to_net applies the inverse)."""
         if self._pass_result is None or \
                 name not in self._pass_result.var_transforms:
             # a copy, never the net's own buffer: the step donates its

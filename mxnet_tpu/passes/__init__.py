@@ -2,9 +2,9 @@
 
 ``analysis/`` is the read-only half of the compiler-pass framework
 (mxlint); this package is the write half: Relay/TVM-style rewrite passes
-that turn the measured perf levers (NHWC layout, space-to-depth stem,
-constant folding, fusion-friendly reordering) into automatic defaults every
-captured graph inherits.  ``Module`` and
+that turn the measured perf levers (NHWC layout, constant folding,
+fusion-friendly reordering) into automatic defaults every captured graph
+inherits.  ``Module`` and
 :class:`~mxnet_tpu.parallel.DataParallelTrainer` run the default pipeline
 unless constructed with ``passes=False``; ``MXNET_PASSES`` tunes it;
 ``tools/mxopt.py`` is the CLI.  Catalog: docs/passes.md.
@@ -18,12 +18,10 @@ unless constructed with ``passes=False``; ``MXNET_PASSES`` tunes it;
 from .manager import (Pass, PassContext, PassManager, PassResult,
                       DEFAULT_PIPELINE, PASS_REGISTRY, register_pass,
                       default_names, resolve, annotate_graph, apply_spec,
-                      spec_shape, provenance,
-                      s2d_weight_forward, s2d_weight_inverse)
+                      spec_shape, provenance)
 # importing the pass modules populates PASS_REGISTRY
 from .fold import ConstantFoldPass
 from .layout import LayoutPass
-from .s2d import SpaceToDepthPass
 from .fusion import FusionReorderPass
 # the quantization passes register too (names: quantize/requantize/
 # dequantize) but stay OPT-IN — quantization changes numerics, so they are
@@ -36,6 +34,4 @@ __all__ = ["Pass", "PassContext", "PassManager", "PassResult",
            "DEFAULT_PIPELINE", "PASS_REGISTRY", "register_pass",
            "default_names", "resolve", "annotate_graph", "apply_spec",
            "spec_shape", "provenance",
-           "s2d_weight_forward", "s2d_weight_inverse",
-           "ConstantFoldPass", "LayoutPass", "SpaceToDepthPass",
-           "FusionReorderPass"]
+           "ConstantFoldPass", "LayoutPass", "FusionReorderPass"]

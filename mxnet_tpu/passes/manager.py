@@ -25,10 +25,9 @@ Pipeline semantics:
 
 Pass catalog (docs/passes.md): ``fold`` (constant folding + dead-branch
 elimination), ``layout`` (automatic NCHW→NHWC propagation), ``fusion``
-(transpose/cast reordering so XLA fuses across layout boundaries), and, by
-name only, ``s2d`` (space-to-depth stem rewrite that re-homes the weight;
-the default path gets the same form from the Convolution op itself, which
-keeps the parameter as the model declares it).
+(transpose/cast reordering so XLA fuses across layout boundaries).  How a
+stride-2 few-channel stem is lowered is not a pass: the Convolution op
+decides it from the shapes it sees (``ops/nn.py``).
 """
 from __future__ import annotations
 
@@ -50,10 +49,7 @@ register_config(
     "disables it; 'layout,fusion' runs exactly those; '-fold' runs the "
     "default minus a pass.")
 
-#: the default pipeline contents, in order. ``s2d`` is NOT in it: its
-#: re-homed (k/2,k/2,4C) weight trains padded taps the model does not have;
-#: ``ops/nn.py`` lowers the stem through space-to-depth inside the
-#: Convolution op instead, exactly, on the traced weight
+#: the default pipeline contents, in order
 DEFAULT_PIPELINE = ("fold", "layout", "fusion")
 
 #: name -> Pass subclass (populated by the pass modules at import)
@@ -250,38 +246,11 @@ def _inv_perm(perm):
     return tuple(inv)
 
 
-def s2d_weight_forward(w: np.ndarray) -> np.ndarray:
-    """(O,kh,kw,C) OHWI conv weight -> its block-2 space-to-depth twin
-    (O,ceil(kh/2),ceil(kw/2),4C): W'[o,du,dv,(2r+s)C+c] = W[o,2du+r,2dv+s,c],
-    zero where the source index falls past the kernel (the exact
-    reparameterization tests/test_s2d_stem.py pins)."""
-    O, kh, kw, C = w.shape
-    kh2, kw2 = (kh + 1) // 2, (kw + 1) // 2
-    padded = np.zeros((O, 2 * kh2, 2 * kw2, C), w.dtype)
-    padded[:, :kh, :kw, :] = w
-    return padded.reshape(O, kh2, 2, kw2, 2, C) \
-                 .transpose(0, 1, 3, 2, 4, 5) \
-                 .reshape(O, kh2, kw2, 4 * C)
-
-
-def s2d_weight_inverse(w2: np.ndarray, kh: int, kw: int) -> np.ndarray:
-    O, kh2, kw2, c4 = w2.shape
-    C = c4 // 4
-    padded = w2.reshape(O, kh2, kw2, 2, 2, C) \
-               .transpose(0, 1, 3, 2, 4, 5) \
-               .reshape(O, 2 * kh2, 2 * kw2, C)
-    return np.ascontiguousarray(padded[:, :kh, :kw, :])
-
-
 def apply_spec(spec, value: np.ndarray, inverse: bool = False) -> np.ndarray:
     kind = spec[0]
     if kind == "transpose":
         perm = spec[1]
         return np.transpose(value, _inv_perm(perm) if inverse else perm)
-    if kind == "s2d_weight":
-        kh, kw = spec[1], spec[2]
-        return s2d_weight_inverse(value, kh, kw) if inverse \
-            else s2d_weight_forward(value)
     raise MXNetError(f"unknown variable-transform spec {spec!r}")
 
 
@@ -294,10 +263,6 @@ def spec_shape(spec, shape: Sequence[int]) -> Tuple[int, ...]:
     kind = spec[0]
     if kind == "transpose":
         return tuple(shape[i] for i in spec[1])
-    if kind == "s2d_weight":
-        kh, kw = spec[1], spec[2]
-        O, _, _, C = shape
-        return (O, (kh + 1) // 2, (kw + 1) // 2, 4 * C)
     raise MXNetError(f"unknown variable-transform spec {spec!r}")
 
 

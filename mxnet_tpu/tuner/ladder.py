@@ -5,8 +5,8 @@ monolithic ``main()``; this module is that machinery as data + functions so
 the autotuner (``tuner.tune``) and the CLI share ONE implementation:
 
 - :class:`VariantSpec` — ladder variants as data (``"NHWC:512"``,
-  ``"RMT:512"`` = NHWC + full remat, ``"S2D:256"`` = NHWC + space-to-depth
-  stem, ``"IMP:32"`` = the imperative-dispatch lab);
+  ``"RMT:512"`` = NHWC + full remat, ``"IMP:32"`` = the
+  imperative-dispatch lab);
 - :func:`run_variant` / :func:`run_ladder` — build + measure one/all
   ResNet-50 variants in ONE process (one process holds the chip),
   emitting the CLI's historical JSON lines;
@@ -33,10 +33,10 @@ __all__ = ["DEFAULT_VARIANTS", "SEED_VARIANTS", "VariantSpec",
            "imperative_lab"]
 
 # the historical default ladder and the staged seed ladder the ROADMAP
-# names for the live-chip window (RMT:512, S2D:256, NHWC:512 + the NCHW
+# names for the live-chip window (RMT:512, NHWC:512 + the NCHW
 # reference point; convert triage = hlo_audit on the last variant)
 DEFAULT_VARIANTS = "NCHW:256,NHWC:256,NHWC:512,NHWC:1024"
-SEED_VARIANTS = "NCHW:256,NHWC:512,S2D:256,RMT:512"
+SEED_VARIANTS = "NCHW:256,NHWC:512,RMT:512"
 
 
 def _repo_root() -> str:
@@ -52,21 +52,18 @@ class VariantSpec:
     """One ladder variant as data. ``token`` spellings:
 
     ``NCHW:B`` / ``NHWC:B``  plain layout at batch B
-    ``S2D:B``                NHWC + space-to-depth stem (exact 7x7/s2
-                             reparameterization, tests/test_s2d_stem.py)
     ``RMT:B``                NHWC + full forward rematerialization (the
                              batch-512 fit-without-spilling lever)
     ``IMP:B``                imperative-dispatch lab (no trainer built)
     """
 
-    __slots__ = ("label", "layout", "batch", "s2d", "remat", "imperative")
+    __slots__ = ("label", "layout", "batch", "remat", "imperative")
 
     def __init__(self, label: str, layout: str, batch: int,
-                 s2d: bool = False, remat=None, imperative: bool = False):
+                 remat=None, imperative: bool = False):
         self.label = label
         self.layout = layout
         self.batch = int(batch)
-        self.s2d = bool(s2d)
         self.remat = remat
         self.imperative = bool(imperative)
 
@@ -79,12 +76,11 @@ class VariantSpec:
             raise MXNetError(f"bad variant token {token!r} (want LABEL:B)")
         if label == "IMP":
             return cls("IMP", "IMP", batch, imperative=True)
-        s2d = label == "S2D"
         remat = "full" if label == "RMT" else None
-        layout = "NHWC" if (s2d or remat) else label
+        layout = "NHWC" if remat else label
         if layout not in ("NCHW", "NHWC"):
             raise MXNetError(f"unknown variant label {label!r}")
-        return cls(label, layout, batch, s2d=s2d, remat=remat)
+        return cls(label, layout, batch, remat=remat)
 
     @property
     def variant(self) -> str:
@@ -95,8 +91,7 @@ class VariantSpec:
         from .space import Candidate
         if self.imperative:
             raise MXNetError("IMP variants have no candidate equivalent")
-        return Candidate(self.batch, self.layout, s2d=self.s2d,
-                         remat=self.remat)
+        return Candidate(self.batch, self.layout, remat=self.remat)
 
     def __repr__(self) -> str:
         return f"VariantSpec({self.variant})"
@@ -189,13 +184,14 @@ def run_variant(spec: VariantSpec, *, steps: int, warmup: int, image: int,
     np.random.seed(0)
     mx.random.seed(0)
     layout, batch = spec.layout, spec.batch
-    net = vision.resnet50_v1(classes=1000, layout=layout, stem_s2d=spec.s2d)
+    net = vision.resnet50_v1(classes=1000, layout=layout)
     net.initialize(mx.init.Xavier())
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
     # ladder variants are explicit hand-flag reference points: the graph
     # passes are pinned OFF so NHWC:512 measures exactly NHWC:512 (the
-    # default pipeline would e.g. auto-s2d the stem and collapse distinct
-    # rungs onto one program); the emitted row records that provenance
+    # default pipeline would rewrite the NCHW rung to NHWC and collapse
+    # distinct rungs onto one program); the emitted row records that
+    # provenance
     trainer = parallel.DataParallelTrainer(
         net, loss_fn, "sgd",
         {"learning_rate": 0.1, "momentum": 0.9, "wd": 1e-4},

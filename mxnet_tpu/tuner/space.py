@@ -2,11 +2,12 @@
 
 A :class:`Candidate` is one fully-specified training-step configuration —
 the five perf levers the staged bench ladders have been exercising by hand
-(ROADMAP item 1): global **batch** size, conv **layout** (NCHW/NHWC, plus
-the space-to-depth stem reparameterization), **remat** policy, buffer
-**donation** and device-feed **prefetch depth**. A :class:`SearchSpace` is
-the declared cross product the tuner enumerates; invalid combinations
-(s2d without NHWC) are skipped at enumeration, never at build time.
+(ROADMAP item 1): global **batch** size, conv **layout** (NCHW/NHWC),
+**remat** policy, buffer **donation** and device-feed **prefetch depth**,
+plus the gradient-reduction levers. A :class:`SearchSpace` is the declared
+cross product the tuner enumerates; invalid combinations (``bucket_bytes``
+next to ``reduce_scatter``) are skipped at enumeration, never at build
+time.
 
 Candidates are *data*: they serialize to/from plain dicts (the
 ``tuner_config`` field of a cost-ledger trial row), produce a stable
@@ -58,11 +59,11 @@ def _norm_reduce_dtype(dt) -> Optional[str]:
 class Candidate:
     """One point of the search space. Immutable value object."""
 
-    __slots__ = ("batch", "layout", "s2d", "remat", "donate",
+    __slots__ = ("batch", "layout", "remat", "donate",
                  "prefetch_depth", "grad_reduce", "grad_reduce_dtype",
                  "bucket_bytes")
 
-    def __init__(self, batch: int, layout: str = "NCHW", s2d: bool = False,
+    def __init__(self, batch: int, layout: str = "NCHW",
                  remat=None, donate: bool = True, prefetch_depth: int = 2,
                  grad_reduce: str = "all_reduce", grad_reduce_dtype=None,
                  bucket_bytes: Optional[int] = None):
@@ -72,9 +73,6 @@ class Candidate:
         if layout not in LAYOUTS:
             raise MXNetError(f"candidate layout must be one of {LAYOUTS}, "
                              f"got {layout!r}")
-        if s2d and layout != "NHWC":
-            raise MXNetError("the space-to-depth stem is an NHWC-only "
-                             "reparameterization (tests/test_s2d_stem.py)")
         if grad_reduce not in GRAD_REDUCE_MODES:
             raise MXNetError("candidate grad_reduce must be one of "
                              f"{GRAD_REDUCE_MODES}, got {grad_reduce!r}")
@@ -92,7 +90,6 @@ class Candidate:
                     "(DataParallelTrainer enforces the same)")
         object.__setattr__(self, "batch", batch)
         object.__setattr__(self, "layout", str(layout))
-        object.__setattr__(self, "s2d", bool(s2d))
         object.__setattr__(self, "remat", _norm_remat(remat))
         object.__setattr__(self, "donate", bool(donate))
         object.__setattr__(self, "prefetch_depth", max(0, int(prefetch_depth)))
@@ -110,8 +107,6 @@ class Candidate:
         """Human-readable tag, perf_lab-style core (``NHWC:512``) plus any
         non-default lever suffixes."""
         tag = f"{self.layout}:{self.batch}"
-        if self.s2d:
-            tag += "+s2d"
         if self.remat:
             tag += f"+remat={self.remat}"
         if not self.donate:
@@ -127,7 +122,7 @@ class Candidate:
         return tag
 
     def as_dict(self) -> Dict[str, Any]:
-        return {"batch": self.batch, "layout": self.layout, "s2d": self.s2d,
+        return {"batch": self.batch, "layout": self.layout,
                 "remat": self.remat, "donate": self.donate,
                 "prefetch_depth": self.prefetch_depth,
                 "grad_reduce": self.grad_reduce,
@@ -136,11 +131,7 @@ class Candidate:
 
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "Candidate":
-        return cls(**{k: d[k] for k in ("batch", "layout", "s2d", "remat",
-                                        "donate", "prefetch_depth",
-                                        "grad_reduce", "grad_reduce_dtype",
-                                        "bucket_bytes")
-                      if k in d})
+        return cls(**{k: d[k] for k in cls.__slots__ if k in d})
 
     def key(self, device_kind: Optional[str] = None, model: str = "",
             n_devices: int = 1, compute_dtype=None,
@@ -186,7 +177,7 @@ class Candidate:
 
     def trainer_kwargs(self) -> Dict[str, Any]:
         """The DataParallelTrainer ctor levers this candidate carries.
-        ``batch``/``layout``/``s2d`` are data- and net-level choices (the
+        ``batch``/``layout`` are data- and net-level choices (the
         caller's ``build``/``data`` functions consume them); ``prefetch_depth``
         is a feed-level knob (``io.prefetch_to_device(depth=...)``). The
         comm levers (``grad_reduce``/``grad_reduce_dtype``/``bucket_bytes``)
@@ -198,7 +189,7 @@ class Candidate:
                 "bucket_bytes": self.bucket_bytes}
 
     def passes_manager(self):
-        """This candidate's ``layout``/``s2d`` dimensions as a graph-pass
+        """This candidate's ``layout`` dimension as a graph-pass
         pipeline over an NCHW-built net (``mxnet_tpu.passes``): the
         flag-vs-pass route.  ``input_layout="NHWC"`` because the
         candidate's ``data_shape`` feeds channel-last batches; the
@@ -208,9 +199,8 @@ class Candidate:
         if self.layout != "NHWC":
             return None
         from ..passes import PassManager
-        names = ["fold", "layout"] + (["s2d"] if self.s2d else []) \
-            + ["fusion"]
-        return PassManager(names, input_layout="NHWC")
+        return PassManager(["fold", "layout", "fusion"],
+                           input_layout="NHWC")
 
     def build_trainer(self, net, loss_fn, optimizer: str = "sgd",
                       optimizer_params: Optional[Dict] = None,
@@ -220,7 +210,7 @@ class Candidate:
         ``DataParallelTrainer(net, loss, ..., remat=..., donate=...)`` would
         build (bitwise-identical lowered HLO — the tuner acceptance test).
 
-        ``via_passes=True`` applies the layout/s2d dimensions as graph
+        ``via_passes=True`` applies the layout dimension as graph
         passes instead of expecting a hand-flagged net: ``net`` must be
         built NCHW, and the candidate's pipeline rewrites the captured
         graph to the identical HLO.  Either way the candidate PINS its
@@ -247,12 +237,11 @@ class SearchSpace:
     against.
     """
 
-    DIMS = ("batch", "layout", "s2d", "remat", "donate", "prefetch_depth",
+    DIMS = ("batch", "layout", "remat", "donate", "prefetch_depth",
             "grad_reduce", "grad_reduce_dtype", "bucket_bytes")
 
     def __init__(self, batch: Sequence[int] = (256, 512),
                  layout: Sequence[str] = ("NCHW", "NHWC"),
-                 s2d: Sequence[bool] = (False,),
                  remat: Sequence = (None,),
                  donate: Sequence[bool] = (True,),
                  prefetch_depth: Sequence[int] = (2,),
@@ -263,7 +252,6 @@ class SearchSpace:
             return tuple(v) if isinstance(v, (list, tuple)) else (v,)
         self.batch = tup(batch)
         self.layout = tup(layout)
-        self.s2d = tup(s2d)
         self.remat = tup(remat)
         self.donate = tup(donate)
         self.prefetch_depth = tup(prefetch_depth)
@@ -276,22 +264,20 @@ class SearchSpace:
 
     def enumerate(self) -> List[Candidate]:
         """Every valid candidate, baseline first. Invalid combinations
-        (s2d on a non-NHWC layout; bucket_bytes next to the ZeRO
-        reduce_scatter path, which fuses its own collectives) are skipped,
+        (bucket_bytes next to the ZeRO reduce_scatter path, which fuses
+        its own collectives) are skipped,
         not errors — a space may legitimately declare both values of every
         dimension at once."""
         out: List[Candidate] = []
-        for vals in itertools.product(self.batch, self.layout, self.s2d,
+        for vals in itertools.product(self.batch, self.layout,
                                       self.remat, self.donate,
                                       self.prefetch_depth, self.grad_reduce,
                                       self.grad_reduce_dtype,
                                       self.bucket_bytes):
-            b, lay, s2d, rm, don, pf, gr, grd, bb = vals
-            if s2d and lay != "NHWC":
-                continue
+            b, lay, rm, don, pf, gr, grd, bb = vals
             if bb not in (None, 0) and gr == "reduce_scatter":
                 continue
-            out.append(Candidate(b, lay, s2d=s2d, remat=rm, donate=don,
+            out.append(Candidate(b, lay, remat=rm, donate=don,
                                  prefetch_depth=pf, grad_reduce=gr,
                                  grad_reduce_dtype=grd, bucket_bytes=bb))
         if not out:
@@ -341,7 +327,7 @@ class SearchSpace:
                 tok = tok.strip()
                 if name == "batch" or name == "prefetch_depth":
                     parsed.append(int(tok))
-                elif name in ("s2d", "donate"):
+                elif name == "donate":
                     parsed.append(tok.lower() in ("1", "true", "yes", "on"))
                 elif name in ("remat", "grad_reduce_dtype"):
                     parsed.append(None if tok.lower() in ("none", "off", "")

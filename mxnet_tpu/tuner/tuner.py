@@ -108,10 +108,10 @@ def best_cached(device_kind: Optional[str] = None,
                 ledger: Optional[_xcost.CostLedger] = None
                 ) -> Optional[Dict[str, Any]]:
     """The best MEASURED tuner row for a device/model signature (highest
-    per-chip throughput), or None. This is what ``bench.py`` stamps into
-    its row provenance (``tuned_config=``, filtered by ``model=``) and
-    what mxlint MXL-T211 checks a default-lever trainer against (filtered
-    by ``net_class=`` — the only signature a live trainer can derive).
+    per-chip throughput), or None. This is what mxlint MXL-T211 checks a
+    default-lever trainer against (filtered by ``net_class=`` — the only
+    signature a live trainer can derive; ``model=`` filters by the
+    caller's label).
     Pass ``n_devices`` too when the consumer knows its chip count: a
     global batch tuned on a 32-chip slice is not a recommendation for a
     single chip of the same device kind."""
@@ -261,13 +261,13 @@ def tune(build: Callable[[Candidate], Tuple[Any, Any]],
     """Search the config space for the fastest training-step configuration.
 
     ``build(candidate) -> (net, loss_fn)`` constructs the model for a
-    candidate (layout/s2d are net-level choices); ``data(candidate) ->
+    candidate (layout is a net-level choice); ``data(candidate) ->
     (x, y)`` returns one host sample batch of the candidate's batch size
     and layout. Everything else — lowering, cost analysis, prediction,
     ranking, the measure budget, ledger persistence, warm-start — is the
     tuner's job. Returns a :class:`TuneResult`.
 
-    ``via_passes=True`` routes each candidate's layout/s2d dimensions
+    ``via_passes=True`` routes each candidate's layout dimension
     through the graph-pass pipeline (``Candidate.passes_manager``) instead
     of hand-built net flags: ``build`` must construct the NCHW net, and the
     pass-rewritten step is bitwise-HLO-identical to the hand-flagged one
@@ -428,8 +428,7 @@ def tune(build: Callable[[Candidate], Tuple[Any, Any]],
                         "platform": dev.platform,
                         "predicted_ms": t.predicted_ms,
                         "batch": cand.batch,
-                        "layout": cand.layout + ("+s2d" if cand.s2d
-                                                 else "")})
+                        "layout": cand.layout})
             # memory column: the candidate's resident footprint (params +
             # opt-state + batch), estimated host-side off the live trainer
             # lower() just materialized — the predicted-OOM gate below and

@@ -727,7 +727,7 @@ def test_perfwatch_normalize_artifacts(tmp_path):
     assert n["kind"] == "bench_row"
     assert n["metrics"] == {"throughput": 2468.3, "mfu": 0.154,
                             "flops_per_step": 3.1e12}
-    # BENCH_rNN wrapper
+    # a wrapper that holds the row under "parsed"
     assert pw_mod.normalize({"parsed": bench_row})["kind"] == "bench_row"
     # ledger JSONL: last parseable row wins
     led = tmp_path / "l.jsonl"

@@ -75,16 +75,42 @@ def test_pipeline_spec_grammar():
     assert passes.default_names("off") == ()
     assert passes.default_names("layout,fusion") == ("layout", "fusion")
     assert passes.DEFAULT_PIPELINE == ("fold", "layout", "fusion")
-    assert passes.default_names("-s2d") == ("fold", "layout", "fusion")
     assert passes.default_names("-fold") == ("layout", "fusion")
-    assert passes.default_names("fold,layout,s2d,fusion") == \
-        ("fold", "layout", "s2d", "fusion")
-    with pytest.raises(MXNetError):
-        passes.default_names("nope")
+    for spec in ("-s2d", "fold,layout,s2d,fusion", "nope"):
+        with pytest.raises(MXNetError, match="unknown graph pass"):
+            passes.default_names(spec)
     assert passes.resolve(False) is None
     assert passes.resolve("0") is None
     mgr = passes.resolve(None)
     assert mgr is not None and mgr.names == passes.DEFAULT_PIPELINE
+
+
+def _spell_in_env(spec, monkeypatch):
+    monkeypatch.setenv("MXNET_PASSES", spec)
+    passes.resolve(None)
+
+
+_S2D_ROUTES = {
+    "default_names": lambda spec, mp: passes.default_names(spec),
+    "env": _spell_in_env,
+    "trainer": lambda spec, mp: parallel.DataParallelTrainer(
+        _conv_net("NCHW", "nos2d_", stem=True), gluon.loss.L2Loss(), "sgd",
+        {"learning_rate": 0.1}, passes=spec),
+    "module": lambda spec, mp: mx.mod.Module(
+        _conv_graph("NCHW"), data_names=("data",), label_names=(),
+        context=mx.cpu(), passes=spec),
+}
+
+
+@pytest.mark.parametrize("route", sorted(_S2D_ROUTES))
+def test_s2d_is_not_a_pass(route, monkeypatch):
+    """How a stem is lowered is the Convolution op's decision, not a pass:
+    the name is refused wherever a pipeline can be spelled, and the message
+    lists the names that exist."""
+    assert "s2d" not in passes.PASS_REGISTRY
+    with pytest.raises(MXNetError, match="unknown graph pass 's2d' "
+                                         r"\(registered: .*fold.*layout"):
+        _S2D_ROUTES[route]("fold,layout,s2d,fusion", monkeypatch)
 
 
 def test_resolve_explicit_falsy_spellings_mean_off():
@@ -188,52 +214,6 @@ def test_layout_pass_skips_nhwc_and_unknown_rank():
     assert res.symbol is sym and res.total_rewrites == 0
 
 
-# -------------------------------------------------------------------- s2d
-def test_s2d_pass_exact_reparameterization(rng):
-    data = sym_mod.Variable("data")
-    out = _op("Convolution", data, kernel=(7, 7), num_filter=8,
-              no_bias=True, layout="NHWC", stride=(2, 2), pad=(3, 3),
-              num_group=1, dilate=(1, 1), name="stem")
-    res = PassManager(("s2d",)).run(
-        out, shapes={"data": (2, 16, 16, 3)}, input_vars=("data",),
-        param_names=("stem_weight",))
-    assert res.counts["s2d"] == 1
-    assert res.var_transforms["stem_weight"][0][0] == "s2d_weight"
-    conv = [n for n in res.symbol.topo_nodes()
-            if n.op == "Convolution"][0]
-    assert tuple(conv.attrs["kernel"]) == (4, 4)
-    assert tuple(conv.attrs["stride"]) == (1, 1)
-    vals, aux = _bind_values(out, (2, 16, 16, 3), rng)
-    o1 = _eval_graph(out, vals, aux)
-    vals2 = {k: res.transform_var(k, v) for k, v in vals.items()}
-    o2 = _eval_graph(res.symbol, vals2, aux)
-    np.testing.assert_allclose(o1, o2, rtol=1e-4, atol=1e-4)
-
-
-def test_s2d_pass_skips_odd_extent_and_big_channels():
-    data = sym_mod.Variable("data")
-    out = _op("Convolution", data, kernel=(7, 7), num_filter=8,
-              no_bias=True, layout="NHWC", stride=(2, 2), pad=(0, 0),
-              num_group=1, dilate=(1, 1), name="stem")
-    # 15 + 0 pad is odd -> no rewrite
-    res = PassManager(("s2d",)).run(
-        out, shapes={"data": (2, 15, 15, 3)}, input_vars=("data",),
-        param_names=("stem_weight",))
-    assert res.total_rewrites == 0
-    # 16 input channels: not a stem — no rewrite
-    res = PassManager(("s2d",)).run(
-        out, shapes={"data": (2, 16, 16, 16)}, input_vars=("data",),
-        param_names=("stem_weight",))
-    assert res.total_rewrites == 0
-
-
-def test_s2d_weight_transform_inverse_roundtrip(rng):
-    w = rng.uniform(-1, 1, (8, 7, 7, 3)).astype("float32")
-    t = passes.s2d_weight_forward(w)
-    assert t.shape == (8, 4, 4, 12)
-    np.testing.assert_array_equal(passes.s2d_weight_inverse(t, 7, 7), w)
-
-
 # ------------------------------------------------------------------- fold
 def test_fold_pass_materializes_constants(rng):
     data = sym_mod.Variable("data")
@@ -321,10 +301,9 @@ def _batch(rng, layout="NCHW", batch=8, image=8):
 def test_trainer_equivalence_matrix_fused(rng, spec, dtype):
     """Trajectory-preserving passes (fold/layout/fusion, alone and
     stacked) train the fused capture path to the same losses as
-    passes=False.  (s2d is different by design: its rewrite is exact on
-    the FORWARD map but re-homes the stem into the (k/2,k/2,4C) parameter
-    space, so its trajectory twin is the hand stem_s2d net — pinned
-    bitwise in the flag-vs-pass tests below — not the 7x7 original.)"""
+    passes=False.  The net has a 7x7/s2 stem: once the layout pass
+    has run, the Convolution op lowers it through space-to-depth on the
+    traced weight, which changes no trajectory either."""
     x, y = _batch(rng)
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
     results = []
@@ -339,33 +318,11 @@ def test_trainer_equivalence_matrix_fused(rng, spec, dtype):
 
 
 @pytest.mark.parametrize("dtype", [None, "bfloat16"])
-def test_trainer_s2d_first_step_exact_then_rehomed_space(rng, dtype):
-    """The s2d PASS (by name only since PR 25: it is not in the default)
-    computes the EXACT same first-step loss as passes=False — the rewrite
-    is a forward reparameterization — and from step 2 on trains in the
-    re-homed stem space (the hand-flag twin's trajectory, not the 7x7
-    one), which is why the default path lowers the stem in the op."""
-    x, y = _batch(rng)
-    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
-    losses = []
-    for pas in ("fold,layout,s2d,fusion", False):
-        net = _conv_net("NCHW", "eqs2d_", stem=True)
-        tr = parallel.DataParallelTrainer(
-            net, loss_fn, "sgd", {"learning_rate": 0.1},
-            compute_dtype=dtype, passes=pas)
-        losses.append(float(tr.step(x, y)))
-        if pas:
-            assert tr.passes_provenance()["rewrites"].get("s2d") == 1
-    tol = 2e-2 if dtype else 1e-5
-    np.testing.assert_allclose(losses[0], losses[1], rtol=tol, atol=tol)
-
-
-@pytest.mark.parametrize("dtype", [None, "bfloat16"])
 def test_trainer_default_trains_the_models_own_stem(rng, dtype):
     """The default pipeline on a net with a 7x7/s2 stem follows passes=False
     for three momentum steps, parameters included: the stem is lowered
     through space-to-depth inside the Convolution op, so there is no padded
-    tap to train (the check PERF.md 7.1 failed under the old default)."""
+    tap to train."""
     from mxnet_tpu.observability import catalog
     x, y = _batch(rng)
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
@@ -379,8 +336,6 @@ def test_trainer_default_trains_the_models_own_stem(rng, dtype):
         losses.append([float(tr.step(x, y)) for _ in range(3)])
         # NCHW without the layout pass never reaches the lowering
         assert (catalog.CONV_S2D_LOWERED.value() > lowered) is (pas is None)
-        if pas is None:
-            assert "s2d" not in tr.passes_provenance()["rewrites"]
         tr.sync_to_net()
         stems.append(net.collect_params()["dflt3_c0_weight"].data().asnumpy())
     tol = 2e-2 if dtype else 1e-5
@@ -399,7 +354,7 @@ def test_trainer_equivalence_kv_path(rng, dtype):
     results = []
     for pas in (None, False):
         # no stride-2 stem: the default pipeline is trajectory-preserving
-        # here (s2d has nothing to rewrite), so all 3 steps must agree
+        # here, so all 3 steps must agree
         net = _conv_net("NCHW", "eqkv_")
         tr = parallel.DataParallelTrainer(
             net, loss_fn, "sgd", {"learning_rate": 0.1},
@@ -419,8 +374,6 @@ def test_trainer_default_rewrites_conv_net(rng):
     prov = tr.passes_provenance()
     assert prov["enabled"] and "layout" in prov["applied"]
     assert prov["rewrites"]["layout"] >= 3
-    # the 7x7/s2 stem is the op's to lower: no pass re-homes it to 4x4x12
-    assert prov["rewrites"].get("s2d", 0) == 0
     # trainer params live re-homed (OIHW -> OHWI); sync_to_net restores the
     # net layout
     assert tr._params["dflt_c0_weight"].shape == (8, 7, 7, 3)
@@ -481,14 +434,14 @@ def test_flag_vs_pass_bitwise_hlo_small_net(rng):
 
 
 def test_tuner_roundtrip_flag_vs_pass_resnet18(rng):
-    """The tuner's layout/s2d dimensions route through the passes:
+    """The tuner's layout dimension routes through the passes:
     Candidate.build_trainer(via_passes=True) on an NCHW-built net lowers
     to bitwise-identical StableHLO as the hand-flagged net (ResNet-50's
     full-size twin runs in the slow lane below)."""
     from mxnet_tpu.gluon.model_zoo import vision
     from mxnet_tpu.tuner import Candidate
     batch, image = 8, 32
-    cand = Candidate(batch, "NHWC", s2d=True)
+    cand = Candidate(batch, "NHWC")
     x = rng.uniform(-1, 1, cand.data_shape(image)).astype("float32")
     y = rng.randint(0, 10, (batch,)).astype("float32")
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
@@ -501,26 +454,25 @@ def test_tuner_roundtrip_flag_vs_pass_resnet18(rng):
     tr_a = cand.build_trainer(net_a, loss_fn, "sgd",
                               {"learning_rate": 0.1}, via_passes=True)
     mx.random.seed(3)
-    net_b = vision.resnet18_v1(classes=10, layout="NHWC", stem_s2d=True,
-                               prefix="rt18_")
+    net_b = vision.resnet18_v1(classes=10, layout="NHWC", prefix="rt18_")
     net_b.initialize(mx.init.Xavier())
     tr_b = cand.build_trainer(net_b, loss_fn, "sgd",
                               {"learning_rate": 0.1}, via_passes=False)
     assert tr_a._lowered_digest(tr_a.lower(x, y)) == \
         tr_b._lowered_digest(tr_b.lower(x, y))
     prov = tr_a.passes_provenance()
-    assert prov["rewrites"].get("s2d") == 1 and prov["input_layout"] == "NHWC"
+    assert prov["rewrites"]["layout"] >= 1 and prov["input_layout"] == "NHWC"
 
 
 @pytest.mark.slow
-def test_acceptance_resnet50_default_equals_hand_nhwc_s2d(rng):
+def test_acceptance_resnet50_default_equals_hand_nhwc(rng):
     """THE acceptance: the pass pipeline applied to the NCHW ResNet-50
-    trainer lowers to HLO bitwise-identical to the hand-flagged NHWC+S2D
-    variant from the seed ladder (the r4 measured win, now a default)."""
+    trainer lowers to HLO bitwise-identical to the hand-flagged NHWC
+    variant from the seed ladder."""
     from mxnet_tpu.gluon.model_zoo import vision
     from mxnet_tpu.tuner import Candidate
     batch, image = 8, 32
-    cand = Candidate(batch, "NHWC", s2d=True)
+    cand = Candidate(batch, "NHWC")
     x = rng.uniform(-1, 1, cand.data_shape(image)).astype("float32")
     y = rng.randint(0, 1000, (batch,)).astype("float32")
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
@@ -530,7 +482,7 @@ def test_acceptance_resnet50_default_equals_hand_nhwc_s2d(rng):
     tr_a = cand.build_trainer(net_a, loss_fn, "sgd",
                               {"learning_rate": 0.1}, via_passes=True)
     mx.random.seed(3)
-    net_b = vision.resnet50_v1(classes=1000, layout="NHWC", stem_s2d=True,
+    net_b = vision.resnet50_v1(classes=1000, layout="NHWC",
                                prefix="rt50_")
     net_b.initialize(mx.init.Xavier())
     tr_b = cand.build_trainer(net_b, loss_fn, "sgd",

@@ -1,7 +1,8 @@
-"""Space-to-depth stem (MLPerf ResNet trick): the 4x4/s1-over-12-channels
-conv must compute EXACTLY the original 7x7/s2-over-3-channels stem when its
-weights are the block-rearranged originals — the transform is a
-reparameterization, not an approximation.
+"""Space-to-depth stem: the Convolution op computes a stride-2 few-channel
+stem as a stride-(1,2) convolution over row pairs folded into channels. It
+must compute EXACTLY the convolution the model declares, value and
+gradients, on the model's own weight — a lowering, not an approximation —
+and it must engage where the op sees a stem and nowhere else.
 """
 import jax
 import jax.numpy as jnp
@@ -11,66 +12,18 @@ from jax import lax
 
 import mxnet_tpu as mx
 import mxnet_tpu.ops.nn as ops_nn
-from mxnet_tpu import gluon, nd, parallel
+from mxnet_tpu import gluon, parallel
+from mxnet_tpu.gluon import nn
 from mxnet_tpu.gluon.model_zoo import vision
-from mxnet_tpu.gluon.model_zoo.vision.resnet import (BasicBlockV1, ResNetV1,
-                                                     SpaceToDepthStem)
+from mxnet_tpu.gluon.model_zoo.vision.resnet import BasicBlockV1, ResNetV1
 from mxnet_tpu.observability import catalog
 from mxnet_tpu.ops import get_op
 
 
-def _s2d_weights(w):
-    """(O,7,7,3) OHWI -> (O,4,4,12) with W'[o,du,dv,(r*2+s)*3+c] =
-    W[o,2du+r,2dv+s,c], zero-padded where 2du+r > 6."""
-    O = w.shape[0]
-    out = np.zeros((O, 4, 4, 12), w.dtype)
-    for du in range(4):
-        for dv in range(4):
-            for r in range(2):
-                for s in range(2):
-                    u, v = 2 * du + r, 2 * dv + s
-                    if u < 7 and v < 7:
-                        out[:, du, dv, (r * 2 + s) * 3:(r * 2 + s) * 3 + 3] \
-                            = w[:, u, v, :]
-    return out
-
-
-def test_s2d_stem_exactly_matches_7x7_conv(rng):
-    B, H = 2, 32                      # any even spatial size works
-    x = rng.uniform(-1, 1, (B, H, H, 3)).astype("float32")
-    w = rng.uniform(-1, 1, (64, 7, 7, 3)).astype("float32")
-
-    ref = nd.Convolution(nd.array(x), nd.array(w), None, kernel=(7, 7),
-                         stride=(2, 2), pad=(3, 3), num_filter=64,
-                         no_bias=True, layout="NHWC")
-
-    mx.random.seed(0)
-    stem = SpaceToDepthStem(64, prefix="s2dtest_")
-    stem.initialize(mx.init.Xavier())
-    stem(nd.array(x))                 # materialize
-    stem.conv.weight.set_data(nd.array(_s2d_weights(w)))
-    got = stem(nd.array(x))
-
-    np.testing.assert_allclose(got.asnumpy(), ref.asnumpy(),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_resnet50_s2d_builds_and_runs(rng):
-    mx.random.seed(0)
-    net = vision.resnet50_v1(classes=10, layout="NHWC", stem_s2d=True)
-    net.initialize(mx.init.Xavier())
-    x = nd.array(rng.uniform(-1, 1, (2, 32, 32, 3)).astype("float32"))
-    out = net(x)
-    assert out.shape == (2, 10)
-    assert np.isfinite(out.asnumpy()).all()
-
-
-# --------------------------------------------------------------------------
-# The op-level lowering (PR 25): ``ops/nn.py`` computes a stem-shaped
-# Convolution through space-to-depth itself (of the rows: the form that costs
-# the image nothing on the chip), on the traced weight, so the parameter
-# keeps the model's (O,kh,kw,C) shape and so does its gradient.
-# --------------------------------------------------------------------------
+# ``ops/nn.py`` computes a stem-shaped Convolution through space-to-depth
+# itself (of the rows: the form that costs the image nothing on the chip), on
+# the traced weight, so the parameter keeps the model's (O,kh,kw,C) shape and
+# so does its gradient.
 _CONV = get_op("Convolution").fn
 
 
@@ -298,3 +251,51 @@ def test_default_passes_lower_to_the_cells_step(rng):
             {"learning_rate": 0.05, "momentum": 0.9}, **kw)
         digests.append(tr._lowered_digest(tr.lower(x, y)))
     assert digests[0] == digests[1]
+
+
+_ZOO = {
+    # id: (zoo name, net kwargs, trainer passes, image, engages)
+    "resnet18_v1-nhwc": ("resnet18_v1", {"layout": "NHWC"}, None, 32, True),
+    "resnet18_v2-nhwc": ("resnet18_v2", {"layout": "NHWC"}, None, 32, True),
+    "resnet18_v1-7x7s2": ("resnet18_v1", {}, None, 32, True),
+    "squeezenet1_0-7x7s2": ("squeezenet1_0", {}, None, 64, True),
+    "mobilenet1_0-3x3s2": ("mobilenet1_0", {}, None, 32, True),
+    "densenet121-7x7s2": ("densenet121", {}, None, 224, True),
+    "resnet18_v1-nchw-nopasses": ("resnet18_v1", {}, False, 32, False),
+    "resnet18_v1-thumbnail-3x3s1": ("resnet18_v1", {"thumbnail": True}, None,
+                                    32, False),
+    "alexnet-11x11s4": ("alexnet", {}, None, 64, False),
+    "vgg11-3x3s1": ("vgg11", {}, None, 32, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ZOO))
+def test_zoo_stems_take_the_ops_lowering_or_bypass_it(case):
+    """Where the stem's lowering is decided, over the zoo: in the op, from
+    the shapes it is handed. A net built channel-last hands its stem over as
+    it is; one built NCHW does once the default passes have made it
+    channel-last, and never under ``passes=False``. One trace of the train
+    step each, nothing compiled."""
+    name, kwargs, passes, image, engages = _ZOO[case]
+    mx.random.seed(0)
+    net = getattr(vision, name)(classes=10, **kwargs)
+    net.initialize(mx.init.Xavier())
+    convs = []
+    net.apply(lambda b: convs.append(b) if isinstance(b, nn.Conv2D) else None)
+    stem = convs[0]._kwargs               # children are visited in order
+    channel_last = stem["layout"] == "NHWC"
+    # what the op sees of the stem, by the predicate it decides with
+    assert ops_nn._s2d_eligible(
+        (8, image, image, 3), (stem["num_filter"],) + stem["kernel"] + (3,),
+        "NHWC" if channel_last or passes is None else "NCHW",
+        stem["stride"], stem["dilate"], stem["num_group"]) is engages
+    x = np.zeros((8, image, image, 3) if channel_last
+                 else (8, 3, image, image), "float32")
+    tr = parallel.DataParallelTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+        {"learning_rate": 0.1}, passes=passes)
+    before = _lowered_count()
+    text = tr.lower(x, np.zeros((8,), "float32")).as_text()
+    assert (_lowered_count() > before) is engages
+    # the lowering's mark in the program: a stride-(1,2) convolution
+    assert ("stride = [1, 2]" in text) is engages

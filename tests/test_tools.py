@@ -589,7 +589,7 @@ def test_mxopt_cli_json_and_dead_nodes(tmp_path):
     """tools/mxopt.py end-to-end: a saved NCHW conv graph gets layout
     rewrites + a before/after lint delta (MXL-G107 before, clean after),
     dead JSON nodes are counted, --emit round-trips, and a bad target
-    exits 2."""
+    or an unknown pass name exits 2."""
     import json
     import mxnet_tpu.symbol as sym_mod
 
@@ -630,6 +630,13 @@ def test_mxopt_cli_json_and_dead_nodes(tmp_path):
     p = subprocess.run([sys.executable, mxopt, str(tmp_path / "nope.json")],
                        capture_output=True, text=True, timeout=120, env=env)
     assert p.returncode == 2
+    # a name that is no pass (the stem's lowering is the op's) exits 2 too
+    p = subprocess.run(
+        [sys.executable, mxopt, str(gpath), "--shape", "data:2,3,8,8",
+         "--passes", "fold,layout,s2d,fusion"],
+        capture_output=True, text=True, timeout=120, env=env)
+    assert p.returncode == 2
+    assert "unknown graph pass 's2d' (registered: " in p.stderr
 
 
 # ------------------------------------------------------------- collbench

@@ -60,9 +60,9 @@ def _ledger(tmp_path):
 
 # ------------------------------------------------------------ search space
 def test_candidate_validation_and_roundtrip():
-    c = Candidate(512, "NHWC", s2d=True, remat="full", donate=False,
+    c = Candidate(512, "NHWC", remat="full", donate=False,
                   prefetch_depth=4)
-    assert c.label == "NHWC:512+s2d+remat=full+nodonate+pf4"
+    assert c.label == "NHWC:512+remat=full+nodonate+pf4"
     assert Candidate.from_dict(c.as_dict()) == c
     assert c.data_shape(224) == (512, 224, 224, 3)
     assert Candidate(8, "NCHW").data_shape(64) == (8, 3, 64, 64)
@@ -74,8 +74,8 @@ def test_candidate_validation_and_roundtrip():
     assert c.key("TPU v5e", compute_dtype="bfloat16") != c.key("TPU v5e")
     assert c.key("TPU v5e", optimizer=("sgd", ())) != \
         c.key("TPU v5e", optimizer=("adam", ()))
-    with pytest.raises(MXNetError):
-        Candidate(256, "NCHW", s2d=True)          # s2d is NHWC-only
+    with pytest.raises(TypeError):
+        Candidate(256, "NHWC", s2d=True)          # the stem is the op's
     with pytest.raises(MXNetError):
         Candidate(256, "NDHW")
     with pytest.raises(MXNetError):
@@ -86,19 +86,18 @@ def test_candidate_validation_and_roundtrip():
 
 def test_search_space_enumeration_and_spec():
     sp = SearchSpace(batch=(256, 512), layout=("NCHW", "NHWC"),
-                     s2d=(False, True), remat=(None, "full"))
+                     remat=(None, "full"))
     cands = sp.enumerate()
-    # s2d=True is skipped for NCHW, kept for NHWC: 2*[(1+2)]*2 = 12
-    assert len(cands) == 12
-    assert all(not (c.s2d and c.layout != "NHWC") for c in cands)
+    assert len(cands) == 8 and len(set(cands)) == 8
     # baseline = first value of every dimension
     assert sp.baseline() == Candidate(256, "NCHW")
     sp2 = SearchSpace.from_spec(
         "batch=8,64;layout=NHWC;remat=none,full;donate=1,0;prefetch=4")
     assert sp2.batch == (8, 64) and sp2.remat == (None, "full")
     assert sp2.donate == (True, False) and sp2.prefetch_depth == (4,)
-    with pytest.raises(MXNetError):
-        SearchSpace.from_spec("bogus=1")
+    for spec in ("bogus=1", "batch=8;s2d=1"):
+        with pytest.raises(MXNetError, match="unknown search-space dim"):
+            SearchSpace.from_spec(spec)
     with pytest.raises(MXNetError):
         SearchSpace.from_spec("layout=NHWC")      # batch is mandatory
 
@@ -106,17 +105,17 @@ def test_search_space_enumeration_and_spec():
 def test_variant_specs_map_to_candidates():
     specs = parse_variants(tuner.SEED_VARIANTS)
     assert [s.variant for s in specs] == \
-        ["NCHW:256", "NHWC:512", "S2D:256", "RMT:512"]
-    s2d = specs[2].to_candidate()
-    assert s2d.layout == "NHWC" and s2d.s2d
-    rmt = specs[3].to_candidate()
+        ["NCHW:256", "NHWC:512", "RMT:512"]
+    assert specs[1].to_candidate() == Candidate(512, "NHWC")
+    rmt = specs[2].to_candidate()
     assert rmt.remat == "full" and rmt.layout == "NHWC"
     imp = VariantSpec.parse("IMP:32")
     assert imp.imperative
     with pytest.raises(MXNetError):
         imp.to_candidate()
-    with pytest.raises(MXNetError):
-        VariantSpec.parse("XYZW:16")
+    for token in ("XYZW:16", "S2D:256"):
+        with pytest.raises(MXNetError, match="unknown variant label"):
+            VariantSpec.parse(token)
 
 
 # ------------------------------------------------------- learned correction
@@ -234,6 +233,45 @@ def test_predict_measure_cache_loop_and_warm_start(tmp_path, monkeypatch):
         [t.candidate.label for t in res.ranked()]
     assert res2.best.candidate == res.best.candidate
     assert res2.best.throughput == pytest.approx(res.best.throughput)
+
+
+def test_rows_of_an_older_tree_are_misses(tmp_path, monkeypatch):
+    """A cache file written while the tuner still had an ``s2d`` dimension:
+    every row's ``tuner_config`` and ``config_key`` hold an ``"s2d"`` entry.
+    Today's key has none, so such a row is a plain miss (never adopted as a
+    measurement of today's candidate), and reading it raises nothing."""
+    _peaks(monkeypatch)
+    led = _ledger(tmp_path)
+    cands = [Candidate(8, "NHWC"), Candidate(64, "NHWC")]
+    tuner.tune(_build, _data, candidates=cands, top_k=2, steps=2, warmup=1,
+               ledger=led, model="oldtree")
+    aged = []
+    for row in led.rows():
+        s2d = row["tuner_config"]["batch"] == 64   # one False, one True
+        row["tuner_config"]["s2d"] = s2d
+        row["config_key"] = json.dumps(
+            dict(json.loads(row["config_key"]), s2d=s2d), sort_keys=True)
+        # that tree's programs are not today's: leave only the key to match
+        row["fingerprint"] = "0" * 64
+        aged.append(json.dumps(row, sort_keys=True))
+    assert len(aged) == 4
+    with open(led.path, "w") as f:
+        f.write("\n".join(aged) + "\n")
+
+    built = []
+    def counting_build(cand):
+        built.append(cand)
+        return _build(cand)
+    res = tuner.tune(counting_build, _data, candidates=cands, top_k=2,
+                     steps=2, warmup=1, ledger=led, model="oldtree")
+    # every candidate lowered anew (and built again to be measured)
+    assert built[:2] == cands and len(built) == 4
+    assert [t.provenance for t in res.trials] == ["measured", "measured"]
+    rows = led.rows()
+    assert len(rows) == 8                       # the old rows stay, unread
+    assert all("s2d" not in r["tuner_config"] for r in rows[4:])
+    # an old row still reads back as a candidate of today
+    assert Candidate.from_dict(rows[0]["tuner_config"]) in cands
 
 
 def test_fingerprint_level_warm_start_skips_remeasure(tmp_path,

@@ -121,8 +121,8 @@ def main(argv=None):
                     help="declare channel-last feeds: rank-4 inputs are "
                          "re-homed instead of transposed in-graph")
     ap.add_argument("--rehome", action="store_true",
-                    help="allow variable re-homing (NHWC weights, s2d "
-                         "stem); reports the value transforms")
+                    help="allow variable re-homing (NHWC weights); "
+                         "reports the value transforms")
     ap.add_argument("--emit", default=None, metavar="PATH",
                     help="write the rewritten graph JSON")
     ap.add_argument("--suppress", action="append", default=[],

@@ -51,15 +51,14 @@ def _build_fns(args):
             np.random.seed(0)
             mx.random.seed(0)
             if args.route == "passes":
-                # the layout/s2d dimensions apply as graph passes over ONE
+                # the layout dimension applies as graph passes over ONE
                 # NCHW-built net (Candidate.passes_manager): bitwise the
                 # same HLO as the hand-flagged net, no per-candidate net
                 # zoo variants
                 net = vision.resnet50_v1(classes=args.classes)
             else:
                 net = vision.resnet50_v1(classes=args.classes,
-                                         layout=cand.layout,
-                                         stem_s2d=cand.s2d)
+                                         layout=cand.layout)
             net.initialize(mx.init.Xavier())
             return net, gluon.loss.SoftmaxCrossEntropyLoss()
 
@@ -79,7 +78,7 @@ def _build_fns(args):
 
     if args.model == "tiny":
         # a small MLP: exercises the full predict->measure->cache loop in
-        # seconds on the CPU backend (layout/s2d are no-ops for 2-D data)
+        # seconds on the CPU backend (layout is a no-op for 2-D data)
         def build(cand):
             import mxnet_tpu as mx
             from mxnet_tpu import gluon
@@ -132,7 +131,7 @@ def main(argv=None) -> int:
                          "bucket_bytes=none,4194304'")
     ap.add_argument("--seed-ladder", action="store_true",
                     help="search the staged bench ladder variants "
-                         "(RMT:512, S2D:256, NHWC:512, NCHW:256) instead "
+                         "(RMT:512, NHWC:512, NCHW:256) instead "
                          "of a cross-product space")
     ap.add_argument("--image", type=int, default=224)
     ap.add_argument("--classes", type=int, default=1000)
@@ -152,7 +151,7 @@ def main(argv=None) -> int:
                          "default stages data device-resident like "
                          "perf_lab)")
     ap.add_argument("--route", choices=("passes", "flags"), default="passes",
-                    help="how layout/s2d candidates apply: 'passes' (the "
+                    help="how layout candidates apply: 'passes' (the "
                          "default) rewrites one NCHW-built net through the "
                          "graph-pass pipeline — bitwise-identical HLO to "
                          "'flags', which builds hand-flagged net variants")
