@@ -134,6 +134,7 @@ _PARAM_SHAPE_RULES: Dict[str, Callable] = {
     "_contrib_quantize": _quantize_param_shapes,
     "Deconvolution": _deconv_param_shapes,
     "BatchNorm": _bn_param_shapes,
+    "_MaxPoolBatchNorm": _bn_param_shapes,
     "LayerNorm": _ln_param_shapes,
     "InstanceNorm": _in_param_shapes,
     "Embedding": _emb_param_shapes,
@@ -151,7 +152,8 @@ def _bn_aux_update(attrs, ins, outs):
     return {3: jax.lax.stop_gradient(new_mean), 4: jax.lax.stop_gradient(new_var)}
 
 
-_AUX_UPDATE_RULES: Dict[str, Callable] = {"BatchNorm": _bn_aux_update}
+_AUX_UPDATE_RULES: Dict[str, Callable] = {
+    "BatchNorm": _bn_aux_update, "_MaxPoolBatchNorm": _bn_aux_update}
 
 
 class _GraphLowering:

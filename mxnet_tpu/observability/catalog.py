@@ -69,6 +69,16 @@ POOL_BWD_LOWERED = _m.counter(
     "stays 0 reached the op in a shape, or in a program whose split of the "
     "batch the op cannot see, that keeps reduce_window's own gradient.")
 
+POOL_SUNK = _m.counter(
+    "mxtpu_pool_sunk_total",
+    "Max pools computed in front of the BatchNorm apply that fed them "
+    "(the fusion pass's _MaxPoolBatchNorm: maxpool(y*s + b) == "
+    "|s| * maxpool(sgn(s)*y) + b, exact), so the apply and the ReLU behind "
+    "it run on the pooled map. Counted when the op is traced: once per "
+    "trace of such a stem, never per step; a net whose counter stays 0 has "
+    "no BatchNorm whose only consumer is a max pool, directly or through a "
+    "ReLU, or was captured with the fusion pass off.")
+
 # -------------------------------------------------------------------- io
 IO_BATCHES = _m.counter(
     "mxtpu_io_batches_total",

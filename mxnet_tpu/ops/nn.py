@@ -30,6 +30,17 @@ def _pair(v, n=2):
     return tuple(int(x) for x in v)
 
 
+def _per_channel(v, ax, ndim):
+    """A per-channel vector, shaped to broadcast against the data."""
+    return v.reshape(tuple(-1 if i == ax else 1 for i in range(ndim)))
+
+
+def _signed(ax, x, *sign):
+    """``x``, or ``x * sign`` for a vector over axis ``ax`` where one is
+    given."""
+    return x * _per_channel(sign[0], ax, x.ndim) if sign else x
+
+
 # ---------------------------------------------------------------- FullyConnected
 @register("FullyConnected", arg_names=("data", "weight", "bias"))
 def _fully_connected(data, weight, bias=None, num_hidden=None, no_bias=False,
@@ -238,15 +249,22 @@ def _pool_tap_eligible(data, lhs, kernel, stride, global_pool):
 
 def _per_shard(kernel_fn, lhs, split):
     """``kernel_fn`` on each device's own rows where ``jit`` splits the batch
-    over a mesh axis (``_pool_batch_split``): every argument and result
-    carries the batch on the same dimension."""
+    over a mesh axis (``_pool_batch_split``): every rank-4 argument and the
+    result carry the batch on the same dimension; a per-channel vector (the
+    sign of a sunk pool) is whole on every device."""
     if not split:
         return kernel_fn
     from jax.sharding import PartitionSpec
     mesh, axis = split
     spec = PartitionSpec(*(axis if a == "N" else None for a in lhs))
-    return jax.shard_map(kernel_fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                         check_vma=False)
+
+    def sharded(*args):
+        return jax.shard_map(
+            kernel_fn, mesh=mesh, out_specs=spec, check_vma=False,
+            in_specs=tuple(spec if a.ndim == 4 else PartitionSpec()
+                           for a in args))(*args)
+
+    return sharded
 
 
 @functools.lru_cache(maxsize=None)
@@ -260,7 +278,13 @@ def _max_pool_taps(lhs, kernel, stride, padding, split=()):
     tap it is, summed in float32: two Pallas kernels (PERF.md, PR 28), each
     device on its own rows (``split``, as the op saw it when it was traced).
     Where the program is lowered for another platform (a CPU context in a
-    TPU process) both are ``reduce_window``'s own, from the input."""
+    TPU process) both are ``reduce_window``'s own, from the input.
+
+    ``pool(x, sign)`` pools ``x * sign`` for a per-channel ``sign`` of +-1
+    (a pool sunk in front of a BatchNorm, ``_max_pool_batch_norm``) without
+    a pass over ``x`` for it: the forward kernel multiplies the block it has
+    loaded, and the backward folds the sign into dy at the pooled size,
+    since scatter(dy) * sign == scatter(dy * sign)."""
     axes = [lhs.index("H"), lhs.index("W")]
     window = tuple(kernel[axes.index(d)] if d in axes else 1 for d in range(4))
     strides = tuple(stride[axes.index(d)] if d in axes else 1 for d in range(4))
@@ -276,31 +300,36 @@ def _max_pool_taps(lhs, kernel, stride, padding, split=()):
             return on_chip(*args)
         return lax.platform_dependent(*args, tpu=on_chip, default=elsewhere)
 
-    def plain(x):
-        return lax.reduce_window(x, -jnp.inf, lax.max, window, strides, padding)
+    signed = functools.partial(_signed, lhs.index("C"))
 
-    def fwd_kernel(x):
+    def plain(x, *sign):
+        return lax.reduce_window(signed(x, *sign), -jnp.inf, lax.max, window,
+                                 strides, padding)
+
+    def fwd_kernel(x, *sign):
         n_out = tuple((x.shape[a] + sum(padding[a]) - k) // s + 1
                       for a, k, s in zip(axes, kernel, stride))
-        out, idx = _pk.max_pool_fwd(x.transpose(hwcn), n_out, *geometry)
+        out, idx = _pk.max_pool_fwd(x.transpose(hwcn), n_out, *geometry,
+                                    *sign)
         return out.transpose(back), idx.transpose(back)
 
-    def fwd_plain(x):
-        out = plain(x)
+    def fwd_plain(x, *sign):
+        out = plain(x, *sign)
         return out, jnp.zeros(out.shape, jnp.int8)      # read by nothing
 
-    def fwd(x):
+    def fwd(x, *sign):
         # runs when a differentiated pool is traced: once per trace
         from ..observability import catalog as _catalog, metrics as _metrics
         if _metrics.enabled():
             _catalog.POOL_BWD_LOWERED.inc()
-        out, idx = route(_per_shard(fwd_kernel, lhs, split), fwd_plain, x)
+        out, idx = route(_per_shard(fwd_kernel, lhs, split), fwd_plain, x,
+                         *sign)
         # x is for the other platforms' gradient: on the TPU nothing reads
         # it after the forward, and inside one program XLA lets it go
-        return out, (idx, x)
+        return out, (idx, x, sign)
 
     def bwd(res, dy):
-        idx, x = res
+        idx, x, sign = res
 
         def bwd_kernel(idx, dy):
             # dy as its producer makes it, then the bitcast: without the
@@ -313,23 +342,25 @@ def _max_pool_taps(lhs, kernel, stride, padding, split=()):
                 (x.shape[axes[0]], x.shape[axes[1]]), *geometry)
             return dx.transpose(back)
 
-        def on_chip(idx, x, dy):
+        def on_chip(idx, x, dy, *sign):
             return _per_shard(bwd_kernel, lhs, split)(idx, dy)
 
-        def bwd_plain(idx, x, dy):
-            return jax.vjp(plain, x)[1](dy)[0]
+        def bwd_plain(idx, x, dy, *sign):
+            return jax.vjp(plain, signed(x, *sign))[1](dy)[0]
 
-        return (route(on_chip, bwd_plain, idx, x, dy),)
+        dx = route(on_chip, bwd_plain, idx, x, signed(dy, *sign), *sign)
+        return (dx,) + tuple(jnp.zeros_like(s) for s in sign)
 
     pool = jax.custom_vjp(plain)
     pool.defvjp(fwd, bwd)
     return pool
 
 
-@register("Pooling", arg_names=("data",))
-def _pooling(data, kernel=(), pool_type="max", global_pool=False, stride=(), pad=(),
-             pooling_convention="valid", cudnn_off=False, p_value=2,
-             count_include_pad=True, layout=None):
+def _pool_geometry(data, kernel, global_pool, stride, pad, pooling_convention,
+                   layout):
+    """``(lhs, kernel, stride, window, strides, padding)`` of a Pooling's
+    attributes over ``data``: the last three as ``reduce_window`` takes them
+    (kFull: the output size is the ceiling, reference pooling-inl.h)."""
     nd = data.ndim - 2
     lhs, _ = _conv_layout(nd, layout)
     spatial = [i for i, a in enumerate(lhs) if a not in ("N", "C")]
@@ -348,22 +379,35 @@ def _pooling(data, kernel=(), pool_type="max", global_pool=False, stride=(), pad
         strides[ax] = stride[i]
         lo = hi = pad[i]
         if pooling_convention == "full":
-            # ceil output size (reference pooling-inl.h kFull)
             size = data.shape[ax]
             out_sz = -(-(size + 2 * pad[i] - kernel[i]) // stride[i]) + 1
             need = (out_sz - 1) * stride[i] + kernel[i] - size - pad[i]
             hi = max(need, pad[i])
         padding[ax] = (lo, hi)
-    window = tuple(window)
-    strides = tuple(strides)
-    padding = tuple(padding)
+    return lhs, kernel, stride, tuple(window), tuple(strides), tuple(padding)
+
+
+def _max_pool(data, geometry, global_pool, *sign):
+    """The max pool of ``data``, or of ``data * sign`` for a per-channel
+    ``sign`` of +-1: from the saved winning tap where the kernels take it
+    (``_pool_tap_eligible``), else ``reduce_window`` and its own gradient."""
+    lhs, kernel, stride, window, strides, padding = geometry
+    if _pool_tap_eligible(data, lhs, kernel, stride, global_pool):
+        return _max_pool_taps(lhs, kernel, stride, padding,
+                              _pool_batch_split())(data, *sign)
+    return lax.reduce_window(_signed(lhs.index("C"), data, *sign), -jnp.inf,
+                             lax.max, window, strides, padding)
+
+
+@register("Pooling", arg_names=("data",))
+def _pooling(data, kernel=(), pool_type="max", global_pool=False, stride=(), pad=(),
+             pooling_convention="valid", cudnn_off=False, p_value=2,
+             count_include_pad=True, layout=None):
+    geometry = _pool_geometry(data, kernel, global_pool, stride, pad,
+                              pooling_convention, layout)
+    _, kernel, _, window, strides, padding = geometry
     if pool_type == "max":
-        if _pool_tap_eligible(data, lhs, kernel, stride, global_pool):
-            out = _max_pool_taps(lhs, kernel, stride, padding,
-                                 _pool_batch_split())(data)
-        else:
-            out = lax.reduce_window(data, -jnp.inf, lax.max, window, strides,
-                                    padding)
+        out = _max_pool(data, geometry, global_pool)
     elif pool_type in ("avg", "sum"):
         out = lax.reduce_window(data, 0.0, lax.add, window, strides, padding)
         if pool_type == "avg":
@@ -386,15 +430,12 @@ def _pooling(data, kernel=(), pool_type="max", global_pool=False, stride=(), pad
 
 
 # ---------------------------------------------------------------- Norms
-@register("BatchNorm", num_outputs=3,
-          arg_names=("data", "gamma", "beta", "moving_mean", "moving_var"),
-          aux_args=("moving_mean", "moving_var"))
-def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3, momentum=0.9,
-                fix_gamma=True, use_global_stats=False, output_mean_var=False,
-                axis=1, cudnn_off=False, is_train=True):
-    ax = int(axis) % data.ndim
+def _bn_scale_shift(data, gamma, beta, moving_mean, moving_var, eps, fix_gamma,
+                    use_global_stats, ax, is_train):
+    """BatchNorm as a per-channel affine map: ``(scale, shift, mean, var)``,
+    float32 vectors, with ``out = data * scale + shift``; the statistics are
+    the batch's own when training, else the running ones."""
     red = tuple(i for i in range(data.ndim) if i != ax)
-    bshape = tuple(data.shape[ax] if i == ax else 1 for i in range(data.ndim))
     g = jnp.ones_like(gamma) if fix_gamma else gamma
     if is_train and not use_global_stats:
         # ONE pass over the activation for both statistics: E[x] and E[x^2]
@@ -418,8 +459,67 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3, momentum=0
     inv = lax.rsqrt(var + eps)
     scale = (inv * g.astype(jnp.float32))
     shift = beta.astype(jnp.float32) - mean * scale
-    out = data * scale.astype(data.dtype).reshape(bshape) \
-        + shift.astype(data.dtype).reshape(bshape)
+    return scale, shift, mean, var
+
+
+_BN_ARGS = dict(num_outputs=3,
+                arg_names=("data", "gamma", "beta", "moving_mean",
+                           "moving_var"),
+                aux_args=("moving_mean", "moving_var"))
+
+
+@register("BatchNorm", **_BN_ARGS)
+def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3, momentum=0.9,
+                fix_gamma=True, use_global_stats=False, output_mean_var=False,
+                axis=1, cudnn_off=False, is_train=True):
+    ax = int(axis) % data.ndim
+    scale, shift, mean, var = _bn_scale_shift(
+        data, gamma, beta, moving_mean, moving_var, eps, fix_gamma,
+        use_global_stats, ax, is_train)
+    out = data * _per_channel(scale.astype(data.dtype), ax, data.ndim) \
+        + _per_channel(shift.astype(data.dtype), ax, data.ndim)
+    return out, mean.astype(moving_mean.dtype), var.astype(moving_var.dtype)
+
+
+@register("_MaxPoolBatchNorm", **_BN_ARGS)
+def _max_pool_batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
+                         momentum=0.9, fix_gamma=True, use_global_stats=False,
+                         output_mean_var=False, axis=1, cudnn_off=False,
+                         is_train=True, pool_kernel=(), pool_stride=(),
+                         pool_pad=(), pool_convention="valid",
+                         pool_layout=None):
+    """``Pooling(BatchNorm(data), pool_type="max")`` with the pool in front
+    of BatchNorm's apply: what the ``fusion`` pass makes of that pair
+    (docs/passes.md), so the apply, and whatever per-channel non-decreasing
+    map follows it, runs on the pooled map. Exact, rounding included: per
+    channel the apply ``y -> y*s + b`` is monotone, non-decreasing for
+    s >= 0 and non-increasing for s < 0, and a maximum commutes with a
+    non-decreasing map, so
+
+        maxpool(y*s + b) == |s| * maxpool(sgn(s) * y) + b      sgn(0) := +1
+
+    The statistics are BatchNorm's own, from all of ``data``; the pool is
+    the Pooling op's (``_max_pool``: kernels, per-shard route, bypasses),
+    which takes the sign without a pass over ``data`` for it; the gradient
+    is autodiff's. Among taps that the apply ROUNDS to one value the
+    gradient goes to the tap whose ``data`` is largest, where the unsunk
+    pair gives it to the first of them."""
+    ax = int(axis) % data.ndim
+    scale, shift, mean, var = _bn_scale_shift(
+        data, gamma, beta, moving_mean, moving_var, eps, fix_gamma,
+        use_global_stats, ax, is_train)
+    # runs when the op is traced: once per trace of such a stem, not per step
+    from ..observability import catalog as _catalog, metrics as _metrics
+    if _metrics.enabled():
+        _catalog.POOL_SUNK.inc()
+    scale = scale.astype(data.dtype)
+    # |s| below is s * sign, whose derivative at 0 is sgn(0) = +1 too
+    sign = jnp.where(scale < 0, -1, 1).astype(data.dtype)
+    pooled = _max_pool(
+        data, _pool_geometry(data, pool_kernel, False, pool_stride, pool_pad,
+                             pool_convention, pool_layout), False, sign)
+    out = pooled * _per_channel(scale * sign, ax, data.ndim) \
+        + _per_channel(shift.astype(data.dtype), ax, data.ndim)
     return out, mean.astype(moving_mean.dtype), var.astype(moving_var.dtype)
 
 

@@ -477,14 +477,20 @@ def _remember(hists, curs, depth):
         hist[depth - 1] = cur[...]
 
 
-def _pool_fwd_kernel(x_ref, out_ref, idx_ref, *hist, kernel, stride, pad_lo,
-                     h, w, n_h, ahead, depth):
+def _pool_fwd_kernel(x_ref, *refs, kernel, stride, pad_lo, h, w, n_h, ahead,
+                     depth, signed):
     """One step loads input rows [s_h*j, s_h*j + s_h) and writes output row
     o = j - ahead with the winning tap of each of its windows. A later tap
     wins only by being GREATER, so the first among equals is kept: what
     select-and-scatter with ``ge`` picks. A tap outside the map holds -inf;
     only taps that can fall outside are masked. The k_w - s_w columns a
-    window shares with the next one are carried, not loaded again."""
+    window shares with the next one are carried, not loaded again. Where
+    ``signed``, the second ref is a (channels, 1) column of +-1 and every tap
+    is multiplied by its channel's as it is read (exact), so the pool is of
+    ``x * sign`` and no pass over ``x`` makes that product."""
+    if signed:
+        sign_ref, *refs = refs
+    out_ref, idx_ref, *hist = refs
     (kh, kw), (sh, sw), (lo_h, lo_w) = kernel, stride, pad_lo
     n_w = out_ref.shape[1]
     o = pl.program_id(2) - ahead
@@ -498,6 +504,7 @@ def _pool_fwd_kernel(x_ref, out_ref, idx_ref, *hist, kernel, stride, pad_lo,
                      None if inside else (e >= 0) & (e < h)))
     shared = max(kw - sw, 0)
     tile = out_ref.shape[2:]
+    sign = jnp.broadcast_to(sign_ref[...], tile) if signed else None
 
     def value(ref, r, row_ok, j, ow=None):
         """Tap value as float32, -inf outside the map (``row_ok`` None: this
@@ -513,6 +520,8 @@ def _pool_fwd_kernel(x_ref, out_ref, idx_ref, *hist, kernel, stride, pad_lo,
             ok = col_ok if ok is None else ok & col_ok
             col = jnp.clip(col, 0, w - 1)
         v = ref[r, col].astype(jnp.float32)
+        if signed:
+            v = v * sign
         return v if ok is None else jnp.where(ok, v, -jnp.inf)
 
     def column(ow, carried):
@@ -646,14 +655,16 @@ def _pool_call(name, kernel_fn, grid, in_specs, out_specs, out_shape, scratch,
             interpret=_interpret())(*args)
 
 
-def max_pool_fwd(x, out_hw, kernel, stride, pad_lo):
+def max_pool_fwd(x, out_hw, kernel, stride, pad_lo, sign=None):
     """Max pool of (H, W, C, N) and the winning tap of each window, in one
     read of the input: (out, idx), both (n_h, n_w, C, N), idx int8 in
-    row-major window order, first among equals."""
+    row-major window order, first among equals. With ``sign`` (C,) of +-1,
+    the pool of ``x * sign``, the product made block by block in VMEM."""
     h, w, c, n = x.shape
     (n_h, n_w), (kh, _), (sh, _) = out_hw, kernel, stride
     cb = _channel_block(x.shape, kernel, stride, x.dtype.itemsize)
     nb = _POOL_LANES
+    signs = [] if sign is None else [sign.astype(jnp.float32).reshape(c, 1)]
     ahead, behind = _reach([(i - pad_lo[0]) // sh for i in range(kh)])
     depth = ahead + behind
     dst = pl.BlockSpec((1, n_w, cb, nb), lambda b, ch, j: (
@@ -662,14 +673,16 @@ def max_pool_fwd(x, out_hw, kernel, stride, pad_lo):
         "max_pool_fwd",
         functools.partial(_pool_fwd_kernel, kernel=kernel, stride=stride,
                           pad_lo=pad_lo, h=h, w=w, n_h=n_h, ahead=ahead,
-                          depth=depth),
+                          depth=depth, signed=sign is not None),
         (n // nb, c // cb, n_h + ahead),
         [pl.BlockSpec((sh, w, cb, nb), lambda b, ch, j: (
-            jnp.minimum(j, h // sh - 1), 0, ch, b))],
+            jnp.minimum(j, h // sh - 1), 0, ch, b))]
+        + [pl.BlockSpec((cb, 1), lambda b, ch, j: (ch, 0))] * len(signs),
         [dst, dst],
         [jax.ShapeDtypeStruct((n_h, n_w, c, n), x.dtype, **_vma_kw(x)),
          jax.ShapeDtypeStruct((n_h, n_w, c, n), jnp.int8, **_vma_kw(x))],
-        [pltpu.VMEM((depth, sh, w, cb, nb), x.dtype)] if depth else [], x)
+        [pltpu.VMEM((depth, sh, w, cb, nb), x.dtype)] if depth else [], x,
+        *signs)
 
 
 def max_pool_bwd(idx, dy, in_hw, kernel, stride, pad_lo):

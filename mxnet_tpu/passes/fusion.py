@@ -1,6 +1,7 @@
-"""Fusion-friendly reordering: hoist casts/transposes so XLA fuses across.
+"""Fusion-friendly reordering: hoist casts/transposes so XLA fuses across,
+and sink max pools in front of the per-channel monotone maps that feed them.
 
-Three structural rewrites, iterated to a fixpoint:
+Four structural rewrites, iterated to a fixpoint:
 
 * **compose/cancel** — ``transpose(transpose(x, q), p)`` becomes one
   transpose with the composed permutation, or disappears entirely when the
@@ -11,20 +12,30 @@ Three structural rewrites, iterated to a fixpoint:
   fusing the convert into the producer's HBM pass).  Sinking moves
   transposes toward consumers where the compose rule can cancel them;
 * **sink through binary** — ``add(transpose(x), transpose(y))`` with equal
-  permutations → ``transpose(add(x, y))``.
+  permutations → ``transpose(add(x, y))``;
+* **sink a max pool** — ``maxpool(relu(x))`` → ``relu(maxpool(x))``, then
+  ``maxpool(BatchNorm(x))`` → ``_MaxPoolBatchNorm(x)`` (``ops/nn.py``): the
+  statistics still come from all of ``x``, the apply and the ReLU run on
+  the pooled map, a quarter of the size for a ResNet stem.
 
-All three are bitwise-exact (pure data-movement reordering around
-elementwise math), so they're validated by bitwise equivalence tests.
-Rewrites only fire when the transposed intermediate has a single consumer —
-duplicating a transpose to sink it would pessimize.
+The three transpose rules are bitwise-exact (pure data-movement reordering
+around elementwise math). The pool rule is exact in VALUE, rounding
+included: a maximum commutes with a non-decreasing map, a ReLU is one and
+so is BatchNorm's per-channel ``y*s + b`` once the sign of ``s`` is taken
+into the pool (docs/passes.md). Its gradient is the unsunk graph's up to
+WHICH of several taps that the maps send to one value wins (the first
+there, the one whose ``x`` is largest here). Rewrites only fire when the
+intermediate has a single consumer — duplicating a transpose to sink it, or
+keeping the full-size map for a second reader, would pessimize.
 """
 from __future__ import annotations
 
 from typing import Dict, Tuple
 
 from ..symbol.symbol import Symbol, _Node
+from ..ops.nn import _conv_layout
 from .manager import Pass, PassContext, Namer, is_barrier, register_pass
-from .layout import UNARY_ELEMWISE, MULTI_ELEMWISE
+from .layout import UNARY_ELEMWISE, MULTI_ELEMWISE, _truthy
 
 __all__ = ["FusionReorderPass"]
 
@@ -42,6 +53,35 @@ def _is_transpose(node) -> bool:
     return node is not None and node.op == "transpose" and bool(_axes_of(node))
 
 
+def _is_max_pool(node) -> bool:
+    attrs = node.attrs or {}
+    return node.op == "Pooling" and attrs.get("pool_type", "max") == "max" \
+        and not _truthy(attrs.get("global_pool", False))
+
+
+def _is_relu(node) -> bool:
+    return node.op == "relu" or (
+        node.op == "Activation"
+        and (node.attrs or {}).get("act_type", "relu") == "relu")
+
+
+#: the Pooling attributes a max pool is made of, as _MaxPoolBatchNorm names them
+_POOL_ATTRS = {"kernel": "pool_kernel", "stride": "pool_stride",
+               "pad": "pool_pad", "pooling_convention": "pool_convention",
+               "layout": "pool_layout"}
+
+
+def _bn_on_pool_channels(bn, pool) -> bool:
+    """Whether BatchNorm's axis is the pool's channel axis."""
+    kernel = tuple((pool.attrs or {}).get("kernel") or ())
+    try:
+        lhs, _ = _conv_layout(len(kernel), (pool.attrs or {}).get("layout"))
+        axis = int((bn.attrs or {}).get("axis", 1))
+    except (KeyError, TypeError, ValueError):
+        return False
+    return len(lhs) == len(kernel) + 2 and axis % len(lhs) == lhs.find("C")
+
+
 @register_pass
 class FusionReorderPass(Pass):
     name = "fusion"
@@ -57,7 +97,8 @@ class FusionReorderPass(Pass):
 
     def _round(self, sym: Symbol):
         nodes = sym.topo_nodes()
-        if not any(_is_transpose(n) for n in nodes if not n.is_var):
+        if not any(_is_transpose(n) or _is_max_pool(n)
+                   for n in nodes if not n.is_var):
             return sym, 0
         consumers: Dict[int, int] = {}
         for n in nodes:
@@ -148,6 +189,30 @@ class FusionReorderPass(Pass):
                 register(node, out_t)
                 count += 1
                 continue
+
+            # ---- sink a max pool in front of the single-consumer ReLU or
+            # BatchNorm that feeds it (the producer as this round found it:
+            # one that an earlier rule of the round rewrote waits a round)
+            if _is_max_pool(node) and len(ins) == 1 and ins[0][1] == 0 \
+                    and consumers.get(id(node.inputs[0][0]), 0) == 1 \
+                    and ins[0][0].op == node.inputs[0][0].op:
+                fed = ins[0][0]
+                if _is_relu(fed):
+                    pooled = clone(node, [fed.inputs[0]])
+                    register(node, (clone(fed, [(pooled, 0)]), 0))
+                    count += 1
+                    continue
+                if fed.op == "BatchNorm" and _bn_on_pool_channels(fed, node):
+                    attrs = dict(fed.attrs)
+                    for k, v in _POOL_ATTRS.items():
+                        if k in node.attrs:
+                            attrs[v] = node.attrs[k]
+                    sunk = _Node("_MaxPoolBatchNorm", fed.name, attrs,
+                                 list(fed.inputs))
+                    sunk._attr_dict = dict(fed._attr_dict)
+                    register(node, (sunk, 0))
+                    count += 1
+                    continue
 
             register(node, clone(node, ins))
 

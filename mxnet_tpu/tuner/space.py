@@ -36,6 +36,19 @@ REMAT_MODES = (None, "none", "full", "dots")
 GRAD_REDUCE_MODES = ("all_reduce", "reduce_scatter")
 
 
+def _graph_rules(input_layout: Optional[str] = None):
+    """The trainer's default pipeline, pinned: with ``layout`` where an
+    NCHW-built net is to run channel-last (``input_layout``), else without
+    it.  The rules that do not depend on the layout (``fold``; ``fusion``,
+    which sinks a stem's max pool in front of its BatchNorm) are part of
+    every default trainer's step, so the flags route, the passes route and
+    the NCHW baseline all measure that step and differ in layout alone."""
+    from ..passes import DEFAULT_PIPELINE, PassManager
+    if input_layout:
+        return PassManager(DEFAULT_PIPELINE, input_layout=input_layout)
+    return PassManager([p for p in DEFAULT_PIPELINE if p != "layout"])
+
+
 def _norm_remat(remat) -> Optional[str]:
     if remat in (None, "none"):
         return None
@@ -194,13 +207,12 @@ class Candidate:
         flag-vs-pass route.  ``input_layout="NHWC"`` because the
         candidate's ``data_shape`` feeds channel-last batches; the
         rewritten step is bitwise-HLO-identical to the hand-flagged net
-        (the tuner round-trip acceptance test).  ``None`` for NCHW
-        candidates — the baseline IS the unrewritten graph."""
+        under :func:`_graph_rules` (the tuner round-trip acceptance test).
+        An NCHW candidate has no layout to rewrite and takes those rules
+        alone, as the flags route does."""
         if self.layout != "NHWC":
-            return None
-        from ..passes import PassManager
-        return PassManager(["fold", "layout", "fusion"],
-                           input_layout="NHWC")
+            return _graph_rules()
+        return _graph_rules(input_layout="NHWC")
 
     def build_trainer(self, net, loss_fn, optimizer: str = "sgd",
                       optimizer_params: Optional[Dict] = None,
@@ -215,14 +227,13 @@ class Candidate:
         built NCHW, and the candidate's pipeline rewrites the captured
         graph to the identical HLO.  Either way the candidate PINS its
         pass configuration explicitly (the flags route runs
-        ``passes=False``) — a tuner trial must measure exactly its
-        declared config, never the ambient default pipeline."""
+        :func:`_graph_rules`, the default pipeline less ``layout``) — a
+        tuner trial must measure exactly its declared config, never the
+        ambient ``MXNET_PASSES``."""
         from ..parallel import DataParallelTrainer
         kw = self.trainer_kwargs()
-        if via_passes:
-            kw["passes"] = self.passes_manager() or False
-        else:
-            kw["passes"] = False
+        kw["passes"] = self.passes_manager() if via_passes \
+            else _graph_rules()
         kw.update(extra)
         return DataParallelTrainer(net, loss_fn, optimizer,
                                    optimizer_params or {}, **kw)

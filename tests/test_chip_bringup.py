@@ -29,17 +29,23 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # ------------------------------------------------- compiles for the chip
 @pytest.fixture(scope="module")
-def one_chip():
-    """Sharding on one device of a described v5e 2x2 host."""
+def four_chips():
+    """The devices of a described v5e 2x2 host."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:   # no libtpu here, or it cannot describe a v5e
         pytest.skip("TPU topology cannot be described: %r" % (e,))
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    """Sharding on one device of that host."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(four_chips[0])
 
 
 @pytest.fixture
@@ -181,6 +187,68 @@ def test_stem_weight_gradient_stays_space_to_depth_on_v5e(one_chip,
     # rows are stride 1 (the blocks), columns keep the stem's stride 2
     assert "rhs_dilate=1x2" in convs[0] and "pad=2_1x3_" in convs[0]
     assert tuple(grad.out_info.shape) == (64, 7, 7, 3)
+
+
+@pytest.mark.parametrize("batch,chips", [(256, 1), (512, 1), (1024, 4)],
+                         ids=["resnet50", "resnet34", "resnet50-dp4"])
+def test_sunk_pool_leaves_no_full_size_pass_on_v5e(four_chips,
+                                                   no_compile_cache,
+                                                   monkeypatch, batch, chips):
+    """The cells' stem with its pool sunk (``_MaxPoolBatchNorm``, PR 30) and
+    one 3x3 convolution behind it, forward and backward at the cells' sizes:
+    the stem map [N,112,112,64] is written by the stem convolution (its
+    statistics in the epilogue), read by the pool's kernel as a bitcast,
+    written by the scatter and read by the weight gradient. No loop fusion
+    touches it: not BatchNorm's apply or the ReLU in front of the pool, not a
+    product with the sign, not BatchNorm's backward reductions behind the
+    scatter. The sign reaches the kernel as a column of 64. On a ``dp`` mesh
+    of the host's four chips, as ``DataParallelTrainer`` names it, each chip
+    holds that program on its own 256 rows and the whole column."""
+    import re
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from mxnet_tpu.ops import get_op
+    conv, bn_pool = get_op("Convolution").fn, get_op("_MaxPoolBatchNorm").fn
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+
+    def loss(w, gamma, beta, w1, x, cot):
+        y = conv(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16), None,
+                 kernel=(7, 7), stride=(2, 2), pad=(3, 3), num_filter=64,
+                 no_bias=True, layout="NHWC")
+        a, _, _ = bn_pool(y, gamma, beta, jnp.zeros(64), jnp.ones(64),
+                          fix_gamma=False, axis=-1, pool_kernel=(3, 3),
+                          pool_stride=(2, 2), pool_pad=(1, 1),
+                          pool_layout="NHWC")
+        z = conv(jnp.maximum(a, 0), w1.astype(jnp.bfloat16), None,
+                 kernel=(3, 3), pad=(1, 1), num_filter=64, no_bias=True,
+                 layout="NHWC")
+        return jnp.sum((z * cot).astype(jnp.float32))
+
+    mesh = Mesh(np.array(four_chips[:chips]), ("dp",))
+    whole = NamedSharding(mesh, PartitionSpec())
+    rows = NamedSharding(mesh, PartitionSpec("dp"))
+    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+             for shape, dtype, sharding in (
+                 ((64, 7, 7, 3), jnp.float32, whole),
+                 ((64,), jnp.float32, whole), ((64,), jnp.float32, whole),
+                 ((64, 3, 3, 64), jnp.float32, whole),
+                 ((batch, 224, 224, 3), jnp.float32, rows),
+                 ((batch, 56, 56, 64), jnp.bfloat16, rows))]
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), *specs)
+    entry = text[text.index("ENTRY"):]
+    assert entry.count('custom_call_target="tpu_custom_call"') == 2
+    assert "f32[64,1]" in entry, "the kernel takes no sign"
+    assert ("all-reduce" in text) == (chips > 1)
+    n = batch // chips
+    full = re.compile(r"\[(%d,112,112,64|112,112,64,%d)\]" % (n, n))
+    touching = [l for l in entry.splitlines()
+                if full.search(l.split(", metadata=")[0])]
+    assert touching
+    for line in touching:
+        assert " copy(" not in line and " transpose(" not in line, line
+        if " fusion(" in line:
+            assert "kind=kLoop" not in line and "kind=kInput" not in line, \
+                "a full-size pass over the stem map: " + line[:200]
 
 
 def test_rtc_does_not_choose_interpret_mode(monkeypatch):

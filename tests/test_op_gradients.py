@@ -127,6 +127,16 @@ SPEC = {
                       # eps=1e-2: with ~1e-5 float32 roundoff on the summed
                       # output, central differences at 1e-3 are noise-bound
                       tol=dict(eps=1e-2, rtol=3e-2, atol=5e-3)),
+    # gamma of both signs: the pool is of sgn(scale) * data; values spread so
+    # that no two taps of a window tie
+    "_MaxPoolBatchNorm": dict(
+        inputs=[spread(2, 3, 4, 4), const(np.array([1.2, -0.8, 0.6], "float32")),
+                u(3)],
+        fixed={3: const(np.zeros(3, "float32")),
+               4: const(np.ones(3, "float32"))},
+        attrs={"fix_gamma": False, "pool_kernel": (2, 2),
+               "pool_stride": (2, 2)},
+        tol=dict(eps=1e-2, rtol=3e-2, atol=5e-3)),
     "LayerNorm": dict(inputs=[u(2, 3, 4), u(4, low=0.5, high=1.5), u(4)],
                       tol=dict(rtol=2e-2, atol=2e-3)),
     "InstanceNorm": dict(inputs=[u(2, 3, 4, 4), u(3, low=0.5, high=1.5), u(3)],

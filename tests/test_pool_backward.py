@@ -168,7 +168,7 @@ def test_index_is_the_first_maximal_tap_in_window_order(one_device):
     x[:, 1, 2, :] = 3.0      # padded (2, 3)
     pool = ops_nn._max_pool_taps("NHWC", (3, 3), (2, 2),
                                  ((0, 0), (1, 1), (1, 1), (0, 0)))
-    out, (idx, _) = pool.fwd(jnp.asarray(x))
+    out, (idx, *_) = pool.fwd(jnp.asarray(x))
     assert idx.dtype == jnp.int8 and idx.shape == out.shape == (128, 2, 2, 32)
     # window (0,0): rows/cols 0 are padding -> tap (1,1); (0,1) holds the peak
     # as tap (2,1); (1,0): column 0 is padding -> tap (0,1); (1,1): the peak

@@ -240,32 +240,45 @@ def test_three_momentum_steps_train_the_7x7_stem_not_a_rehomed_one(
 
 def test_default_passes_lower_to_the_cells_step(rng):
     """DataParallelTrainer() with no ``passes`` and with the benchmark
-    cells' explicit list build the same program."""
+    cells' explicit list build the same program: the one whose stem is
+    lowered through space-to-depth and whose max pool reads the stem
+    convolution's output itself (PR 30), which the passes off do not build."""
     x = rng.uniform(-1, 1, (8, 32, 32, 3)).astype("float32")
     y = rng.randint(0, 4, (8,)).astype("float32")
     digests = []
-    for kw in ({}, {"passes": ["fold", "layout", "fusion"]}):
+    for kw in ({}, {"passes": ["fold", "layout", "fusion"]},
+               {"passes": False}):
         net = _tiny_nhwc_resnet("s2dflt_")
         tr = parallel.DataParallelTrainer(
             net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
             {"learning_rate": 0.05, "momentum": 0.9}, **kw)
+        before = catalog.POOL_SUNK.value(), _lowered_count()
         digests.append(tr._lowered_digest(tr.lower(x, y)))
-    assert digests[0] == digests[1]
+        assert _lowered_count() > before[1]
+        assert catalog.POOL_SUNK.value() - before[0] == \
+            (kw.get("passes") is not False)
+    assert digests[0] == digests[1] != digests[2]
 
 
 _ZOO = {
-    # id: (zoo name, net kwargs, trainer passes, image, engages)
-    "resnet18_v1-nhwc": ("resnet18_v1", {"layout": "NHWC"}, None, 32, True),
-    "resnet18_v2-nhwc": ("resnet18_v2", {"layout": "NHWC"}, None, 32, True),
-    "resnet18_v1-7x7s2": ("resnet18_v1", {}, None, 32, True),
-    "squeezenet1_0-7x7s2": ("squeezenet1_0", {}, None, 64, True),
-    "mobilenet1_0-3x3s2": ("mobilenet1_0", {}, None, 32, True),
-    "densenet121-7x7s2": ("densenet121", {}, None, 224, True),
-    "resnet18_v1-nchw-nopasses": ("resnet18_v1", {}, False, 32, False),
+    # id: (zoo name, net kwargs, trainer passes, image, engages, pool:
+    #      (stems whose max pool sank in front of BatchNorm, ReLUs that a max
+    #       pool passed))
+    "resnet18_v1-nhwc": ("resnet18_v1", {"layout": "NHWC"}, None, 32, True,
+                         (1, 1)),
+    "resnet18_v2-nhwc": ("resnet18_v2", {"layout": "NHWC"}, None, 32, True,
+                         (1, 1)),
+    "resnet18_v1-7x7s2": ("resnet18_v1", {}, None, 32, True, (1, 1)),
+    # conv -> relu -> max pool once; its other two pools read a concat
+    "squeezenet1_0-7x7s2": ("squeezenet1_0", {}, None, 64, True, (0, 1)),
+    "mobilenet1_0-3x3s2": ("mobilenet1_0", {}, None, 32, True, (0, 0)),
+    "densenet121-7x7s2": ("densenet121", {}, None, 224, True, (1, 1)),
+    "resnet18_v1-nchw-nopasses": ("resnet18_v1", {}, False, 32, False,
+                                  (0, 0)),
     "resnet18_v1-thumbnail-3x3s1": ("resnet18_v1", {"thumbnail": True}, None,
-                                    32, False),
-    "alexnet-11x11s4": ("alexnet", {}, None, 64, False),
-    "vgg11-3x3s1": ("vgg11", {}, None, 32, False),
+                                    32, False, (0, 0)),
+    "alexnet-11x11s4": ("alexnet", {}, None, 64, False, (0, 3)),
+    "vgg11-3x3s1": ("vgg11", {}, None, 32, False, (0, 5)),
 }
 
 
@@ -274,9 +287,11 @@ def test_zoo_stems_take_the_ops_lowering_or_bypass_it(case):
     """Where the stem's lowering is decided, over the zoo: in the op, from
     the shapes it is handed. A net built channel-last hands its stem over as
     it is; one built NCHW does once the default passes have made it
-    channel-last, and never under ``passes=False``. One trace of the train
+    channel-last, and never under ``passes=False``. Likewise the stem's max
+    pool: the fusion pass moves it wherever a BatchNorm or a ReLU with no
+    other reader feeds it, whatever the net is called. One trace of the train
     step each, nothing compiled."""
-    name, kwargs, passes, image, engages = _ZOO[case]
+    name, kwargs, passes, image, engages, pool = _ZOO[case]
     mx.random.seed(0)
     net = getattr(vision, name)(classes=10, **kwargs)
     net.initialize(mx.init.Xavier())
@@ -294,8 +309,17 @@ def test_zoo_stems_take_the_ops_lowering_or_bypass_it(case):
     tr = parallel.DataParallelTrainer(
         net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
         {"learning_rate": 0.1}, passes=passes)
-    before = _lowered_count()
+    before, sunk_before = _lowered_count(), catalog.POOL_SUNK.value()
     text = tr.lower(x, np.zeros((8,), "float32")).as_text()
     assert (_lowered_count() > before) is engages
+    # the max pools the fusion pass moved (PR 30): in front of a BatchNorm's
+    # apply (the op's counter, once a trace) and past a ReLU (the graph)
+    graph = tr._pass_result.symbol.topo_nodes() if passes is None else []
+    passed = [n for n in graph if n.op in ("Activation", "relu")
+              and n.inputs[0][0].op in ("Pooling", "_MaxPoolBatchNorm")]
+    assert (catalog.POOL_SUNK.value() - sunk_before, len(passed)) == pool
+    if passes is None and kwargs.get("layout") == "NHWC":
+        # nothing else for the pass to do in a net built channel-last
+        assert tr.passes_provenance()["rewrites"]["fusion"] == sum(pool)
     # the lowering's mark in the program: a stride-(1,2) convolution
     assert ("stride = [1, 2]" in text) is engages

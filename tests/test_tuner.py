@@ -477,6 +477,60 @@ def test_best_config_builds_bitwise_identical_trainer(tmp_path,
     assert t._remat_mode == best.remat and t._donate == best.donate
 
 
+def _stem_net(layout):
+    """conv -> BatchNorm -> relu -> max pool -> dense: a stem whose pool the
+    ``fusion`` pass sinks (PR 30). Fixed names: they are keys of the program."""
+    mx.random.seed(29)
+    ax = -1 if layout == "NHWC" else 1
+    net = nn.HybridSequential(prefix="tstem_")
+    net.add(nn.Conv2D(8, 3, 1, 1, use_bias=False, layout=layout,
+                      in_channels=3, prefix="tstem_c_"),
+            nn.BatchNorm(axis=ax, in_channels=8, prefix="tstem_bn_"),
+            nn.Activation("relu"),
+            nn.MaxPool2D(3, 2, 1, layout=layout),
+            nn.GlobalAvgPool2D(layout=layout),
+            nn.Dense(4, in_units=8, prefix="tstem_fc_"))
+    net.initialize(mx.init.Xavier())
+    return net, gluon.loss.SoftmaxCrossEntropyLoss()
+
+
+def test_both_routes_of_tune_build_the_default_trainers_step(tmp_path,
+                                                             monkeypatch):
+    """What ``tune()`` itself builds, on either route and for the NCHW
+    baseline too: the step of a default trainer on the net in that layout,
+    stem's pool sunk (``mxtpu_pool_sunk_total`` rises once a trial). The
+    candidates differ in layout alone, and the two routes' rows are
+    interchangeable because their fingerprints are equal."""
+    from mxnet_tpu.parallel import DataParallelTrainer
+    _peaks(monkeypatch)
+    cands = [Candidate(8, "NCHW"), Candidate(8, "NHWC")]
+
+    def data(cand):
+        rng = np.random.RandomState(0)
+        return (rng.randn(*cand.data_shape(8)).astype("float32"),
+                rng.randint(0, 4, (cand.batch,)).astype("float32"))
+
+    prints = {}
+    for route, via_passes in (("flags", False), ("passes", True)):
+        def build(cand, _flags=not via_passes):
+            return _stem_net(cand.layout if _flags else "NCHW")
+        before = catalog.POOL_SUNK.value()
+        res = tuner.tune(build, data, candidates=cands, measure=False,
+                         ledger=_ledger(tmp_path / route), model=route,
+                         via_passes=via_passes)
+        assert catalog.POOL_SUNK.value() - before == len(cands)
+        prints[route] = {t.candidate.layout: t.fingerprint
+                         for t in res.trials}
+    assert prints["flags"] == prints["passes"]
+    assert prints["flags"]["NCHW"] != prints["flags"]["NHWC"]
+    # the NHWC candidate is the default trainer on the hand-flagged net
+    net, loss_fn = _stem_net("NHWC")
+    by_hand = DataParallelTrainer(net, loss_fn, "sgd", {})
+    x, y = data(cands[1])
+    assert by_hand._lowered_digest(by_hand.lower(x, y)) == \
+        prints["flags"]["NHWC"]
+
+
 # ------------------------------------------------------- cache utilities
 def test_best_cached_filters_by_signature(tmp_path, monkeypatch):
     led = _ledger(tmp_path)

@@ -129,6 +129,122 @@ def test_max_pool_kernels_equal_reduce_windows_gradient_on_the_chip(
     np.testing.assert_allclose(g_k, g_x, rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", ["3x3s2p1", "3x3s2p0", "2x2s2"])
+def test_sunk_pool_equals_the_declared_stem_on_the_chip(name, dtype):
+    """``BatchNorm -> relu -> max pool`` as declared and as the fusion pass
+    rewrites it (``_MaxPoolBatchNorm -> relu``, PR 30), both on the TPU from
+    the same data, scales of both signs and one at exactly 0, at a shape the
+    kernels take (so the sign is multiplied in VMEM): the same values to the
+    bit, the same statistics, and in float32 the same gradients."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.observability import catalog
+    from mxnet_tpu.ops import get_op
+    bn, pool, sunk = (get_op(n).fn for n in ("BatchNorm", "Pooling",
+                                             "_MaxPoolBatchNorm"))
+    window = _POOL_WINDOWS[name]
+    rs = np.random.RandomState(5)
+    x = jnp.asarray(rs.randn(128, 12, 12, 32), dtype)
+    gamma = jnp.asarray(rs.uniform(0.5, 1.5, 32)
+                        * np.where(np.arange(32) % 2, -1.0, 1.0)
+                        * (np.arange(32) != 6), jnp.float32)
+    beta = jnp.asarray(rs.uniform(-0.5, 0.5, 32), jnp.float32)
+    aux = (jnp.zeros(32, jnp.float32), jnp.ones(32, jnp.float32))
+    attrs = dict(eps=1e-5, fix_gamma=False, axis=-1)
+
+    def declared(x, gamma, beta):
+        a, mean, var = bn(x, gamma, beta, *aux, **attrs)
+        return pool(jnp.maximum(a, 0), pool_type="max", layout="NHWC",
+                    **window), mean, var
+
+    def rewritten(x, gamma, beta):
+        a, mean, var = sunk(x, gamma, beta, *aux, pool_layout="NHWC", **attrs,
+                            **{"pool_" + k: v for k, v in window.items()})
+        return jnp.maximum(a, 0), mean, var
+
+    before = (catalog.POOL_SUNK.value(), catalog.POOL_BWD_LOWERED.value())
+    with jax.sharding.use_abstract_mesh(
+            jax.sharding.AbstractMesh((1,), ("dp",))):
+        (want, mean_w, var_w), vjp_w = jax.vjp(declared, x, gamma, beta)
+        (got, mean, var), vjp = jax.vjp(rewritten, x, gamma, beta)
+        cot = (jnp.asarray(rs.randn(*want.shape), dtype),
+               jnp.zeros_like(mean), jnp.zeros_like(var))
+        g_want, g_got = vjp_w(cot), vjp(cot)
+    assert (catalog.POOL_SUNK.value() - before[0],
+            catalog.POOL_BWD_LOWERED.value() - before[1]) == (1, 2)
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+    np.testing.assert_array_equal(np.asarray(mean), np.asarray(mean_w))
+    np.testing.assert_array_equal(np.asarray(var), np.asarray(var_w))
+    if dtype == "float32":
+        for a, e, what in zip(g_got, g_want, ("data", "gamma", "beta")):
+            a, e = np.asarray(a), np.asarray(e)
+            if what == "gamma":     # at a scale of 0 every tap ties
+                a, e = np.delete(a, 6), np.delete(e, 6)
+            np.testing.assert_allclose(a, e, rtol=1e-5,
+                                       atol=1e-5 * np.abs(e).max(),
+                                       err_msg=what)
+
+
+def test_sunk_stem_over_a_dp_mesh_of_the_chips():
+    """conv -> BatchNorm (scales of both signs) -> relu -> max pool through
+    ``DataParallelTrainer`` on the host's four chips, 128 rows a chip: the
+    pool inside ``_MaxPoolBatchNorm`` runs its kernels per shard of the
+    batch, each chip with the whole column of signs. The same losses,
+    trained weights and running statistics as on one chip (float32: taps do
+    not tie; the sums over the batch are added in another order)."""
+    import jax
+    from jax.sharding import Mesh
+    from mxnet_tpu import gluon, nd, parallel
+    from mxnet_tpu.observability import catalog
+    chips = 4
+    if jax.device_count() < chips:
+        pytest.skip("needs the four chips of one host (chiprun --chips 4)")
+    batch, c = 128 * chips, 32
+    rs = np.random.RandomState(11)
+    x = rs.uniform(-1, 1, (batch, 16, 16, 3)).astype("float32")
+    y = rs.randint(0, 4, (batch,)).astype("float32")
+    gamma = (rs.uniform(0.5, 1.5, c)
+             * np.where(np.arange(c) % 2, -1.0, 1.0)).astype("float32")
+    ends = {}
+    for n in (1, chips):
+        mx.random.seed(5)
+        net = gluon.nn.HybridSequential(prefix="sinkdp_")
+        net.add(gluon.nn.Conv2D(c, 3, padding=1, use_bias=False,
+                                layout="NHWC", in_channels=3,
+                                prefix="sinkdp_c_"),
+                gluon.nn.BatchNorm(axis=-1, in_channels=c,
+                                   prefix="sinkdp_bn_"),
+                gluon.nn.Activation("relu"),
+                gluon.nn.MaxPool2D(3, 2, 1, layout="NHWC"),
+                gluon.nn.Dense(4, prefix="sinkdp_fc_"))
+        net.initialize(mx.init.Xavier())
+        net[1].gamma.set_data(nd.array(gamma))
+        tr = parallel.DataParallelTrainer(
+            net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+            {"learning_rate": 0.002, "momentum": 0.9},
+            mesh=Mesh(np.array(jax.devices()[:n]), ("dp",)))
+        before = (catalog.POOL_SUNK.value(), catalog.POOL_BWD_LOWERED.value())
+        losses = [float(tr.step(x, y)) for _ in range(3)]
+        assert (catalog.POOL_SUNK.value() - before[0],
+                catalog.POOL_BWD_LOWERED.value() - before[1]) == (1, 1)
+        text = tr.lower(x, y).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        # each chip's kernel on its own rows, with the column of signs
+        assert "[8,8,%d,%d]" % (c, batch // n) in text \
+            and "f32[%d,1]" % c in text
+        tr.sync_to_net()
+        ends[n] = (losses, {k: p.data().asnumpy()
+                            for k, p in net.collect_params().items()})
+    print("losses", {n: e[0] for n, e in ends.items()})
+    np.testing.assert_allclose(ends[chips][0], ends[1][0], rtol=1e-3)
+    for k, want in ends[1][1].items():
+        np.testing.assert_allclose(ends[chips][1][k], want, rtol=1e-2,
+                                   atol=1e-3 * np.abs(want).max(),
+                                   err_msg=k)
+
+
 def test_softmax_ce_parity():
     net = sym.SoftmaxOutput(
         sym.FullyConnected(sym.Variable("data"), num_hidden=10),
