@@ -15,6 +15,7 @@ sequence of async device dispatches just like forward.
 from __future__ import annotations
 
 import threading
+import weakref
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -514,7 +515,11 @@ def _backward_create_graph(heads, head_grads, wrt, retain_graph=True):
     return out
 
 
-_all_leaves: Dict[int, Any] = {}
+# weak: the registry finds a leaf by id for as long as someone holds it; it
+# must not be the one that holds it (a net's parameters and their gradient
+# buffers, gigabytes on the device, outlived the net; PERF.md, PR 31)
+_all_leaves: "weakref.WeakValueDictionary[int, Any]" = \
+    weakref.WeakValueDictionary()
 
 
 def _register_leaf(arr):

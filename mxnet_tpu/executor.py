@@ -156,9 +156,34 @@ _AUX_UPDATE_RULES: Dict[str, Callable] = {
     "BatchNorm": _bn_aux_update, "_MaxPoolBatchNorm": _bn_aux_update}
 
 
+def _mirror_segments(nodes):
+    """Runs of op nodes, consecutive in topological order (variables apart),
+    that carry the same ``force_mirroring`` attribute: [[node index, ...]].
+    ``mx.AttrScope(force_mirroring=<name>)`` around a part of a graph is how
+    a block asks for that part to be recomputed in the backward pass; nodes
+    of one scope that other nodes separate make a segment per run."""
+    runs, name = [], None
+    for i, node in enumerate(nodes):
+        if node.is_var:
+            continue
+        mine = node._attr_dict.get("force_mirroring")
+        mine = mine if mine not in (None, "", "0", "False", "false") else None
+        if mine is not None and mine == name:
+            runs[-1].append(i)
+        elif mine is not None:
+            runs.append([i])
+        name = mine
+    return runs
+
+
 class _GraphLowering:
     """Lowers a Symbol DAG to a pure jax function
-    ``fn(inputs: dict, rng) -> (outputs: list, aux_updates: dict)``."""
+    ``fn(inputs: dict, rng) -> (outputs: list, aux_updates: dict)``.
+
+    Nodes traced under one ``AttrScope(force_mirroring=<name>)`` lower as ONE
+    function under ``jax.checkpoint`` whose inputs are the values that enter
+    the segment: the backward pass keeps those and recomputes the rest. A
+    graph without the attribute lowers node by node, as it always has."""
 
     def __init__(self, symbol):
         self.symbol = symbol
@@ -166,20 +191,51 @@ class _GraphLowering:
         self.var_names = [n.name for n in self.nodes if n.is_var]
         self.has_rng = any(
             n.op is not None and get_op(n.op).needs_rng for n in self.nodes)
+        self.segments = _mirror_segments(self.nodes)
+
+    def _plan(self):
+        """The op nodes in order, a segment standing where its first node
+        stood: [node index | (node indices, entries read from outside,
+        entries read outside)]; an entry is (id(node), output index)."""
+        nodes = self.nodes
+        seg_of = {id(nodes[i]): k for k, run in enumerate(self.segments)
+                  for i in run}
+        read_outside = {(id(n), idx) for (n, idx) in self.symbol._outputs}
+        for node in nodes:
+            for (src, idx) in node.inputs:
+                if seg_of.get(id(src), -1) != seg_of.get(id(node), -2):
+                    read_outside.add((id(src), idx))
+        plan = []
+        for i, node in enumerate(nodes):
+            if node.is_var:
+                continue
+            k = seg_of.get(id(node))
+            if k is None:
+                plan.append(i)
+            elif self.segments[k][0] == i:
+                run = self.segments[k]
+                ext = list(dict.fromkeys(
+                    (id(src), idx) for j in run
+                    for (src, idx) in nodes[j].inputs
+                    if seg_of.get(id(src)) != k))
+                outs = [(id(nodes[j]), o) for j in run
+                        for o in range(nodes[j].num_outputs)
+                        if (id(nodes[j]), o) in read_outside]
+                plan.append((run, ext, outs))
+        return plan
 
     def lower(self, is_train: bool) -> Callable:
         nodes = self.nodes
         out_entries = self.symbol._outputs
+        plan = self._plan()
 
-        def fn(inputs: Dict[str, Any], rng):
-            vals: Dict[int, Tuple] = {}
-            aux_updates: Dict[str, Any] = {}
-            for i, node in enumerate(nodes):
-                if node.is_var:
-                    vals[id(node)] = (inputs[node.name],)
-                    continue
+        def run(idxs, vals, rng, aux_updates):
+            """The nodes ``idxs`` in order, reading and writing ``vals``
+            ({(id(node), output index): value})."""
+            for i in idxs:
+                node = nodes[i]
                 opdef = get_op(node.op)
-                in_arrays = [vals[id(src)][idx] for (src, idx) in node.inputs]
+                in_arrays = [vals[(id(src), idx)] for (src, idx) in node.inputs]
                 attrs = dict(node.attrs)
                 accepts_train, accepts_rng = _op_signature_flags(opdef)
                 if accepts_train and "is_train" not in attrs:
@@ -188,15 +244,39 @@ class _GraphLowering:
                     attrs["rng"] = jax.random.fold_in(rng, i)
                 out = opdef.fn(*in_arrays, **attrs)
                 out = out if isinstance(out, tuple) else (out,)
-                vals[id(node)] = out
+                vals.update(((id(node), o), v) for o, v in enumerate(out))
                 if is_train and node.op in _AUX_UPDATE_RULES:
                     upd = _AUX_UPDATE_RULES[node.op](attrs, in_arrays, out)
                     for in_idx, new_val in upd.items():
                         src, _ = node.inputs[in_idx]
                         if src.is_var:
                             aux_updates[src.name] = new_val
-            outs = [vals[id(node)][idx] for (node, idx) in out_entries]
-            return outs, aux_updates
+
+        def segment(idxs, ext, outs):
+            def body(ext_vals, rng):
+                local, aux_updates = dict(zip(ext, ext_vals)), {}
+                run(idxs, local, rng, aux_updates)
+                return [local[k] for k in outs], aux_updates
+            return jax.checkpoint(body)
+
+        def fn(inputs: Dict[str, Any], rng):
+            vals: Dict[Tuple[int, int], Any] = {
+                (id(n), 0): inputs[n.name] for n in nodes if n.is_var}
+            aux_updates: Dict[str, Any] = {}
+            for unit in plan:
+                if isinstance(unit, int):
+                    run((unit,), vals, rng, aux_updates)
+                    continue
+                idxs, ext, outs = unit
+                got, upd = segment(idxs, ext, outs)([vals[k] for k in ext], rng)
+                vals.update(zip(outs, got))
+                aux_updates.update(upd)
+            if self.segments:
+                from .observability import catalog, metrics
+                if metrics.enabled():
+                    catalog.REMAT_SEGMENTS.inc(len(self.segments))
+            return [vals[(id(node), idx)] for (node, idx) in out_entries], \
+                aux_updates
 
         return fn
 

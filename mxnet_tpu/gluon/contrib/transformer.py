@@ -8,8 +8,18 @@ whose sequence dimension shards over a mesh via ``parallel.ring_attention``
 / ``parallel.ulysses`` for contexts longer than one chip's HBM.
 
 Layers:
-- ``MultiHeadAttention`` — fused qkv projection, flash attention
-  (``F._contrib_flash_attention``), output projection.
+- ``MultiHeadAttention`` — fused qkv projection, rotary positions on request
+  (``rotary_theta=``), flash attention (``F._contrib_flash_attention``),
+  output projection.
+- ``RMSNorm``, ``GatedFFN`` — the root-mean-square norm (statistic in
+  float32) and the gated-SiLU feed-forward of current decoder LMs.
+- ``SandwichDecoderCell`` — a causal decoder layer with a norm on each
+  sub-layer's input AND output; every call of it asks the lowering to
+  recompute it in the backward pass (``AttrScope(force_mirroring=)``).
+- ``LoopedDecoderLM`` / ``looped_decoder_lm`` — embedding, ONE stack of such
+  layers run ``loops`` times over the same parameters, a final norm, an exit
+  gate and an untied head; it hands ``gluon.loss.ExpectedExitCELoss`` every
+  pass's normed state, every gate and the head's weight.
 - ``TransformerEncoderCell`` / ``TransformerDecoderCell`` (causal) —
   pre-norm residual blocks (pre-norm trains stably at depth without warmup
   gymnastics; the post-norm original is available via ``pre_norm=False``).
@@ -20,27 +30,31 @@ Layers:
 """
 from __future__ import annotations
 
-import math
-
 import numpy as np
 
+from ...attribute import AttrScope
 from ...base import MXNetError
 from ..block import Block, HybridBlock
 from ..nn import Dense, Dropout, Embedding, LayerNorm, HybridSequential
 
 __all__ = ["MultiHeadAttention", "TransformerEncoderCell",
            "TransformerDecoderCell", "TransformerEncoder",
-           "SinusoidalPositionalEmbedding", "TransformerLM"]
+           "SinusoidalPositionalEmbedding", "TransformerLM", "RMSNorm",
+           "GatedFFN", "SandwichDecoderCell", "LoopedDecoderLM",
+           "looped_decoder_lm"]
 
 
 class MultiHeadAttention(HybridBlock):
     """Self-attention with the flash kernel on the hot path.
 
     Input/output layout (B, T, C); internally (B, H, T, D) for the kernel.
+    The fused weight's rows are the queries' heads, then the keys', then the
+    values'. ``rotary_theta`` turns queries and keys by their position in
+    the row (``F._contrib_rotary_embedding``, half-rotation form).
     """
 
     def __init__(self, units, num_heads, dropout=0.0, causal=False,
-                 use_bias=True, **kw):
+                 use_bias=True, rotary_theta=None, in_units=0, **kw):
         super().__init__(**kw)
         if units % num_heads:
             raise MXNetError(f"units {units} not divisible by heads "
@@ -48,10 +62,12 @@ class MultiHeadAttention(HybridBlock):
         self._units = units
         self._heads = num_heads
         self._causal = causal
+        self._theta = rotary_theta
         with self.name_scope():
             self.qkv = Dense(3 * units, flatten=False, use_bias=use_bias,
-                             prefix="qkv_")
+                             in_units=in_units, prefix="qkv_")
             self.proj = Dense(units, flatten=False, use_bias=use_bias,
+                              in_units=units if in_units else 0,
                               prefix="proj_")
             self.drop = Dropout(dropout)
 
@@ -63,6 +79,9 @@ class MultiHeadAttention(HybridBlock):
         q = F.slice_axis(qkv, axis=1, begin=0, end=h)
         k = F.slice_axis(qkv, axis=1, begin=h, end=2 * h)
         v = F.slice_axis(qkv, axis=1, begin=2 * h, end=3 * h)
+        if self._theta is not None:
+            q = F.contrib_rotary_embedding(q, theta=self._theta)
+            k = F.contrib_rotary_embedding(k, theta=self._theta)
         out = F.contrib_flash_attention(q, k, v, causal=self._causal)
         out = F.transpose(out, axes=(0, 2, 1, 3))           # (B, T, H, D)
         out = F.reshape(out, shape=(0, 0, -1))              # (B, T, C)
@@ -183,3 +202,136 @@ class TransformerLM(Block):
                            transpose_b=True).reshape(
                                (x.shape[0], x.shape[1], -1))
         return self.head(x)
+
+
+class RMSNorm(HybridBlock):
+    """``x / sqrt(mean(x**2) + epsilon) * gamma`` over ``axis``; the
+    statistic is taken in float32 whatever the compute type (``F.RMSNorm``)."""
+
+    def __init__(self, axis=-1, epsilon=1e-6, gamma_initializer="ones",
+                 in_channels=0, **kw):
+        super().__init__(**kw)
+        self._axis = axis
+        self._eps = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                         init=gamma_initializer,
+                                         allow_deferred_init=True)
+
+    def hybrid_forward(self, F, x, gamma):
+        return F.RMSNorm(x, gamma, axis=self._axis, eps=self._eps)
+
+
+class GatedFFN(HybridBlock):
+    """``down(silu(gate(x)) * up(x))``, no bias."""
+
+    def __init__(self, units, hidden_size, **kw):
+        super().__init__(**kw)
+        with self.name_scope():
+            self.gate = Dense(hidden_size, flatten=False, use_bias=False,
+                              in_units=units, prefix="gate_")
+            self.up = Dense(hidden_size, flatten=False, use_bias=False,
+                            in_units=units, prefix="up_")
+            self.down = Dense(units, flatten=False, use_bias=False,
+                              in_units=hidden_size, prefix="down_")
+
+    def hybrid_forward(self, F, x):
+        a = self.gate(x)
+        return self.down(a * F.sigmoid(a) * self.up(x))
+
+
+class SandwichDecoderCell(HybridBlock):
+    """Causal decoder layer with rotary attention, a gated-SiLU FFN and an
+    RMSNorm on each sub-layer's input and output::
+
+        x = x + norm2(attn(norm1(x)));  x = x + norm4(ffn(norm3(x)))
+
+    Every CALL of the cell is traced under its own
+    ``AttrScope(force_mirroring=<name>)``: the lowering keeps the call's
+    input and recomputes the rest in the backward pass
+    (docs/architecture.md). A block that calls one cell several times (a
+    looped stack) gets one segment a call."""
+
+    def __init__(self, units, hidden_size, num_heads, rotary_theta=10000.0,
+                 epsilon=1e-6, **kw):
+        super().__init__(**kw)
+        self._calls = 0
+        with self.name_scope():
+            self.norm1 = RMSNorm(epsilon=epsilon, in_channels=units,
+                                 prefix="norm1_")
+            self.attn = MultiHeadAttention(
+                units, num_heads, causal=True, use_bias=False,
+                rotary_theta=rotary_theta, in_units=units, prefix="attn_")
+            self.norm2 = RMSNorm(epsilon=epsilon, in_channels=units,
+                                 prefix="norm2_")
+            self.norm3 = RMSNorm(epsilon=epsilon, in_channels=units,
+                                 prefix="norm3_")
+            self.ffn = GatedFFN(units, hidden_size, prefix="ffn_")
+            self.norm4 = RMSNorm(epsilon=epsilon, in_channels=units,
+                                 prefix="norm4_")
+
+    def hybrid_forward(self, F, x):
+        self._calls += 1
+        with AttrScope(force_mirroring=f"{self.prefix}call{self._calls}"):
+            x = x + self.norm2(self.attn(self.norm1(x)))
+            return x + self.norm4(self.ffn(self.norm3(x)))
+
+
+class LoopedDecoderLM(HybridBlock):
+    """A decoder LM whose one stack of ``num_layers`` layers runs ``loops``
+    times over the same parameters. After each pass the final norm gives
+    that pass's exit state, which is also the next pass's input; a gate
+    ``sigmoid(state . w + b)`` per token says how likely the model stops
+    there.
+
+    ``forward(ids (B, S) int)`` -> ``(states (loops, B, S, units), gates
+    (loops, B, S), head_weight (vocab, units))``: what
+    ``gluon.loss.ExpectedExitCELoss`` takes before the label, so that the
+    head's product lies inside the loss's recomputed segment and one exit's
+    logits live at a time. ``exit_logits(ids)`` gives the logits themselves.
+    """
+
+    def __init__(self, vocab_size, units, hidden_size, num_layers, num_heads,
+                 loops=1, rotary_theta=10000.0, epsilon=1e-6, **kw):
+        super().__init__(**kw)
+        self._loops = loops
+        with self.name_scope():
+            self.embed = Embedding(vocab_size, units, prefix="embed_")
+            self.layers = []
+            for i in range(num_layers):
+                cell = SandwichDecoderCell(
+                    units, hidden_size, num_heads, rotary_theta=rotary_theta,
+                    epsilon=epsilon, prefix=f"layer{i}_")
+                self.register_child(cell, f"layer{i}")
+                self.layers.append(cell)
+            self.norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                prefix="norm_")
+            self.gate = Dense(1, flatten=False, in_units=units, prefix="gate_")
+            self.head_weight = self.params.get(
+                "head_weight", shape=(vocab_size, units))
+
+    def hybrid_forward(self, F, ids, head_weight):
+        h = self.embed(ids)
+        states, gates = [], []
+        for _ in range(self._loops):
+            for cell in self.layers:
+                h = cell(h)
+            h = self.norm(h)
+            states.append(h)
+            gates.append(F.sigmoid(F.squeeze(self.gate(h), axis=2)))
+        return (F.stack(*states, axis=0), F.stack(*gates, axis=0),
+                head_weight)
+
+    def exit_logits(self, ids):
+        """(loops, B, S, vocab): every exit's logits, op by op."""
+        from ... import nd
+        states, _gates, head_weight = self(ids)
+        return nd.dot(states, head_weight, transpose_b=True)
+
+
+def looped_decoder_lm(vocab_size, units, hidden_size, num_layers, num_heads,
+                      loops=1, rotary_theta=10000.0, epsilon=1e-6, **kw):
+    """A ``LoopedDecoderLM`` from its sizes (a configuration's builder)."""
+    return LoopedDecoderLM(vocab_size, units, hidden_size, num_layers,
+                           num_heads, loops=loops, rotary_theta=rotary_theta,
+                           epsilon=epsilon, **kw)

@@ -3,12 +3,14 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..attribute import AttrScope
 from .block import HybridBlock
 
 __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
            "SigmoidBCELoss", "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
            "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss", "SquaredHingeLoss",
-           "LogisticLoss", "TripletLoss", "CosineEmbeddingLoss"]
+           "LogisticLoss", "TripletLoss", "CosineEmbeddingLoss",
+           "ExpectedExitCELoss"]
 
 
 def _apply_weighting(F, loss, weight=None, sample_weight=None):
@@ -220,3 +222,51 @@ class CosineEmbeddingLoss(Loss):
         label = F.reshape_like(label, cos)
         loss = F.where(label == 1, 1 - cos, F.relu(cos - self._margin))
         return _apply_weighting(F, loss, self._weight, sample_weight)
+
+
+class ExpectedExitCELoss(Loss):
+    """Cross-entropy over the ``exits`` exits of a looped model, weighted by
+    the distribution its exit gates define, less ``beta`` times that
+    distribution's entropy (arXiv:2510.25741, section 3)::
+
+        p_t = gate_t * prod_{j<t}(1 - gate_j)  (t < exits),  p_last = the rest
+        loss = mean over tokens of  sum_t p_t * CE(state_t . head^T, label)
+                                    - beta * H(p)
+
+    Takes what ``gluon.contrib.transformer.LoopedDecoderLM`` returns: states
+    (exits, B, S, units), gates (exits, B, S), the head's weight (vocab,
+    units), then the label (B, S). The head's product and the cross-entropy
+    of ONE exit at a time are traced under their own
+    ``AttrScope(force_mirroring=)``, so the lowering keeps one
+    exit's logits at a time and recomputes them in the backward pass. Gates,
+    distribution, log-sum-exp and entropy are taken in float32."""
+
+    def __init__(self, exits, beta=0.1, weight=None, batch_axis=0, **kwargs):
+        super().__init__(weight, batch_axis, **kwargs)
+        self._exits = int(exits)
+        self._beta = beta
+        self._calls = 0
+
+    def hybrid_forward(self, F, states, gates, head_weight, label,
+                       sample_weight=None):
+        pick = lambda a, t: F.squeeze(  # noqa: E731
+            F.slice_axis(a, axis=0, begin=t, end=t + 1), axis=0)
+        lam = F.cast(gates, dtype="float32")
+        probs, rest = [], None
+        for t in range(self._exits - 1):
+            g = pick(lam, t)
+            probs.append(g if rest is None else g * rest)
+            rest = (1.0 - g) if rest is None else rest * (1.0 - g)
+        probs.append(F.ones_like(pick(lam, 0)) if rest is None else rest)
+        loss = None
+        for t, p in enumerate(probs):
+            self._calls += 1
+            with AttrScope(force_mirroring=f"{self.prefix}exit{self._calls}"):
+                logits = F.dot(pick(states, t), head_weight, transpose_b=True)
+                ce = F.softmax_cross_entropy(logits, label, per_row=True)
+            # - beta * H(p) = beta * sum p log p; a gate that saturates
+            # gives p = 0, whose term is 0 and not 0 * -inf
+            term = p * ce + self._beta * p * F.log(F.maximum(p, 1e-30))
+            loss = term if loss is None else loss + term
+        loss = _apply_weighting(F, loss, self._weight, sample_weight)
+        return F.mean(loss, axis=self._batch_axis, exclude=True)

@@ -35,6 +35,11 @@ CAPTURES_TOTAL = _m.counter(
     "mxtpu_trainer_captures_total",
     "Net captures (graph trace + jit rebuild). More than one per input "
     "signature means something is forcing re-capture.")
+LOSS_INPUTS = _m.gauge(
+    "mxtpu_trainer_loss_inputs",
+    "Outputs of the net that the latest capture handed to the loss block: "
+    "as many as the loss's hybrid_forward names in front of label (1 for a "
+    "loss of one prediction, whatever the net returns).")
 GRAD_SKIPPED = _m.gauge(
     "mxtpu_trainer_grad_skipped_steps",
     "Grad-guard skip-step count (published when anomaly_stats()/Monitor "
@@ -78,6 +83,25 @@ POOL_SUNK = _m.counter(
     "trace of such a stem, never per step; a net whose counter stays 0 has "
     "no BatchNorm whose only consumer is a max pool, directly or through a "
     "ReLU, or was captured with the fusion pass off.")
+
+FLASH_ATTENTION_LOWERED = _m.counter(
+    "mxtpu_flash_attention_lowered_total",
+    "Traces of the _contrib_flash_attention op, labeled route= by what its "
+    "forward lowered to: \"pallas\" (the Mosaic kernel: a TPU process or the "
+    "interpreter, head size a multiple of 128, lengths of 8, a batch the op "
+    "can see the split of) or \"xla\" (the plain softmax(q k^T) v form with "
+    "the T x T scores in memory). Counted when the op is TRACED, never per "
+    "step; a long-context net that reads route=\"xla\" on a TPU fell back "
+    "silently and pays for the scores.")
+
+REMAT_SEGMENTS = _m.counter(
+    "mxtpu_remat_segments_total",
+    "Segments of a symbol graph lowered as one function under "
+    "jax.checkpoint (executor._GraphLowering: consecutive nodes that carry "
+    "the same force_mirroring attribute, as mx.AttrScope(force_mirroring=) "
+    "sets it), counted per trace of the lowered function: a capture of a "
+    "looped decoder's step reads passes x layers + exits, a graph without "
+    "the attribute 0.")
 
 # -------------------------------------------------------------------- io
 IO_BATCHES = _m.counter(

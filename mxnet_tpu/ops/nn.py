@@ -222,6 +222,17 @@ def _pool_batch_split():
     return mesh, split[0]
 
 
+def _named_batch_split():
+    """``_pool_batch_split`` for the kernels that were there before a mesh
+    was ever named (attention, cross-entropy): with no mesh named they run
+    as they always have, on the caller's one device's rows (a ``jit`` that
+    shards them over several refuses the Mosaic kernel, loudly); under a
+    named mesh they follow the pool's rule."""
+    if jax.sharding.get_abstract_mesh().empty:
+        return ()
+    return _pool_batch_split()
+
+
 def _pool_tap_eligible(data, lhs, kernel, stride, global_pool):
     """Whether a max pool's backward scatters from the saved winning tap
     (``_max_pool_taps``) and not through ``select-and-scatter``: a 2-D window
@@ -538,6 +549,20 @@ def _layer_norm(data, gamma, beta, axis=-1, eps=1e-5, output_mean_var=False):
             jnp.squeeze(var.astype(data.dtype), ax))
 
 
+@register("RMSNorm", arg_names=("data", "gamma"))
+def _rms_norm(data, gamma, axis=-1, eps=1e-6):
+    """``data / sqrt(mean(data**2) + eps) * gamma`` over ``axis``, the
+    statistic and the products in float32 whatever the data's type, the
+    result in the data's type. An op and not a composition because a block
+    under a symbolic trace cannot name the type it has to cast back to."""
+    ax = int(axis) % data.ndim
+    xf = data.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=ax, keepdims=True)
+                    + jnp.float32(eps))
+    g = _per_channel(gamma.astype(jnp.float32), ax, data.ndim)
+    return (xf * inv * g).astype(data.dtype)
+
+
 @register("InstanceNorm", arg_names=("data", "gamma", "beta"))
 def _instance_norm(data, gamma, beta, eps=1e-3):
     red = tuple(range(2, data.ndim))
@@ -827,16 +852,24 @@ def _spatial_transformer(data, loc, target_shape=(0, 0), transform_type="affine"
 
 
 @register("softmax_cross_entropy", arg_names=("data", "label"))
-def _softmax_cross_entropy(data, label):
+def _softmax_cross_entropy(data, label, per_row=False):
     """Total softmax CE over the batch, shape (1,).
 
     Reference: ``src/operator/loss_binary_op.cc`` (out = Σ_i CE(row_i)).
     On TPU the per-row CE is the fused Pallas kernel (no materialized
     softmax); gradient is the fused softmax−onehot custom VJP.
+
+    ``per_row=True`` gives each row's CE instead, float32 in the shape of
+    ``label``: data (..., C), label (...). What a loss that weights its rows
+    (``gluon.loss.ExpectedExitCELoss``) composes with. The kernel is not
+    partitioned automatically, so under a named mesh that splits the rows
+    over devices (``_named_batch_split``) the log-sum-exp is XLA's.
     """
     from .pallas_kernels import softmax_cross_entropy as _ce
-    per_row = _ce(data, label.astype(jnp.int32).reshape(-1))
-    return jnp.sum(per_row).reshape(1)
+    rows = _ce(data.reshape(-1, data.shape[-1]),
+               label.astype(jnp.int32).reshape(-1),
+               kernel=_named_batch_split() == ())
+    return rows.reshape(label.shape) if per_row else jnp.sum(rows).reshape(1)
 
 
 @register("_contrib_flash_attention", aliases=["contrib_flash_attention"],
@@ -848,11 +881,58 @@ def _flash_attention_op(query, key, value, causal=False, scale=None,
     The reference has no attention op (SURVEY.md §5.7) — this is the
     long-context extension the TPU build makes first-class; the same kernel
     is the ring-attention per-step partial (``parallel.ring_attention``).
+
+    A Mosaic kernel is not partitioned automatically, so where ``jit`` splits
+    the batch over a named mesh axis (``_named_batch_split``) each device runs
+    the kernel on its own rows under ``shard_map``; where the op cannot see
+    the split, or the batch does not divide, the forward keeps the plain XLA
+    form. ``mxtpu_flash_attention_lowered_total{route=}`` counts traces by
+    the route the forward took.
     """
-    from .pallas_kernels import flash_attention
-    return flash_attention(query, key, value, causal=bool(causal),
-                           scale=None if scale is None else float(scale),
-                           q_offset=int(q_offset), k_offset=int(k_offset))
+    from . import pallas_kernels as _pk
+    attend = functools.partial(
+        _pk.flash_attention, causal=bool(causal),
+        scale=None if scale is None else float(scale),
+        q_offset=int(q_offset), k_offset=int(k_offset))
+    kernel = _pk.flash_forward_tiles(query, key)
+    split = _named_batch_split() if kernel else ()
+    if split is None or (split and query.shape[0] % split[0].shape[split[1]]):
+        kernel, split = False, ()
+    from ..observability import catalog as _catalog, metrics as _metrics
+    if _metrics.enabled():
+        _catalog.FLASH_ATTENTION_LOWERED.inc(
+            route="pallas" if kernel else "xla")
+    if not kernel:
+        return attend(query, key, value, kernel=False)
+    return _per_shard(attend, "NHTD", split)(query, key, value)
+
+
+@register("_contrib_rotary_embedding", aliases=["contrib_rotary_embedding"],
+          arg_names=("data",))
+def _rotary_embedding(data, theta=10000.0):
+    """Rotary positions on (..., T, D), half-rotation form: with ``x1, x2``
+    the two halves of the last axis, ``[x1 cos - x2 sin, x2 cos + x1 sin]``
+    at angle ``position * theta**(-2i/D)``; the position is the index along
+    the second-to-last axis. Angles and products in float32,
+    the result in the data's type. An op because the positions are an iota
+    of a length a symbolic trace does not know."""
+    t, d = data.shape[-2], data.shape[-1]
+    if d % 2:
+        raise MXNetError(f"rotary embedding needs an even last axis, got {d}")
+    half = d // 2
+    inv_freq = jnp.float32(theta) ** (
+        -jnp.arange(half, dtype=jnp.float32) * jnp.float32(2.0 / d))
+    pos = jnp.arange(t, dtype=jnp.float32)
+    angle = pos[:, None] * inv_freq[None, :]                 # (T, D/2)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    # [x1, x2] * [cos, cos] + [x2, x1] * [-sin, sin]: the halves meet by a
+    # roll of the whole axis. Joining two computed halves instead makes
+    # XLA:TPU write half a lane tile at a time, and its float32 form at
+    # (1, 16, 4096, 128) fails a check inside the compiler (PERF.md, PR 31)
+    xf = data.astype(jnp.float32)
+    out = xf * jnp.concatenate([cos, cos], axis=-1) \
+        + jnp.roll(xf, half, axis=-1) * jnp.concatenate([-sin, sin], axis=-1)
+    return out.astype(data.dtype)
 
 
 @register("SVMOutput", arg_names=("data", "label"))

@@ -17,7 +17,8 @@ Kernels:
                              ``mx.nd.contrib.flash_attention``.
 * ``softmax_cross_entropy`` — row-fused logsumexp - logit[label], no
                              materialized softmax; grad is the classic
-                             ``softmax - onehot`` (fused by XLA).
+                             ``softmax - onehot`` (fused by XLA, the label's
+                             column found by an iota, no one-hot built).
 
 Gating: Pallas compiles only on TPU. ``use_pallas()`` is True on a TPU
 backend (override off with ``MXTPU_PALLAS=0``); on CPU the same kernels run
@@ -45,6 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
+           "flash_forward_tiles",
            "softmax_cross_entropy", "max_pool_fwd", "max_pool_bwd",
            "use_pallas"]
 
@@ -82,7 +84,7 @@ def _fa_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     for one (bh, q-block); at the final k step the normalized output and the
     row log-sum-exp are written out.
     """
-    ik = pl.program_id(2)
+    iq, ik = pl.program_id(1), pl.program_id(2)
     # Mosaic can't legalize f64 constants: pin every python-float scalar to f32
     scale = jnp.float32(scale)
     neg_inf = jnp.float32(_NEG_INF)
@@ -93,41 +95,45 @@ def _fa_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[...] = jnp.full_like(m_ref, neg_inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32)                     # (bq, D)
-    k = k_ref[0].astype(jnp.float32)                     # (bk, D)
-    v = v_ref[0].astype(jnp.float32)                     # (bk, D)
-    # zero the ragged tail (padded block rows may hold garbage/NaN)
-    krow = lax.broadcasted_iota(jnp.int32, v.shape, 0) + ik * block_k
-    v = jnp.where(krow < tk_total, v, 0.0)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    # global positions: q_offset/k_offset arrive via SMEM (they are traced
+    # values in the ring-attention loop, so they can't be python ints baked
+    # into the kernel). A key block that lies wholly past this query block's
+    # last position is all mask: it changes nothing and is not computed
+    q0 = iq * block_q + offs_ref[0]
+    k0 = ik * block_k + offs_ref[1]
+    seen = (k0 <= q0 + (block_q - 1)) if causal else True
 
-    # mask ragged tail of the key axis (grid pads the last block)
-    k_idx = lax.broadcasted_iota(jnp.int32, s.shape, 1) + ik * block_k
-    s = jnp.where(k_idx < tk_total, s, neg_inf)
+    @pl.when(seen)
+    def _block():
+        # the products run in the operands' own type (bfloat16 at the MXU's
+        # full rate), accumulated in float32; the softmax is float32
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]           # (bq, D), (bk, D) x 2
+        # zero the ragged tail (padded block rows may hold garbage/NaN)
+        krow = lax.broadcasted_iota(jnp.int32, v.shape, 0) + ik * block_k
+        v = jnp.where(krow < tk_total, v, jnp.zeros_like(v))
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
 
-    if causal:
-        # global positions: q_offset/k_offset arrive via SMEM (they are
-        # traced values in the ring-attention loop, so they can't be python
-        # ints baked into the kernel)
-        iq = pl.program_id(1)
-        qpos = lax.broadcasted_iota(jnp.int32, s.shape, 0) + iq * block_q \
-            + offs_ref[0]
-        kpos = lax.broadcasted_iota(jnp.int32, s.shape, 1) + ik * block_k \
-            + offs_ref[1]
-        s = jnp.where(qpos >= kpos, s, neg_inf)
+        # mask ragged tail of the key axis (grid pads the last block)
+        k_idx = lax.broadcasted_iota(jnp.int32, s.shape, 1) + ik * block_k
+        s = jnp.where(k_idx < tk_total, s, neg_inf)
+        if causal:
+            qpos = lax.broadcasted_iota(jnp.int32, s.shape, 0) + q0
+            kpos = lax.broadcasted_iota(jnp.int32, s.shape, 1) + k0
+            s = jnp.where(qpos >= kpos, s, neg_inf)
 
-    m_prev = m_ref[...]                                  # (bq, 128)
-    blk_max = jnp.max(s, axis=1)[:, None]                # (bq, 1)
-    m_new = jnp.maximum(m_prev, jnp.broadcast_to(blk_max, m_prev.shape))
-    p = jnp.exp(s - m_new[:, :1])                        # (bq, bk)
-    p = jnp.where(s <= neg_inf / 2, jnp.float32(0.0), p)
-    corr = jnp.exp(m_prev[:, :1] - m_new[:, :1])         # (bq, 1)
-    l_ref[...] = l_ref[...] * jnp.broadcast_to(corr, l_ref.shape) \
-        + jnp.broadcast_to(jnp.sum(p, axis=1)[:, None], l_ref.shape)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+        m_prev = m_ref[...]                              # (bq, 128)
+        blk_max = jnp.max(s, axis=1)[:, None]            # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.broadcast_to(blk_max, m_prev.shape))
+        p = jnp.exp(s - m_new[:, :1])                    # (bq, bk)
+        p = jnp.where(s <= neg_inf / 2, jnp.float32(0.0), p)
+        corr = jnp.exp(m_prev[:, :1] - m_new[:, :1])     # (bq, 1)
+        l_ref[...] = l_ref[...] * jnp.broadcast_to(corr, l_ref.shape) \
+            + jnp.broadcast_to(jnp.sum(p, axis=1)[:, None], l_ref.shape)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
     @pl.when(ik == nk_total - 1)
     def _finalize():
@@ -146,8 +152,11 @@ def _vma_kw(x):
 
 
 def _fa_pallas(q, k, v, scale, causal, q_offset, k_offset,
-               block_q=128, block_k=128):
-    """q,k,v: (BH, T, D) → (out (BH,Tq,D), lse (BH,Tq)) via pallas_call."""
+               block_q=512, block_k=512):
+    """q,k,v: (BH, T, D) → (out (BH,Tq,D), lse (BH,Tq)) via pallas_call.
+    Blocks of 512 x 512 scores (1 MiB in float32): at 128 x 128 the grid's
+    own steps, 16,384 a call at (16, 4096, 128), were most of the kernel's
+    9.4 ms (PERF.md, PR 31)."""
     BH, Tq, D = q.shape
     Tk = k.shape[1]
     block_q = min(block_q, Tq)
@@ -179,6 +188,7 @@ def _fa_pallas(q, k, v, scale, causal, q_offset, k_offset,
                           block_q=block_q, block_k=block_k, nk_total=nk,
                           tk_total=Tk),
         grid_spec=grid_spec,
+        name="flash_attention_fwd",   # the custom call's in a device trace
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tq, D), q.dtype, **_vma_kw(q)),
             jax.ShapeDtypeStruct((BH, Tq, 128), jnp.float32, **_vma_kw(q)),
@@ -205,30 +215,41 @@ def _fa_reference(q, k, v, scale, causal, q_offset, k_offset):
     return out.astype(q.dtype), lse
 
 
-def _fa_fwd_dispatch(q, k, v, scale, causal, q_offset, k_offset):
+def flash_forward_tiles(q, k) -> bool:
     """The tiling rule: the Pallas kernel takes head dimensions that are a
-    multiple of 128 and sequence lengths that are a multiple of 8; every
-    other shape takes the jnp reference (same numerics, T x T scores in
-    HBM). Callers that must know which ran check the lowered text for
-    ``tpu_custom_call``, as chip_smoke.py does."""
-    D = q.shape[-1]
-    tile_ok = D % 128 == 0 and q.shape[1] % 8 == 0 and k.shape[1] % 8 == 0
+    multiple of 128 and sequence lengths that are a multiple of 8, where
+    Pallas runs at all; every other (..., T, D) shape takes the jnp
+    reference (same numerics, T x T scores in HBM). Callers that must know
+    which ran check the lowered text for ``tpu_custom_call``, as
+    chip_smoke.py does, or read ``mxtpu_flash_attention_lowered_total``."""
+    tile_ok = q.shape[-1] % 128 == 0 and q.shape[-2] % 8 == 0 \
+        and k.shape[-2] % 8 == 0
     # the pallas *interpreter* can't run inside a vma-checked shard_map
     # (dynamic_slice varying-axes mismatch, jax#...); the compiled TPU path can
     interp_in_manual = _interpret() and bool(_vma_kw(q))
-    if use_pallas() and tile_ok and not interp_in_manual:
+    return use_pallas() and tile_ok and not interp_in_manual
+
+
+def _fa_fwd_dispatch(q, k, v, scale, causal, q_offset, k_offset, kernel=True):
+    """(out, lse) by the Pallas kernel where the shape tiles
+    (``flash_forward_tiles``) and the caller does not keep it off
+    (``kernel=False``: an op that cannot see how its batch is split)."""
+    if kernel and flash_forward_tiles(q, k):
         return _fa_pallas(q, k, v, scale, causal, q_offset, k_offset)
     return _fa_reference(q, k, v, scale, causal, q_offset, k_offset)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_core(q, k, v, scale, causal, q_offset, k_offset, block_k):
-    out, _ = _fa_fwd_dispatch(q, k, v, scale, causal, q_offset, k_offset)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_core(q, k, v, scale, causal, q_offset, k_offset, block_k, kernel):
+    out, _ = _fa_fwd_dispatch(q, k, v, scale, causal, q_offset, k_offset,
+                              kernel)
     return out
 
 
-def _flash_core_fwd(q, k, v, scale, causal, q_offset, k_offset, block_k):
-    out, lse = _fa_fwd_dispatch(q, k, v, scale, causal, q_offset, k_offset)
+def _flash_core_fwd(q, k, v, scale, causal, q_offset, k_offset, block_k,
+                    kernel):
+    out, lse = _fa_fwd_dispatch(q, k, v, scale, causal, q_offset, k_offset,
+                                kernel)
     return out, (q, k, v, out, lse)
 
 
@@ -285,7 +306,7 @@ def flash_attention_bwd(q, k, v, out, lse, g, scale, causal,
     return dq, dk, dv
 
 
-def _flash_core_bwd(scale, causal, q_offset, k_offset, block_k,
+def _flash_core_bwd(scale, causal, q_offset, k_offset, block_k, kernel,
                     res, g):
     q, k, v, out, lse = res
     dq, dk, dv = flash_attention_bwd(q, k, v, out, lse, g, scale, causal,
@@ -298,20 +319,22 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
-                    q_offset: int = 0, k_offset: int = 0):
+                    q_offset: int = 0, k_offset: int = 0,
+                    kernel: bool = True):
     """Memory-efficient attention. q,k,v: (B, H, T, D) → (B, H, Tq, D).
 
     Differentiable (custom VJP, blockwise backward). On TPU the forward is a
     Pallas kernel where the shape tiles (D % 128 == 0, T % 8 == 0, see
-    ``_fa_fwd_dispatch``); elsewhere a jnp reference path with identical
-    numerics.
+    ``flash_forward_tiles``) and ``kernel`` is left on; elsewhere a jnp
+    reference path with identical numerics.
     """
     B, H, Tq, Dh = q.shape
     sc = scale if scale is not None else 1.0 / (Dh ** 0.5)
     qf = q.reshape(B * H, Tq, Dh)
     kf = k.reshape(B * H, k.shape[2], Dh)
     vf = v.reshape(B * H, v.shape[2], Dh)
-    out = _flash_core(qf, kf, vf, sc, causal, q_offset, k_offset, 128)
+    out = _flash_core(qf, kf, vf, sc, causal, q_offset, k_offset, 128,
+                      bool(kernel))
     return out.reshape(B, H, Tq, Dh)
 
 
@@ -387,6 +410,7 @@ def _ce_lse_pallas(logits):
     nc = pl.cdiv(C, bc)
     return pl.pallas_call(
         functools.partial(_ce_kernel, block_c=bc, n_classes=C, nc_total=nc),
+        name="cross_entropy_lse",   # the custom call's in a device trace
         grid=(pl.cdiv(N, bn), nc),
         in_specs=[pl.BlockSpec((bn, bc), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bn, 128), lambda i, j: (i, _I0)),
@@ -397,36 +421,46 @@ def _ce_lse_pallas(logits):
     )(logits)[:, 0]
 
 
-@jax.custom_vjp
-def softmax_cross_entropy(logits, labels):
+def softmax_cross_entropy(logits, labels, kernel: bool = True):
     """Per-row CE: logsumexp(logits) − logits[label]. logits (N,C), labels (N,).
 
-    Fused in one Pallas kernel on TPU (no materialized softmax); the gradient
-    is the classic ``(softmax − onehot) · g`` which XLA fuses on its own.
+    Fused in one Pallas kernel on TPU (no materialized softmax) unless the
+    caller keeps it off (``kernel=False``: an op that cannot see how its rows
+    are split over devices); the gradient is the classic
+    ``(softmax − onehot) · g`` in one pass from the saved row log-sum-exp,
+    which XLA fuses on its own.
     """
-    return _ce_fwd(logits, labels)[0]
+    return _ce_rows(logits, labels, bool(kernel))
 
 
-def _ce_fwd(logits, labels):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _ce_rows(logits, labels, kernel):
+    return _ce_fwd(logits, labels, kernel)[0]
+
+
+def _ce_fwd(logits, labels, kernel):
     N, C = logits.shape
     labels = labels.astype(jnp.int32)
-    if use_pallas() and C % 128 == 0 and N % 8 == 0:
+    if kernel and use_pallas() and C % 128 == 0 and N % 8 == 0:
         lse = _ce_lse_pallas(logits)
     else:
         lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=1)
     picked = jnp.take_along_axis(
         logits, labels[:, None], axis=1)[:, 0].astype(jnp.float32)
-    return lse - picked, (logits, labels)
+    return lse - picked, (logits, labels, lse)
 
 
-def _ce_bwd(res, g):
-    logits, labels = res
-    p = jax.nn.softmax(logits.astype(jnp.float32), axis=1)
-    onehot = jax.nn.one_hot(labels, logits.shape[1], dtype=jnp.float32)
-    return ((p - onehot) * g[:, None]).astype(logits.dtype), None
+def _ce_bwd(kernel, res, g):
+    # the label's column by comparison with an iota, inside the one fused
+    # pass over the logits: no (N, C) one-hot is ever built
+    logits, labels, lse = res
+    p = jnp.exp(logits.astype(jnp.float32) - lse[:, None])
+    col = lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    d = jnp.where(col == labels[:, None], p - 1.0, p) * g[:, None]
+    return d.astype(logits.dtype), None
 
 
-softmax_cross_entropy.defvjp(_ce_fwd, _ce_bwd)
+_ce_rows.defvjp(_ce_fwd, _ce_bwd)
 
 
 # ---------------------------------------------------------------------------

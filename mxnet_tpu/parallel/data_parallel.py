@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import inspect
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -168,6 +169,17 @@ def _guard_scaler_apply(guard_cfg, scaler_cfg, gstate, grads,
         gstate.update(_recovery.scaler_apply(
             scaler_cfg, gstate, overflow, bad))
     return tree, gstate
+
+
+def _loss_predictions(loss_block) -> int:
+    """How many of the net's outputs a loss block takes: the positional
+    parameters of its ``hybrid_forward`` in front of ``label`` (one where it
+    names none so, as ``TripletLoss``)."""
+    forward = getattr(loss_block, "hybrid_forward", None)
+    if forward is None:
+        return 1
+    names = list(inspect.signature(forward).parameters)[1:]
+    return names.index("label") if "label" in names[1:] else 1
 
 
 def _make_optax(optimizer: str, optimizer_params: Dict):
@@ -519,13 +531,20 @@ class DataParallelTrainer:
                          for i in range(n_inputs - 1)]
             label_sym = sym_mod.Variable("__label")
             out = self._net(*data_syms)
-            if isinstance(out, (list, tuple)):
-                out = out[0]
-            loss_sym = self._loss_block(out, label_sym)
+            # the loss gets as many of the net's outputs as it takes before
+            # the label: a loss of one prediction the first, as ever
+            outs = list(out) if isinstance(out, (list, tuple)) else [out]
+            outs = outs[:_loss_predictions(self._loss_block)]
+            loss_sym = self._loss_block(*outs, label_sym)
             loss_sym = self._run_passes(loss_sym, data_syms, init_arrays)
             lowering = _GraphLowering(loss_sym)
             raw_fn = lowering.lower(is_train=True)
-        var_names = [n.name for n in loss_sym.topo_nodes() if n.is_var]
+            if _metrics.enabled():
+                _telemetry.LOSS_INPUTS.set(len(outs))
+        # a block that calls a child several times makes a variable node of
+        # the same name per call: every shared parameter once, in first order
+        var_names = list(dict.fromkeys(
+            n.name for n in loss_sym.topo_nodes() if n.is_var))
         data_names = [s.name for s in data_syms] + ["__label"]
         pmap = {p.name: p for p in self._net.collect_params().values()
                 if p.name in var_names}

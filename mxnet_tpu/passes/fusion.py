@@ -170,6 +170,7 @@ class FusionReorderPass(Pass):
                 inner_op._attr_dict = dict(node._attr_dict)
                 out_t = _Node("transpose", namer.fresh(node.name + "_sunk"),
                               {"axes": _axes_of(t)}, [(inner_op, 0)])
+                out_t._attr_dict = dict(node._attr_dict)
                 register(node, out_t)
                 count += 1
                 continue
@@ -186,6 +187,7 @@ class FusionReorderPass(Pass):
                 inner_op._attr_dict = dict(node._attr_dict)
                 out_t = _Node("transpose", namer.fresh(node.name + "_sunk"),
                               {"axes": _axes_of(ta)}, [(inner_op, 0)])
+                out_t._attr_dict = dict(node._attr_dict)
                 register(node, out_t)
                 count += 1
                 continue

@@ -251,6 +251,82 @@ def test_sunk_pool_leaves_no_full_size_pass_on_v5e(four_chips,
                 "a full-size pass over the stem map: " + line[:200]
 
 
+@pytest.mark.parametrize("chips,kernels", [(1, 2), (4, 1)],
+                         ids=["one-chip", "dp4"])
+def test_attention_and_cross_entropy_ops_under_a_named_mesh_on_v5e(
+        four_chips, no_compile_cache, monkeypatch, chips, kernels):
+    """The ops a decoder LM's step is made of, differentiated under the mesh
+    ``DataParallelTrainer`` names: on one chip the attention forward and the
+    cross-entropy's log-sum-exp are Mosaic kernels; on a ``dp`` mesh of four
+    each chip runs the attention kernel on its own rows (``shard_map``; jit
+    would refuse to partition it) and the log-sum-exp is XLA's."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from mxnet_tpu.ops import get_op
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    flash = get_op("_contrib_flash_attention").fn
+    ce = get_op("softmax_cross_entropy").fn
+
+    def loss(q, k, v, y):
+        o = flash(q, k, v, causal=True)
+        logits = o.reshape(o.shape[0], -1, 128)[:, :2048]
+        return jnp.sum(ce(logits, y, per_row=True)) \
+            + jnp.sum(o.astype(jnp.float32))
+
+    mesh = Mesh(np.array(four_chips[:chips]), ("dp",))
+    rows = NamedSharding(mesh, PartitionSpec("dp"))
+    q = jax.ShapeDtypeStruct((4, 16, 1024, 128), jnp.bfloat16, sharding=rows)
+    y = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=rows)
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q, y)
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+
+
+def test_looped_decoder_cell_step_fits_and_keeps_its_kernels_on_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """The cell ``ouro_2_6b.train``'s own step (``harness.build_program``, one
+    row of 4,096 tokens, Adam, bfloat16), compiled for a described v5e: with
+    its 16 layer-calls and 4 exits recomputed it takes under 12.5 GB (the
+    same step without the segments: 14.8 GB by this compile, which with the
+    net's own copy of the weights is past the chip); every attention forward
+    is the Pallas kernel (16 calls and 16 recomputed) and every exit's
+    log-sum-exp too (4 + 4); no [4096, 49152] float32 buffer stands alone in
+    the step: one exit's logits are bfloat16 and the label's column is found
+    by an iota inside the fused gradient, not by a one-hot. Weights are
+    zeros (shapes are all a compile reads)."""
+    import re
+    sys.path.insert(0, REPO)
+    from chipbench import harness
+    bench = harness.load_json(REPO, "BENCHMARK.json")
+    _cell, cfg, _mix, _limits, ref = harness.find_cell(bench, "ouro_2_6b.train")
+    monkeypatch.setattr(mx.init, "Xavier", mx.init.Zero)
+    monkeypatch.setattr(ref, "init", lambda c, k: [
+        jnp.zeros(shape, jnp.float32) for _k, shape, _t in ref.leaf_specs(c)])
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    net, trainer, _mesh, _t = harness.build_program(cfg, ref, 1, jax.devices()[:1])
+    trainer._capture(2, sample_arrays=None)
+    spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    state = jax.tree_util.tree_map(spec, (
+        trainer._params, trainer._aux, trainer._opt_state, trainer._guard_state))
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, cfg["seq_len"]), jnp.int32, sharding=one_chip)
+    step = jax.jit(trainer._step_fn.__wrapped__, donate_argnums=(0, 1, 2, 3))
+    compiled = step.lower(*state, rng, ids, ids).compile()
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 6e9 < peak < 12.5e9, peak
+    text = compiled.as_text()
+    assert "f64[" not in text
+    calls = cfg["num_hidden_layers"] * cfg["total_ut_steps"]
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        2 * calls + 2 * cfg["total_ut_steps"]
+    entry = text[text.index("ENTRY"):].split("\n", 1)[1]
+    logits = re.compile(r"= (\w+)\[(?:1,)?%d,%d\]" % (
+        cfg["seq_len"], cfg["vocab_held"]))
+    kinds = [m.group(1) for m in map(logits.search, entry.splitlines()) if m]
+    assert kinds and set(kinds) == {"bf16"}, sorted(set(kinds))
+
+
 def test_rtc_does_not_choose_interpret_mode(monkeypatch):
     """No chip and no request for the interpreter: the launch fails, it does
     not quietly run the kernel on the host."""

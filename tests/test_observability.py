@@ -294,10 +294,14 @@ def test_watchdog_timeout_dumps_flight_recorder(tmp_path, monkeypatch):
     rt = ResilientTrainer(
         _make_net("obswd_"), gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
         {"learning_rate": 0.1}, directory=str(tmp_path / "run"),
-        preemption=False, retry=False, step_deadline=1.0)
+        preemption=False, retry=False, step_deadline=120.0)
     fired0 = catalog.WATCHDOG_FIRED.value()
+    # the healthy steps compile: beside five other workers that takes more
+    # than the second the hung step gets, and an interrupt out here ends
+    # the worker and with it the whole run
     for _ in range(3):
         rt.step(x, y)
+    rt._watchdog.deadline = 1.0
     with chaos.hung_step(rt, hang=30.0) as st:
         with pytest.raises(KeyboardInterrupt):
             rt.step(x, y)

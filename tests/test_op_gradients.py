@@ -139,6 +139,8 @@ SPEC = {
         tol=dict(eps=1e-2, rtol=3e-2, atol=5e-3)),
     "LayerNorm": dict(inputs=[u(2, 3, 4), u(4, low=0.5, high=1.5), u(4)],
                       tol=dict(rtol=2e-2, atol=2e-3)),
+    "RMSNorm": dict(inputs=[u(2, 3, 4), u(4, low=0.5, high=1.5)],
+                    tol=dict(rtol=2e-2, atol=2e-3)),
     "InstanceNorm": dict(inputs=[u(2, 3, 4, 4), u(3, low=0.5, high=1.5), u(3)],
                          tol=dict(eps=1e-2, rtol=3e-2, atol=5e-3)),
     "L2Normalization": dict(inputs=[away0(2, 3, 4)]),
