@@ -11,9 +11,12 @@ the full width of ResNet-50 (random weights from ``--seed``), in ONE process:
               ModelConfig (buckets 1/8/32): answers match a direct hybridized
               forward, executor on the TPU device
   kernels     nd.contrib.flash_attention (B*H=16, T=2048, D=128, bf16, causal;
-              forward and gradient) and the softmax_cross_entropy op at
-              (4096, 32768) bf16, each against its jnp reference and each shown
-              to lower to a Pallas kernel (``tpu_custom_call``)
+              forward and gradient; again at (16, 4096, 128) under
+              jax.checkpoint with a recomputed segment's policy: one forward
+              kernel, the plain call's gradients) and the
+              softmax_cross_entropy op at (4096, 32768) bf16, each against its
+              jnp reference and each shown to lower to a Pallas kernel
+              (``tpu_custom_call``)
   imperative  an autograd.record() LSTM language-model loop on mx.tpu()
               NDArrays (PTB widths), exercising the per-op jit cache
 
@@ -82,6 +85,7 @@ def compiles():
 FULL = dict(batch=256, image=224, classes=1000, train_steps=6, lr=0.02,
             buckets=(1, 8, 32), bursts=(1, 3, 8, 5, 20, 32, 2, 11),
             fa=(2, 8, 2048, 128), fa_dtype="bfloat16",
+            fa_kept=(1, 16, 4096, 128),     # ouro_2_6b.train's layer-call
             ce=(4096, 32768), ce_dtype="bfloat16",
             lm=dict(vocab=10000, embed=200, hidden=200, layers=2,
                     batch=32, bptt=35, steps=6),
@@ -96,6 +100,7 @@ TINY = dict(batch=8, image=32, classes=10, train_steps=3,
             lr=0.002,
             buckets=(1, 2, 4), bursts=(1, 3, 4, 2),
             fa=(1, 2, 256, 128), fa_dtype="float32",
+            fa_kept=(1, 2, 256, 128),
             ce=(64, 512), ce_dtype="float32",
             lm=dict(vocab=50, embed=16, hidden=16, layers=1,
                     batch=4, bptt=5, steps=3),
@@ -293,6 +298,30 @@ def phase_kernels(cfg, seed, dev, on_tpu):
         "_contrib_flash_attention", normalize_attrs({"causal": True})
     ).lower(q._data, k._data, v._data))
 
+    # ---- the same kernel inside a recomputed segment: under jax.checkpoint
+    # with the lowering's policy the named residuals (out, log-sum-exp) are
+    # kept through Mosaic's custom call, the forward kernel runs once, and
+    # the gradients are those of the plain call
+    from mxnet_tpu.ops.registry import KEPT_IN_SEGMENT
+    qs, ks, vs, ws = [a._data for a in (
+        mx.nd.random_normal(shape=cfg["fa_kept"], dtype=dt) for _ in range(4))]
+    seg_loss = lambda *a: jnp.sum(  # noqa: E731
+        (pk.flash_attention(*a, causal=True) * ws).astype(jnp.float32))
+    t0 = time.perf_counter()
+    kept = jax.jit(jax.grad(jax.checkpoint(
+        seg_loss, policy=jax.checkpoint_policies.save_only_these_names(
+            KEPT_IN_SEGMENT)), argnums=(0, 1, 2))).lower(qs, ks, vs).compile()
+    kept_calls = kept.as_text().count('custom_call_target="tpu_custom_call"')
+    assert kept_calls == (1 if on_tpu else 0), kept_calls
+    plain = jax.jit(jax.grad(seg_loss, argnums=(0, 1, 2)))(qs, ks, vs)
+    kept_err = {n: rel_err(g, p) for n, g, p in
+                zip(("dq", "dk", "dv"), kept(qs, ks, vs), plain)}
+    # the same kernel and the same backward in another program: at most a
+    # rounding of the type where XLA fused differently
+    assert max(kept_err.values()) < (2 ** -7 if dt == "bfloat16" else 1e-6), \
+        kept_err
+    kept_s = time.perf_counter() - t0
+
     # ---- fused softmax cross-entropy through the nd op
     N, C = cfg["ce"]
     logits = mx.nd.random_normal(scale=3.0, shape=(N, C),
@@ -314,7 +343,10 @@ def phase_kernels(cfg, seed, dev, on_tpu):
     placed = on_device([out._data, q.grad._data, logits._data], [dev])
     emit(phase="kernels", ok=True, flash_attention=dict(
         shape=[B * H, T, D], dtype=dt, rel_err=fa_err, pallas=fa_pallas,
-        seconds=round(fa_s, 2)), softmax_cross_entropy=dict(
+        seconds=round(fa_s, 2)), flash_attention_in_segment=dict(
+        shape=list(cfg["fa_kept"]), dtype=dt, forward_kernels=kept_calls,
+        rel_err_to_plain=kept_err, seconds=round(kept_s, 2)),
+        softmax_cross_entropy=dict(
         shape=[N, C], dtype=cfg["ce_dtype"], rel_err=ce_err, value=got_ce,
         pallas=ce_pallas, seconds=round(ce_s, 2)),
         devices=placed, memory=mem_stats(dev))

@@ -20,9 +20,10 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from .base import MXNetError
-from .ops.registry import get_op
+from .ops.registry import KEPT_IN_SEGMENT, get_op
 from ._imperative import _op_signature_flags
 from . import random as _random
 
@@ -182,8 +183,20 @@ class _GraphLowering:
 
     Nodes traced under one ``AttrScope(force_mirroring=<name>)`` lower as ONE
     function under ``jax.checkpoint`` whose inputs are the values that enter
-    the segment: the backward pass keeps those and recomputes the rest. A
-    graph without the attribute lowers node by node, as it always has."""
+    the segment. The backward pass keeps those and the result of every
+    product inside the segment that is no larger than the product's first
+    operand (an op registered with ``product=True``: ``FullyConnected``,
+    ``Convolution``, ``dot``, the attention op and its kernel's residuals;
+    in a decoder layer the output projection, the FFN's down projection and
+    the attention) and recomputes the rest: norms, activations, reshapes,
+    residual adds, and the products that widen (a fused qkv projection, an
+    FFN's up projections: as many bytes to keep as three layer inputs each).
+    The reference's own mirror rule keeps every product (``need_mirror`` in
+    ``src/executor/graph_executor.cc`` never mirrors ``Convolution`` or
+    ``FullyConnected``); kept whole it does not fit the chip at a decoder
+    LM's widths (PERF.md, PR 32). The attribute is a segment's name here,
+    not the reference's per-node override of that rule. A graph without the
+    attribute lowers node by node, as it always has."""
 
     def __init__(self, symbol):
         self.symbol = symbol
@@ -228,10 +241,14 @@ class _GraphLowering:
         nodes = self.nodes
         out_entries = self.symbol._outputs
         plan = self._plan()
+        keep_products = jax.checkpoint_policies.save_only_these_names(
+            KEPT_IN_SEGMENT)
 
-        def run(idxs, vals, rng, aux_updates):
+        def run(idxs, vals, rng, aux_updates, kept=None):
             """The nodes ``idxs`` in order, reading and writing ``vals``
-            ({(id(node), output index): value})."""
+            ({(id(node), output index): value}). Inside a segment (``kept``
+            is its list) a product that does not widen its first operand
+            carries the name the segment's policy keeps, and is listed."""
             for i in idxs:
                 node = nodes[i]
                 opdef = get_op(node.op)
@@ -244,6 +261,10 @@ class _GraphLowering:
                     attrs["rng"] = jax.random.fold_in(rng, i)
                 out = opdef.fn(*in_arrays, **attrs)
                 out = out if isinstance(out, tuple) else (out,)
+                if kept is not None and opdef.product \
+                        and out[0].size <= in_arrays[0].size:
+                    out = (checkpoint_name(out[0], KEPT_IN_SEGMENT),) + out[1:]
+                    kept.append(i)
                 vals.update(((id(node), o), v) for o, v in enumerate(out))
                 if is_train and node.op in _AUX_UPDATE_RULES:
                     upd = _AUX_UPDATE_RULES[node.op](attrs, in_arrays, out)
@@ -252,29 +273,32 @@ class _GraphLowering:
                         if src.is_var:
                             aux_updates[src.name] = new_val
 
-        def segment(idxs, ext, outs):
+        def segment(idxs, ext, outs, kept):
             def body(ext_vals, rng):
                 local, aux_updates = dict(zip(ext, ext_vals)), {}
-                run(idxs, local, rng, aux_updates)
+                run(idxs, local, rng, aux_updates, kept)
                 return [local[k] for k in outs], aux_updates
-            return jax.checkpoint(body)
+            return jax.checkpoint(body, policy=keep_products)
 
         def fn(inputs: Dict[str, Any], rng):
             vals: Dict[Tuple[int, int], Any] = {
                 (id(n), 0): inputs[n.name] for n in nodes if n.is_var}
             aux_updates: Dict[str, Any] = {}
+            kept: List[int] = []
             for unit in plan:
                 if isinstance(unit, int):
                     run((unit,), vals, rng, aux_updates)
                     continue
                 idxs, ext, outs = unit
-                got, upd = segment(idxs, ext, outs)([vals[k] for k in ext], rng)
+                got, upd = segment(idxs, ext, outs, kept)(
+                    [vals[k] for k in ext], rng)
                 vals.update(zip(outs, got))
                 aux_updates.update(upd)
             if self.segments:
                 from .observability import catalog, metrics
                 if metrics.enabled():
                     catalog.REMAT_SEGMENTS.inc(len(self.segments))
+                    catalog.REMAT_KEPT.inc(len(kept))
             return [vals[(id(node), idx)] for (node, idx) in out_entries], \
                 aux_updates
 

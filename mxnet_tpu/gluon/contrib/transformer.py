@@ -14,8 +14,10 @@ Layers:
 - ``RMSNorm``, ``GatedFFN`` — the root-mean-square norm (statistic in
   float32) and the gated-SiLU feed-forward of current decoder LMs.
 - ``SandwichDecoderCell`` — a causal decoder layer with a norm on each
-  sub-layer's input AND output; every call of it asks the lowering to
-  recompute it in the backward pass (``AttrScope(force_mirroring=)``).
+  sub-layer's input AND output; every call of it is a segment
+  (``AttrScope(force_mirroring=)``) of which the lowering keeps the input
+  and the products that do not widen, and recomputes the rest in the
+  backward pass.
 - ``LoopedDecoderLM`` / ``looped_decoder_lm`` — embedding, ONE stack of such
   layers run ``loops`` times over the same parameters, a final norm, an exit
   gate and an untied head; it hands ``gluon.loss.ExpectedExitCELoss`` every
@@ -248,7 +250,11 @@ class SandwichDecoderCell(HybridBlock):
 
     Every CALL of the cell is traced under its own
     ``AttrScope(force_mirroring=<name>)``: the lowering keeps the call's
-    input and recomputes the rest in the backward pass
+    input, the products that do not widen their operand (the attention's
+    output projection, the FFN's down projection) and the attention's
+    output and log-sum-exp, and recomputes the rest in the backward pass:
+    the four norms, rotary, the head split, the SiLU gate, the residual
+    adds, and the widening qkv, gate and up projections
     (docs/architecture.md). A block that calls one cell several times (a
     looped stack) gets one segment a call."""
 
@@ -287,8 +293,10 @@ class LoopedDecoderLM(HybridBlock):
     ``forward(ids (B, S) int)`` -> ``(states (loops, B, S, units), gates
     (loops, B, S), head_weight (vocab, units))``: what
     ``gluon.loss.ExpectedExitCELoss`` takes before the label, so that the
-    head's product lies inside the loss's recomputed segment and one exit's
-    logits live at a time. ``exit_logits(ids)`` gives the logits themselves.
+    head's product is made inside the loss, an exit at a time, and one
+    exit's logits live at a time. The layer-calls (``SandwichDecoderCell``)
+    are the block's only recomputed segments: ``loops`` x ``num_layers``;
+    the exits are none. ``exit_logits(ids)`` gives the logits themselves.
     """
 
     def __init__(self, vocab_size, units, hidden_size, num_layers, num_heads,

@@ -3,7 +3,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from ..attribute import AttrScope
 from .block import HybridBlock
 
 __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
@@ -235,17 +234,18 @@ class ExpectedExitCELoss(Loss):
 
     Takes what ``gluon.contrib.transformer.LoopedDecoderLM`` returns: states
     (exits, B, S, units), gates (exits, B, S), the head's weight (vocab,
-    units), then the label (B, S). The head's product and the cross-entropy
-    of ONE exit at a time are traced under their own
-    ``AttrScope(force_mirroring=)``, so the lowering keeps one
-    exit's logits at a time and recomputes them in the backward pass. Gates,
+    units), then the label (B, S). Each exit's logits are the head's product
+    over that exit's states and go straight into the fused cross-entropy.
+    The exits are NOT recomputed segments: the gradient of the loss in an
+    exit's cross-entropy needs only the gates up to that exit, so an exit's
+    backward pass can run right after its forward; as segments the exits
+    ran every head product and log-sum-exp twice (PERF.md, PR 32). Gates,
     distribution, log-sum-exp and entropy are taken in float32."""
 
     def __init__(self, exits, beta=0.1, weight=None, batch_axis=0, **kwargs):
         super().__init__(weight, batch_axis, **kwargs)
         self._exits = int(exits)
         self._beta = beta
-        self._calls = 0
 
     def hybrid_forward(self, F, states, gates, head_weight, label,
                        sample_weight=None):
@@ -260,10 +260,8 @@ class ExpectedExitCELoss(Loss):
         probs.append(F.ones_like(pick(lam, 0)) if rest is None else rest)
         loss = None
         for t, p in enumerate(probs):
-            self._calls += 1
-            with AttrScope(force_mirroring=f"{self.prefix}exit{self._calls}"):
-                logits = F.dot(pick(states, t), head_weight, transpose_b=True)
-                ce = F.softmax_cross_entropy(logits, label, per_row=True)
+            logits = F.dot(pick(states, t), head_weight, transpose_b=True)
+            ce = F.softmax_cross_entropy(logits, label, per_row=True)
             # - beta * H(p) = beta * sum p log p; a gate that saturates
             # gives p = 0, whose term is 0 and not 0 * -inf
             term = p * ce + self._beta * p * F.log(F.maximum(p, 1e-30))

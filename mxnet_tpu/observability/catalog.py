@@ -100,8 +100,22 @@ REMAT_SEGMENTS = _m.counter(
     "jax.checkpoint (executor._GraphLowering: consecutive nodes that carry "
     "the same force_mirroring attribute, as mx.AttrScope(force_mirroring=) "
     "sets it), counted per trace of the lowered function: a capture of a "
-    "looped decoder's step reads passes x layers + exits, a graph without "
-    "the attribute 0.")
+    "looped decoder's step reads passes x layers (its exits are no "
+    "segments), a graph without the attribute 0. The backward pass keeps a "
+    "segment's inputs and its products that do not widen "
+    "(mxtpu_remat_kept_total) and recomputes the rest.")
+
+REMAT_KEPT = _m.counter(
+    "mxtpu_remat_kept_total",
+    "Values inside recomputed segments that the backward pass keeps besides "
+    "the segments' inputs: output 0 of an op registered with product=True "
+    "(FullyConnected, Convolution, Deconvolution, dot, batch_dot, "
+    "_contrib_flash_attention) where it is no larger than the op's first "
+    "operand; a product that widens is recomputed with the cheap ops. "
+    "Counted per trace of the lowered function, where "
+    "mxtpu_remat_segments_total is: a capture of a looped decoder's step "
+    "reads segments x 3 (out-projection, down-projection and attention of "
+    "a layer-call; qkv, gate and up widen), a graph without segments 0.")
 
 # -------------------------------------------------------------------- io
 IO_BATCHES = _m.counter(

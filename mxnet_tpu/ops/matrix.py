@@ -106,7 +106,7 @@ def _squeeze(x, axis=None):
     return jnp.squeeze(x, tuple(axis))
 
 
-@register("dot")
+@register("dot", product=True)
 def _dot(lhs, rhs, transpose_a=False, transpose_b=False, forward_stype=None):
     # reference tensor/dot-inl.h: reduces over the last axis of lhs and the
     # first axis of rhs (generalized to >2-D operands).
@@ -117,7 +117,7 @@ def _dot(lhs, rhs, transpose_a=False, transpose_b=False, forward_stype=None):
     return jnp.tensordot(lhs, rhs, axes=([lhs.ndim - 1], [0]))
 
 
-@register("batch_dot")
+@register("batch_dot", product=True)
 def _batch_dot(lhs, rhs, transpose_a=False, transpose_b=False, forward_stype=None):
     if transpose_a:
         lhs = jnp.swapaxes(lhs, -1, -2)

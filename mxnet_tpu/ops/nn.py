@@ -42,7 +42,8 @@ def _signed(ax, x, *sign):
 
 
 # ---------------------------------------------------------------- FullyConnected
-@register("FullyConnected", arg_names=("data", "weight", "bias"))
+@register("FullyConnected", arg_names=("data", "weight", "bias"),
+          product=True)
 def _fully_connected(data, weight, bias=None, num_hidden=None, no_bias=False,
                      flatten=True):
     """out = X·Wᵀ + b. Weight layout (num_hidden, input_dim), matching the
@@ -129,7 +130,8 @@ def _conv_s2d(data, weight, pad):
         dimension_numbers=("NHWC", "OHWI", "NHWC"))
 
 
-@register("Convolution", arg_names=("data", "weight", "bias"))
+@register("Convolution", arg_names=("data", "weight", "bias"),
+          product=True)
 def _convolution(data, weight, bias=None, kernel=(), stride=(), dilate=(), pad=(),
                  num_filter=1, num_group=1, no_bias=False, workspace=1024,
                  cudnn_tune=None, cudnn_off=False, layout=None):
@@ -158,7 +160,8 @@ def _convolution(data, weight, bias=None, kernel=(), stride=(), dilate=(), pad=(
     return out
 
 
-@register("Deconvolution", arg_names=("data", "weight", "bias"))
+@register("Deconvolution", arg_names=("data", "weight", "bias"),
+          product=True)
 def _deconvolution(data, weight, bias=None, kernel=(), stride=(), dilate=(), pad=(),
                    adj=(), target_shape=(), num_filter=1, num_group=1, no_bias=True,
                    workspace=512, cudnn_tune=None, cudnn_off=False, layout=None):
@@ -873,7 +876,7 @@ def _softmax_cross_entropy(data, label, per_row=False):
 
 
 @register("_contrib_flash_attention", aliases=["contrib_flash_attention"],
-          arg_names=("query", "key", "value"))
+          arg_names=("query", "key", "value"), product=True)
 def _flash_attention_op(query, key, value, causal=False, scale=None,
                         q_offset=0, k_offset=0):
     """Blockwise (flash) attention, (B, H, T, D) layout; Pallas kernel on TPU.

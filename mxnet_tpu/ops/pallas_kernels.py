@@ -42,8 +42,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .registry import KEPT_IN_SEGMENT
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
            "flash_forward_tiles",
@@ -250,6 +253,12 @@ def _flash_core_fwd(q, k, v, scale, causal, q_offset, k_offset, block_k,
                     kernel):
     out, lse = _fa_fwd_dispatch(q, k, v, scale, causal, q_offset, k_offset,
                                 kernel)
+    # named for a recomputed segment's policy (executor._GraphLowering): with
+    # both kept, its backward pass does not run the forward kernel again.
+    # ``lse`` is no output of the op, so only this rule can name it; outside
+    # a ``jax.checkpoint`` a name is an identity that lowers to nothing
+    out = checkpoint_name(out, KEPT_IN_SEGMENT)
+    lse = checkpoint_name(lse, KEPT_IN_SEGMENT)
     return out, (q, k, v, out, lse)
 
 

@@ -28,9 +28,17 @@ import jax
 
 from ..base import MXNetError
 
-__all__ = ["OpDef", "register", "get_op", "list_ops", "alias", "jitted_op"]
+__all__ = ["OpDef", "register", "get_op", "list_ops", "alias", "jitted_op",
+           "KEPT_IN_SEGMENT"]
 
 _REGISTRY: Dict[str, "OpDef"] = {}
+
+#: the ``jax.ad_checkpoint.checkpoint_name`` of what the backward pass of a
+#: recomputed segment keeps besides the segment's inputs: the result of the
+#: ``product`` ops inside it that do not widen their first operand
+#: (``executor._GraphLowering``) and the attention kernel's residuals
+#: (``pallas_kernels._flash_core_fwd``)
+KEPT_IN_SEGMENT = "mxtpu_segment_product"
 
 
 class OpDef:
@@ -47,6 +55,11 @@ class OpDef:
     needs_rng : op consumes a PRNG key; the runtime threads one in as the
         ``rng`` keyword (imperative: from the global seed stream; symbolic:
         as a traced input so jitted graphs stay functional).
+    product : output 0 is a matrix or convolution product (or the attention
+        built of them): dear to compute again, so a recomputed segment of a
+        graph keeps it for the backward pass where it is no larger than the
+        op's first operand (``executor._GraphLowering``), as the reference's
+        mirror pass never mirrors ``Convolution`` or ``FullyConnected``.
     grad : optional custom gradient: ``grad(attrs) -> fn`` returning a
         function with a ``jax.custom_vjp`` already applied, or None to use
         plain ``jax.vjp`` over ``fn``.
@@ -55,11 +68,12 @@ class OpDef:
 
     def __init__(self, name: str, fn: Callable, num_outputs=1, needs_rng: bool = False,
                  differentiable: bool = True, doc: str = "", arg_names=None,
-                 aux_args=(), host: bool = False):
+                 aux_args=(), host: bool = False, product: bool = False):
         self.name = name
         self.fn = fn
         self.num_outputs = num_outputs
         self.needs_rng = needs_rng
+        self.product = product
         self.differentiable = differentiable
         # host=True: data-dependent shapes/rejection loops with no fixed-shape
         # XLA lowering; imperative path runs fn eagerly (no jit) so it may do
@@ -115,13 +129,14 @@ def normalize_attrs(attrs: Dict[str, Any]) -> Tuple[Tuple[str, Any], ...]:
 
 def register(name: str, num_outputs=1, needs_rng: bool = False,
              differentiable: bool = True, aliases: Sequence[str] = (),
-             arg_names=None, aux_args=(), host: bool = False):
+             arg_names=None, aux_args=(), host: bool = False,
+             product: bool = False):
     """Decorator: register ``fn`` as operator ``name`` (plus aliases)."""
 
     def deco(fn: Callable):
         opdef = OpDef(name, fn, num_outputs=num_outputs, needs_rng=needs_rng,
                       differentiable=differentiable, arg_names=arg_names,
-                      aux_args=aux_args, host=host)
+                      aux_args=aux_args, host=host, product=product)
         _REGISTRY[name] = opdef
         for a in aliases:
             _REGISTRY[a] = opdef

@@ -284,12 +284,19 @@ def test_attention_and_cross_entropy_ops_under_a_named_mesh_on_v5e(
 def test_looped_decoder_cell_step_fits_and_keeps_its_kernels_on_v5e(
         one_chip, no_compile_cache, monkeypatch):
     """The cell ``ouro_2_6b.train``'s own step (``harness.build_program``, one
-    row of 4,096 tokens, Adam, bfloat16), compiled for a described v5e: with
-    its 16 layer-calls and 4 exits recomputed it takes under 12.5 GB (the
-    same step without the segments: 14.8 GB by this compile, which with the
-    net's own copy of the weights is past the chip); every attention forward
-    is the Pallas kernel (16 calls and 16 recomputed) and every exit's
-    log-sum-exp too (4 + 4); no [4096, 49152] float32 buffer stands alone in
+    row of 4,096 tokens, Adam, bfloat16), compiled for a described v5e. Each
+    of its 16 layer-calls is a recomputed segment that keeps its input, the
+    products that do not widen (out-projection, down-projection) and the
+    attention's output and log-sum-exp; norms, rotary, SiLU gates, residual
+    adds and the widening products (qkv, gate, up) are recomputed; the 4
+    exits are no segments. So the step takes 8 to 9.5 GB (8.64 by this
+    compile; 7.98 GB when a segment kept its input alone; 12.1 GB with
+    every product kept and 14.8 GB without segments, which with the net's
+    own copy of the weights are both past the chip), its XLA products are
+    under 36 TFLOP (35.0; 40.4 when every segment and every exit ran twice,
+    30.4 with every product kept), every attention forward is the Pallas
+    kernel, once (16 calls), and every exit's log-sum-exp too (4); no
+    [4096, 49152] float32 buffer stands alone in
     the step: one exit's logits are bfloat16 and the label's column is found
     by an iota inside the fused gradient, not by a one-hot. Weights are
     zeros (shapes are all a compile reads)."""
@@ -314,12 +321,15 @@ def test_looped_decoder_cell_step_fits_and_keeps_its_kernels_on_v5e(
     m = compiled.memory_analysis()
     peak = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
-    assert 6e9 < peak < 12.5e9, peak
+    assert 8.0e9 < peak < 9.5e9, peak
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    assert cost["flops"] < 36e12, cost["flops"]
     text = compiled.as_text()
     assert "f64[" not in text
     calls = cfg["num_hidden_layers"] * cfg["total_ut_steps"]
     assert text.count('custom_call_target="tpu_custom_call"') == \
-        2 * calls + 2 * cfg["total_ut_steps"]
+        calls + cfg["total_ut_steps"]
     entry = text[text.index("ENTRY"):].split("\n", 1)[1]
     logits = re.compile(r"= (\w+)\[(?:1,)?%d,%d\]" % (
         cfg["seq_len"], cfg["vocab_held"]))

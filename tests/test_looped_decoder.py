@@ -200,8 +200,8 @@ def _checkpointed_calls(jaxpr_text):
 def test_segments_change_neither_loss_nor_update(ref, cell_cfg, mix,
                                                  monkeypatch):
     cfg = small_cfg(cell_cfg)
-    segments = cfg["num_hidden_layers"] * cfg["total_ut_steps"] \
-        + cfg["total_ut_steps"]
+    # one segment a layer-call; the exits are no segments
+    segments = cfg["num_hidden_layers"] * cfg["total_ut_steps"]
     runs = {}
     for name, want in (("segments", segments), ("plain", 0)):
         if name == "plain":     # the same graph, its attribute not read
@@ -225,6 +225,149 @@ def test_segments_change_neither_loss_nor_update(ref, cell_cfg, mix,
     np.testing.assert_allclose(runs["segments"][0], runs["plain"][0], atol=1e-6)
     for a, b in zip(runs["segments"][1], runs["plain"][1]):
         np.testing.assert_allclose(a, b, atol=1e-6)
+
+
+LAYERS, PASSES = 2, 3
+#: a layer-call's products that do not widen their first operand and are
+#: kept: out-proj, down and the attention (qkv, gate and up widen)
+PRODUCTS = 3
+#: ... as ``dot_general``s on the CPU, where the attention's forward is the
+#: plain form's two (q k^T and p v)
+DOTS = 4
+
+
+def _toy_loss(dtype="float32"):
+    """A looped decoder of 2 layers and 3 passes under its loss, as a graph:
+    (symbol, f(parameters) -> loss, parameters)."""
+    from mxnet_tpu import symbol as sym
+    mx.random.seed(7)
+    net = tfm.looped_decoder_lm(vocab_size=64, units=32, hidden_size=48,
+                                num_layers=LAYERS, num_heads=2, loops=PASSES)
+    net.initialize(mx.init.Xavier())
+    x = np.random.RandomState(0).randint(0, 64, (2, 8))
+    net(ids(x))
+    loss = gluon.loss.ExpectedExitCELoss(exits=PASSES)
+    symbol = loss(*net(sym.Variable("__data0")), sym.Variable("__label"))
+    values = {p.name: p.data()._data.astype(dtype)
+              for p in net.collect_params().values()}
+
+    def f(lowering, ws):
+        outs, _ = lowering.lower(True)(
+            dict(ws, __data0=jnp.asarray(x), __label=jnp.asarray(x[:, ::-1])),
+            jax.random.PRNGKey(0))
+        return jnp.mean(outs[0].astype(jnp.float32))
+
+    return symbol, f, values
+
+
+def test_a_segment_keeps_its_products(monkeypatch):
+    """The gradient of a graph with segments traces a segment's products
+    that do not widen once: against the same graph under a bare
+    ``jax.checkpoint`` (every segment's forward traced again for its
+    backward) it holds fewer ``dot_general``s by exactly those products."""
+    symbol, f, values = _toy_loss()
+    lowering = _GraphLowering(symbol)
+    assert len(lowering.segments) == LAYERS * PASSES
+
+    def dots():
+        before = catalog.REMAT_SEGMENTS.value(), catalog.REMAT_KEPT.value()
+        text = str(jax.make_jaxpr(jax.grad(
+            functools.partial(f, lowering)))(values))
+        assert (catalog.REMAT_SEGMENTS.value() - before[0],
+                catalog.REMAT_KEPT.value() - before[1]) == (
+            LAYERS * PASSES, LAYERS * PASSES * PRODUCTS)
+        return text.count("dot_general")
+
+    kept = dots()
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+    assert dots() - kept == LAYERS * PASSES * DOTS
+
+
+def test_the_ops_that_call_themselves_products():
+    """A product says so where it is registered; the lowering holds no list."""
+    from mxnet_tpu.ops.registry import _REGISTRY
+    assert {op.name for op in _REGISTRY.values() if op.product} == {
+        "FullyConnected", "dot", "batch_dot", "Convolution", "Deconvolution",
+        "_contrib_flash_attention"}
+
+
+@pytest.mark.parametrize("hidden,kept", [(4, 1), (8, 1), (9, 0), (24, 0)])
+def test_a_segment_keeps_a_product_that_does_not_widen(hidden, kept):
+    """Inside a segment a product's result is named, and so kept, where it
+    is no larger than the product's first operand; a widening one is traced
+    again for the backward pass with the cheap ops. Outside, none is named."""
+    from mxnet_tpu import symbol as sym
+    x = sym.Variable("x")
+    h = sym.FullyConnected(x, num_hidden=8, name="outside")
+    with mx.AttrScope(force_mirroring="a"):
+        h = sym.tanh(sym.FullyConnected(h, num_hidden=hidden, name="fc"))
+    fn = _GraphLowering(sym.sum(h)).lower(True)
+    ins = {"x": jnp.ones((4, 8)), "outside_weight": jnp.ones((8, 8)),
+           "outside_bias": jnp.ones((8,)), "fc_weight": jnp.ones((hidden, 8)),
+           "fc_bias": jnp.ones((hidden,))}
+    before = catalog.REMAT_KEPT.value()
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda i: fn(i, jax.random.PRNGKey(0))[0][0]))(ins))
+    assert catalog.REMAT_KEPT.value() - before == kept
+    assert text.count("name[") == kept
+    # outside's product, forward and two gradients; fc's the same, and once
+    # more where it is recomputed
+    assert text.count("dot_general") == 3 + 3 + (1 - kept)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 2 ** -6)])
+def test_kept_products_give_the_unsegmented_gradients(monkeypatch, dtype, tol):
+    """A kept value is the value the recomputation would have produced: the
+    gradients are those of the same graph with the attribute not read, to
+    XLA's choice of fusions: a float32 rounding; in bfloat16 a few of its
+    own (2**-8 each: inside a fusion a recomputed value is not rounded to
+    bfloat16 between two ops, a stored one is; measured 0.0088 of a leaf's
+    gradient norm at worst)."""
+    from mxnet_tpu import executor
+    symbol, f, values = _toy_loss(dtype)
+    grad = lambda: jax.jit(jax.grad(functools.partial(  # noqa: E731
+        f, _GraphLowering(symbol))))(values)
+    got = grad()
+    monkeypatch.setattr(executor, "_mirror_segments", lambda nodes: [])
+    want = grad()
+    for name in values:
+        a, b = (np.asarray(g[name], np.float32) for g in (got, want))
+        assert np.linalg.norm(a - b) <= tol * np.linalg.norm(b), name
+
+
+@pytest.mark.parametrize("route", ["kernel", "xla"])
+def test_flash_attention_keeps_its_residuals_under_a_policy(
+        monkeypatch, rng, route):
+    """Under ``jax.checkpoint`` with the segments' policy the attention's
+    forward is traced once (``out`` and the log-sum-exp are kept for the
+    blockwise backward), under a bare one twice; the gradient is the
+    unwrapped call's."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.ops.registry import KEPT_IN_SEGMENT
+    monkeypatch.setenv("MXTPU_PALLAS_INTERPRET",
+                       "1" if route == "kernel" else "0")
+    q, k, v = (jnp.asarray(rng.randn(1, 2, 16, 128), jnp.float32)
+               for _ in range(3))
+    loss = lambda *a: jnp.sum(pk.flash_attention(*a, causal=True) ** 2)  # noqa: E731
+
+    def forwards(fn):
+        text = str(jax.make_jaxpr(jax.grad(fn, argnums=(0, 1, 2)))(q, k, v))
+        if route == "kernel":
+            return text.count("pallas_call")
+        # the plain form is two products (q k^T, p v); the blockwise
+        # backward's scan body holds five of its own
+        return (text.count("dot_general") - 5) // 2
+
+    kept = jax.checkpoint(
+        loss, policy=jax.checkpoint_policies.save_only_these_names(
+            KEPT_IN_SEGMENT))
+    assert (forwards(loss), forwards(kept), forwards(jax.checkpoint(loss))) \
+        == (1, 1, 2)
+    got = jax.grad(kept, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
 
 
 def test_segment_is_one_run_of_equal_attributes():
