@@ -87,6 +87,8 @@ FULL = dict(batch=256, image=224, classes=1000, train_steps=6, lr=0.02,
             fa=(2, 8, 2048, 128), fa_dtype="bfloat16",
             fa_kept=(1, 16, 4096, 128),     # ouro_2_6b.train's layer-call
             ce=(4096, 32768), ce_dtype="bfloat16",
+            # zaya1_8b.train's expert layer: tokens, width, hidden, held
+            moe=(16384, 2048, 2048, 8), moe_dtype="bfloat16",
             lm=dict(vocab=10000, embed=200, hidden=200, layers=2,
                     batch=32, bptt=35, steps=6),
             mc_steps=3, mc_loss_tol=2.5e-2,
@@ -102,6 +104,7 @@ TINY = dict(batch=8, image=32, classes=10, train_steps=3,
             fa=(1, 2, 256, 128), fa_dtype="float32",
             fa_kept=(1, 2, 256, 128),
             ce=(64, 512), ce_dtype="float32",
+            moe=(192, 128, 256, 4), moe_dtype="float32",
             lm=dict(vocab=50, embed=16, hidden=16, layers=1,
                     batch=4, bptt=5, steps=3),
             mc_steps=2, mc_loss_tol=0.25,
@@ -340,8 +343,63 @@ def phase_kernels(cfg, seed, dev, on_tpu):
     assert np.isfinite(got_ce) and ce_err < 1e-3, (got_ce, ref_ce)
     ce_pallas = pallas_in(jitted_op("softmax_cross_entropy", ())
                           .lower(logits._data, labels._data))
+    # ---- routed experts: the grouped products (tokens sorted by expert,
+    # uneven groups, one of them empty, half the tokens routed to experts
+    # held elsewhere) and their gradients against the per-expert loop in
+    # float32 at the highest precision
+    from mxnet_tpu.ops import nn as ops_nn
+    from mxnet_tpu.ops.registry import get_op
+    T, W, F, held = cfg["moe"]
+    mdt = jnp.dtype(cfg["moe_dtype"])
+    rs = np.random.RandomState(seed + 1)
+    load = rs.dirichlet(np.full(2 * held, 0.7))
+    load[1] = 0.0                                   # expert 1 gets no token
+    expert = jnp.asarray(rs.choice(2 * held, T, p=load / load.sum()),
+                         jnp.int32)
+    xm = jnp.asarray(rs.randn(T, W), mdt)
+    gate_m = jnp.asarray(rs.uniform(0.1, 1.0, T), jnp.float32)
+    wm = [jnp.asarray(rs.randn(held, *sh) / np.sqrt(sh[1]), mdt)
+          for sh in ((F, W), (F, W), (W, F))]
+    ym = jnp.asarray(rs.randn(T, W), jnp.float32)
+    moe_op = functools.partial(get_op("_contrib_moe_experts").fn,
+                               first_expert=0, num_experts=2 * held)
+    def moe_loss(fn, x, g, *w):
+        out = fn(x, expert, g, *w).astype(jnp.float32)
+        return jnp.sum(out * ym), out
+
+    grouped = jax.jit(jax.value_and_grad(
+        functools.partial(moe_loss, moe_op), argnums=(0, 1, 2, 3, 4),
+        has_aux=True))
+    moe_pallas = pallas_in(grouped.lower(xm, gate_m, *wm))
+    t0 = time.perf_counter()
+    got_m = jax.block_until_ready(grouped(xm, gate_m, *wm))
+    moe_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    for _ in range(3):
+        got_m = jax.block_until_ready(grouped(xm, gate_m, *wm))
+    moe_ms = (time.perf_counter() - t0) / 3 * 1e3
+    plain_fn = ops_nn._moe_experts_fn(0, "plain", pk.MOE_TILE_ROWS)
+    with jax.default_matmul_precision("highest"):
+        ref_m = jax.jit(jax.value_and_grad(
+            functools.partial(moe_loss, plain_fn), argnums=(0, 1, 2, 3, 4),
+            has_aux=True))(
+                xm.astype(jnp.float32), gate_m,
+                *[a.astype(jnp.float32) for a in wm])
+    moe_err = {n: rel_err(np.asarray(g.astype(jnp.float32)), r) for n, g, r in
+               zip(("dx", "dgate", "dw_gate", "dw_up", "dw_down"),
+                   got_m[1], ref_m[1])}
+    moe_err["out"] = rel_err(np.asarray(got_m[0][1]), ref_m[0][1])
+    assert max(moe_err.values()) < (3e-2 if mdt == jnp.bfloat16 else 2e-4), \
+        moe_err
+    counts = np.bincount(np.asarray(expert), minlength=2 * held)[:held]
+    assert counts[1] == 0 and float(jnp.abs(got_m[1][2][1]).max()) == 0.0
+
     placed = on_device([out._data, q.grad._data, logits._data], [dev])
-    emit(phase="kernels", ok=True, flash_attention=dict(
+    emit(phase="kernels", ok=True, routed_experts=dict(
+        shape=[T, W, F, held], dtype=str(mdt), pallas=moe_pallas,
+        tokens_per_held_expert=counts.tolist(), rel_err=moe_err,
+        first_call_s=round(moe_s, 2), forward_and_gradient_ms=round(moe_ms, 3)),
+        flash_attention=dict(
         shape=[B * H, T, D], dtype=dt, rel_err=fa_err, pallas=fa_pallas,
         seconds=round(fa_s, 2)), flash_attention_in_segment=dict(
         shape=list(cfg["fa_kept"]), dtype=dt, forward_kernels=kept_calls,

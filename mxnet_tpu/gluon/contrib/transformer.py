@@ -22,6 +22,15 @@ Layers:
   layers run ``loops`` times over the same parameters, a final norm, an exit
   gate and an untied head; it hands ``gluon.loss.ExpectedExitCELoss`` every
   pass's normed state, every gate and the head's weight.
+- ``CompressedLatentAttention``, ``RouterMLP``, ``RoutedExperts``,
+  ``ZayaDecoderCell``, ``ZayaDecoderLM`` / ``zaya_decoder_lm`` — a decoder LM
+  of top-1 routed experts of which this holder has a share
+  (``F._contrib_moe_experts``: dropless, a grouped product), chosen by a
+  router MLP that hands its state to the next layer's, with attention in a
+  compressed latent (grouped key/value heads, two causal convolutions over
+  queries and keys, a shifted value head, partial rotary) and a TIED head:
+  it hands ``gluon.loss.TiedHeadCELoss`` its final normed states and the
+  embedding's weight.
 - ``TransformerEncoderCell`` / ``TransformerDecoderCell`` (causal) —
   pre-norm residual blocks (pre-norm trains stably at depth without warmup
   gymnastics; the post-norm original is available via ``pre_norm=False``).
@@ -37,13 +46,16 @@ import numpy as np
 from ...attribute import AttrScope
 from ...base import MXNetError
 from ..block import Block, HybridBlock
-from ..nn import Dense, Dropout, Embedding, LayerNorm, HybridSequential
+from ..nn import (Conv1D, Dense, Dropout, Embedding, LayerNorm,
+                  HybridSequential)
 
 __all__ = ["MultiHeadAttention", "TransformerEncoderCell",
            "TransformerDecoderCell", "TransformerEncoder",
            "SinusoidalPositionalEmbedding", "TransformerLM", "RMSNorm",
            "GatedFFN", "SandwichDecoderCell", "LoopedDecoderLM",
-           "looped_decoder_lm"]
+           "looped_decoder_lm", "CompressedLatentAttention", "RouterMLP",
+           "RoutedExperts", "ZayaDecoderCell", "ZayaDecoderLM",
+           "zaya_decoder_lm"]
 
 
 class MultiHeadAttention(HybridBlock):
@@ -343,3 +355,291 @@ def looped_decoder_lm(vocab_size, units, hidden_size, num_layers, num_heads,
     return LoopedDecoderLM(vocab_size, units, hidden_size, num_layers,
                            num_heads, loops=loops, rotary_theta=rotary_theta,
                            epsilon=epsilon, **kw)
+
+
+class CompressedLatentAttention(HybridBlock):
+    """Causal self-attention in a compressed latent with convolution mixing
+    (arXiv:2510.04476): ``num_heads`` query heads and ``num_kv_heads``
+    key/value heads of ``head_dim``, so the latent is ``num_heads *
+    head_dim`` wide for the queries and ``num_kv_heads * head_dim`` for keys
+    and values, whatever ``units`` is. For a row ``x`` (B, T, units)::
+
+        q0, k0 = x Wq, x Wk
+        v = [x Wv_h ; shift(x Wv_h)]  a key/value head sees the token, the
+                                      next one the token before it, ...
+        [qc ; kc] = conv2(conv1([q0 ; k0]))   along T, both causal: conv1
+                    depthwise, conv2 grouped by head, kernels ``conv_kernels``
+        q = qc + (q0 + k0[its key head]) / 2
+        k = kc + (k0 + mean of its query heads' q0) / 2
+        q, k to a root mean square of 1 per head; k times a learned
+        temperature per key/value head; rotary on the first
+        ``rotary_dim`` channels of every head
+        out = causal softmax(q k^T / sqrt(head_dim)) v  Wo
+
+    No bias on the projections, one on each convolution. The attention op
+    (``F._contrib_flash_attention``) sees ``num_heads`` key/value heads: each
+    is repeated for the query heads it serves."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 conv_kernels=(2, 2), rotary_theta=10000.0, rotary_dim=None,
+                 epsilon=1e-6, **kw):
+        super().__init__(**kw)
+        if num_heads % num_kv_heads:
+            raise MXNetError(f"{num_heads} query heads do not divide over "
+                             f"{num_kv_heads} key/value heads")
+        self._heads, self._kv, self._dim = num_heads, num_kv_heads, head_dim
+        self._theta, self._rot, self._eps = rotary_theta, rotary_dim, epsilon
+        self._taps = tuple(conv_kernels)
+        latent = (num_heads + num_kv_heads) * head_dim
+        with self.name_scope():
+            self.query = Dense(num_heads * head_dim, flatten=False,
+                               use_bias=False, in_units=units, prefix="query_")
+            self.key = Dense(num_kv_heads * head_dim, flatten=False,
+                             use_bias=False, in_units=units, prefix="key_")
+            self.values = []
+            for i in range(num_kv_heads):
+                proj = Dense(head_dim, flatten=False, use_bias=False,
+                             in_units=units, prefix=f"value{i}_")
+                self.register_child(proj, f"value{i}")
+                self.values.append(proj)
+            self.conv1 = Conv1D(latent, self._taps[0], groups=latent,
+                                padding=self._taps[0] - 1, layout="NWC",
+                                in_channels=latent, prefix="conv1_")
+            self.conv2 = Conv1D(latent, self._taps[1],
+                                groups=num_heads + num_kv_heads,
+                                padding=self._taps[1] - 1, layout="NWC",
+                                in_channels=latent, prefix="conv2_")
+            self.temperature = self.params.get(
+                "temperature", shape=(num_kv_heads,), init="ones")
+            self.proj = Dense(units, flatten=False, use_bias=False,
+                              in_units=num_heads * head_dim, prefix="proj_")
+
+    @staticmethod
+    def _causal(F, conv, x, taps):
+        """A convolution padded on both sides, less the positions that look
+        ahead: position t sees t - taps + 1 .. t."""
+        y = conv(x)
+        return y if taps == 1 else F.slice_axis(y, axis=1, begin=0,
+                                                end=1 - taps)
+
+    def hybrid_forward(self, F, x, temperature):
+        h, kv, d = self._heads, self._kv, self._dim
+        q0, k0 = self.query(x), self.key(x)                 # (B, T, H D)
+        vs = []
+        for i, proj in enumerate(self.values):
+            v = proj(x)                                     # (B, T, D)
+            if i:   # shift(x) W = shift(x W): zero rows in front, the end cut
+                v = F.slice_axis(F.pad(
+                    F.expand_dims(v, axis=0), mode="constant",
+                    pad_width=(0, 0, 0, 0, i, 0, 0, 0)), axis=2, begin=0,
+                    end=-i)
+                v = F.squeeze(v, axis=0)
+            vs.append(F.expand_dims(v, axis=2))             # (B, T, 1, D)
+        v = F.concat(*vs, dim=2) if kv > 1 else vs[0]       # (B, T, KV, D)
+        mixed = self._causal(F, self.conv2, self._causal(
+            F, self.conv1, F.concat(q0, k0, dim=2), self._taps[0]),
+            self._taps[1])
+        qc = F.reshape(F.slice_axis(mixed, axis=2, begin=0, end=h * d),
+                       shape=(0, 0, kv, h // kv, d))
+        kc = F.reshape(F.slice_axis(mixed, axis=2, begin=h * d, end=None),
+                       shape=(0, 0, kv, 1, d))
+        q0 = F.reshape(q0, shape=(0, 0, kv, h // kv, d))
+        k0 = F.reshape(k0, shape=(0, 0, kv, 1, d))
+        q = qc + F.broadcast_add(q0, k0) * 0.5
+        k = kc + (k0 + F.mean(q0, axis=3, keepdims=True)) * 0.5
+        q = F.RMSNorm(q, axis=-1, eps=self._eps, no_gain=True)
+        k = F.broadcast_mul(
+            F.RMSNorm(k, axis=-1, eps=self._eps, no_gain=True),
+            F.reshape(temperature, shape=(1, 1, kv, 1, 1)))
+        # (B, T, KV, G, D) -> (B, H, T, D); a key/value head once per query
+        # head of its group
+        q = F.transpose(F.reshape(q, shape=(0, 0, h, d)), axes=(0, 2, 1, 3))
+        k = F.transpose(F.reshape(F.broadcast_axis(
+            k, axis=3, size=h // kv), shape=(0, 0, h, d)), axes=(0, 2, 1, 3))
+        v = F.transpose(F.reshape(F.broadcast_axis(
+            F.expand_dims(v, axis=3), axis=3, size=h // kv),
+            shape=(0, 0, h, d)), axes=(0, 2, 1, 3))
+        rot = dict(theta=self._theta, rotary_dim=self._rot)
+        out = F.contrib_flash_attention(
+            F.contrib_rotary_embedding(q, **rot),
+            F.contrib_rotary_embedding(k, **rot), v, causal=True)
+        out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)), shape=(0, 0, -1))
+        return self.proj(out)
+
+
+class RouterMLP(HybridBlock):
+    """The router of a layer of top-1 routed experts (arXiv:2511.17127): a
+    projection of the token to ``hidden`` channels, mixed with the layer
+    before's router state by a learned vector where there is one
+    (``mixes=True``), a three-layer gelu MLP to ``num_experts`` scores, their
+    softmax and its top-1. ``forward(x[, state])`` -> (expert (B, T) int32,
+    gate (B, T) float32, state (B, T, hidden) float32); float32 throughout
+    (``F._contrib_moe_router``)."""
+
+    def __init__(self, units, hidden, num_experts, mixes=False, **kw):
+        super().__init__(**kw)
+        with self.name_scope():
+            get = self.params.get
+            self.down_weight = get("down_weight", shape=(hidden, units))
+            self.down_bias = get("down_bias", shape=(hidden,), init="zeros")
+            if mixes:
+                self.mix = get("mix", shape=(hidden,), init="zeros")
+            self.w1 = get("w1_weight", shape=(hidden, hidden))
+            self.w2 = get("w2_weight", shape=(hidden, hidden))
+            self.w3 = get("w3_weight", shape=(num_experts, hidden))
+
+    def hybrid_forward(self, F, x, state=None, *, down_weight, down_bias, w1,
+                       w2, w3, mix=None):
+        more = () if state is None else (state, mix)
+        expert, gate, state = F.contrib_moe_router(
+            x, down_weight, down_bias, w1, w2, w3, *more)
+        return expert, gate, state
+
+
+class RoutedExperts(HybridBlock):
+    """The gated-SiLU experts ``first_expert .. first_expert + experts_held -
+    1`` of a layer of ``num_experts``, their weights stacked:
+    ``forward(x, expert, gate)`` is this holder's part of the layer's result
+    (``F._contrib_moe_experts``), 0 for a token routed elsewhere."""
+
+    def __init__(self, units, hidden_size, num_experts, experts_held=None,
+                 first_expert=0, **kw):
+        super().__init__(**kw)
+        held = num_experts if experts_held is None else experts_held
+        if not 0 <= first_expert <= num_experts - held:
+            raise MXNetError(f"experts {first_expert}..{first_expert + held - 1} "
+                             f"are not among {num_experts}")
+        self._first, self._experts = first_expert, num_experts
+        with self.name_scope():
+            get = self.params.get
+            self.gate_weight = get("gate_weight",
+                                   shape=(held, hidden_size, units))
+            self.up_weight = get("up_weight", shape=(held, hidden_size, units))
+            self.down_weight = get("down_weight",
+                                   shape=(held, units, hidden_size))
+
+    def hybrid_forward(self, F, x, expert, gate, gate_weight, up_weight,
+                       down_weight):
+        return F.contrib_moe_experts(
+            x, expert, gate, gate_weight, up_weight, down_weight,
+            first_expert=self._first, num_experts=self._experts)
+
+
+class _Join(HybridBlock):
+    """``y * scale + shift``, learned per channel: what a sub-layer's output
+    passes on its way into the residual."""
+
+    def __init__(self, units, **kw):
+        super().__init__(**kw)
+        with self.name_scope():
+            self.scale = self.params.get("scale", shape=(units,), init="ones")
+            self.shift = self.params.get("shift", shape=(units,), init="zeros")
+
+    def hybrid_forward(self, F, y, scale, shift):
+        return F.broadcast_add(F.broadcast_mul(y, scale), shift)
+
+
+class ZayaDecoderCell(HybridBlock):
+    """One decoder layer of attention in a compressed latent and top-1
+    routed experts, each behind an RMSNorm and joined to the residual through
+    a learned scale and shift::
+
+        h = h + join_a(attn(norm_a(h)))
+        expert, gate, state = router(norm_m(h)[, the layer before's state])
+        h = h + join_m(experts(norm_m(h), expert, gate))
+
+    ``forward(h[, state])`` -> (h, state). Every CALL is one recomputed
+    segment (``AttrScope(force_mirroring=)``, as ``SandwichDecoderCell``)."""
+
+    def __init__(self, units, hidden_size, num_heads, num_kv_heads, head_dim,
+                 num_experts, router_hidden, experts_held=None,
+                 first_expert=0, mixes=False, conv_kernels=(2, 2),
+                 rotary_theta=10000.0, rotary_dim=None, epsilon=1e-5, **kw):
+        super().__init__(**kw)
+        self._calls = 0
+        with self.name_scope():
+            self.norm_a = RMSNorm(epsilon=epsilon, in_channels=units,
+                                  prefix="norm_a_")
+            self.attn = CompressedLatentAttention(
+                units, num_heads, num_kv_heads, head_dim,
+                conv_kernels=conv_kernels, rotary_theta=rotary_theta,
+                rotary_dim=rotary_dim, epsilon=epsilon, prefix="attn_")
+            self.join_a = _Join(units, prefix="join_a_")
+            self.norm_m = RMSNorm(epsilon=epsilon, in_channels=units,
+                                  prefix="norm_m_")
+            self.router = RouterMLP(units, router_hidden, num_experts,
+                                    mixes=mixes, prefix="router_")
+            self.experts = RoutedExperts(
+                units, hidden_size, num_experts, experts_held=experts_held,
+                first_expert=first_expert, prefix="experts_")
+            self.join_m = _Join(units, prefix="join_m_")
+
+    def hybrid_forward(self, F, h, state=None):
+        self._calls += 1
+        with AttrScope(force_mirroring=f"{self.prefix}call{self._calls}"):
+            h = h + self.join_a(self.attn(self.norm_a(h)))
+            x = self.norm_m(h)
+            more = () if state is None else (state,)
+            expert, gate, state = self.router(x, *more)
+            return h + self.join_m(self.experts(x, expert, gate)), state
+
+
+class ZayaDecoderLM(HybridBlock):
+    """A decoder LM of ``ZayaDecoderCell`` layers with a TIED head: the
+    embedding is one parameter, read by the lookup and by the loss.
+    ``forward(ids (B, S) int)`` -> (final normed states (B, S, units), the
+    embedding's weight (vocab, units)): what ``gluon.loss.TiedHeadCELoss``
+    takes before the label, so that the head's product runs inside the loss,
+    straight into the fused cross-entropy. The first layer's router has no
+    state to mix in; every later one mixes in the layer before's.
+    ``logits(ids)`` gives the logits themselves."""
+
+    def __init__(self, vocab_size, units, hidden_size, num_layers, num_heads,
+                 num_kv_heads, head_dim, num_experts, router_hidden,
+                 experts_held=None, first_expert=0, conv_kernels=(2, 2),
+                 rotary_theta=10000.0, rotary_dim=None, epsilon=1e-5, **kw):
+        super().__init__(**kw)
+        self._vocab, self._units = vocab_size, units
+        with self.name_scope():
+            self.embed_weight = self.params.get(
+                "embed_weight", shape=(vocab_size, units))
+            self.layers = []
+            for i in range(num_layers):
+                cell = ZayaDecoderCell(
+                    units, hidden_size, num_heads, num_kv_heads, head_dim,
+                    num_experts, router_hidden, experts_held=experts_held,
+                    first_expert=first_expert, mixes=i > 0,
+                    conv_kernels=conv_kernels, rotary_theta=rotary_theta,
+                    rotary_dim=rotary_dim, epsilon=epsilon,
+                    prefix=f"layer{i}_")
+                self.register_child(cell, f"layer{i}")
+                self.layers.append(cell)
+            self.norm = RMSNorm(epsilon=epsilon, in_channels=units,
+                                prefix="norm_")
+
+    def hybrid_forward(self, F, ids, embed_weight):
+        h = F.Embedding(ids, embed_weight, input_dim=self._vocab,
+                        output_dim=self._units)
+        state = None
+        for cell in self.layers:
+            h, state = cell(h) if state is None else cell(h, state)
+        return self.norm(h), embed_weight
+
+    def logits(self, ids):
+        """(B, S, vocab), op by op."""
+        from ... import nd
+        states, weight = self(ids)
+        return nd.dot(states, weight, transpose_b=True)
+
+
+def zaya_decoder_lm(vocab_size, units, hidden_size, num_layers, num_heads,
+                    num_kv_heads, head_dim, num_experts, router_hidden,
+                    experts_held=None, first_expert=0, conv_kernels=(2, 2),
+                    rotary_theta=10000.0, rotary_dim=None, epsilon=1e-5, **kw):
+    """A ``ZayaDecoderLM`` from its sizes (a configuration's builder)."""
+    return ZayaDecoderLM(
+        vocab_size, units, hidden_size, num_layers, num_heads, num_kv_heads,
+        head_dim, num_experts, router_hidden, experts_held=experts_held,
+        first_expert=first_expert, conv_kernels=conv_kernels,
+        rotary_theta=rotary_theta, rotary_dim=rotary_dim, epsilon=epsilon,
+        **kw)

@@ -9,7 +9,7 @@ __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
            "SigmoidBCELoss", "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
            "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss", "SquaredHingeLoss",
            "LogisticLoss", "TripletLoss", "CosineEmbeddingLoss",
-           "ExpectedExitCELoss"]
+           "ExpectedExitCELoss", "TiedHeadCELoss"]
 
 
 def _apply_weighting(F, loss, weight=None, sample_weight=None):
@@ -266,5 +266,26 @@ class ExpectedExitCELoss(Loss):
             # gives p = 0, whose term is 0 and not 0 * -inf
             term = p * ce + self._beta * p * F.log(F.maximum(p, 1e-30))
             loss = term if loss is None else loss + term
+        loss = _apply_weighting(F, loss, self._weight, sample_weight)
+        return F.mean(loss, axis=self._batch_axis, exclude=True)
+
+
+class TiedHeadCELoss(Loss):
+    """Mean softmax cross-entropy of a language model whose head is its
+    embedding: takes the final normed states (B, S, units), the embedding's
+    weight (vocab, units) and the label (B, S), as
+    ``gluon.contrib.transformer.ZayaDecoderLM`` hands them over. The head's
+    product runs here, straight into the fused cross-entropy
+    (``F.softmax_cross_entropy(per_row=True)``), as ``ExpectedExitCELoss``
+    does for one exit: the logits are the compute type's and no float32
+    log-softmax of them is ever held."""
+
+    def __init__(self, weight=None, batch_axis=0, **kwargs):
+        super().__init__(weight, batch_axis, **kwargs)
+
+    def hybrid_forward(self, F, states, head_weight, label,
+                       sample_weight=None):
+        logits = F.dot(states, head_weight, transpose_b=True)
+        loss = F.softmax_cross_entropy(logits, label, per_row=True)
         loss = _apply_weighting(F, loss, self._weight, sample_weight)
         return F.mean(loss, axis=self._batch_axis, exclude=True)

@@ -110,12 +110,36 @@ REMAT_KEPT = _m.counter(
     "Values inside recomputed segments that the backward pass keeps besides "
     "the segments' inputs: output 0 of an op registered with product=True "
     "(FullyConnected, Convolution, Deconvolution, dot, batch_dot, "
-    "_contrib_flash_attention) where it is no larger than the op's first "
+    "_contrib_flash_attention, _contrib_moe_experts) where it is no larger "
+    "than the op's first "
     "operand; a product that widens is recomputed with the cheap ops. "
     "Counted per trace of the lowered function, where "
     "mxtpu_remat_segments_total is: a capture of a looped decoder's step "
     "reads segments x 3 (out-projection, down-projection and attention of "
     "a layer-call; qkv, gate and up widen), a graph without segments 0.")
+
+MOE_LOWERED = _m.counter(
+    "mxtpu_moe_lowered_total",
+    "Differentiated traces of the _contrib_moe_experts op, labeled route= by "
+    "what its products lowered to: \"grouped\" (tokens sorted by expert and "
+    "one grouped product a projection, the Mosaic kernels moe_gmm*: a TPU "
+    "process or the interpreter, widths of whole lane blocks that fit in "
+    "VMEM, a batch whose split over devices the op can see) or \"plain\" "
+    "(every held expert over all tokens under a mask: held times the "
+    "products). Counted when the op's gradient is TRACED, as "
+    "mxtpu_pool_bwd_lowered_total is: once per expert layer of a captured "
+    "training step, never per step and never for an inference trace; a "
+    "mixture-of-experts net that reads route=\"plain\" on a TPU fell back "
+    "silently.")
+MOE_EXPERTS_HELD = _m.gauge(
+    "mxtpu_moe_experts_held",
+    "Experts whose weights the most recently traced _contrib_moe_experts op "
+    "was given: this holder's share of mxtpu_moe_experts_routed.")
+MOE_EXPERTS_ROUTED = _m.gauge(
+    "mxtpu_moe_experts_routed",
+    "Experts the router of the most recently traced _contrib_moe_experts op "
+    "chooses among (num_experts=; the held ones where it is not given). A "
+    "token whose expert is not held gets no expert term here.")
 
 # -------------------------------------------------------------------- io
 IO_BATCHES = _m.counter(

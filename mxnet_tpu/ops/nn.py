@@ -18,7 +18,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .registry import register
+from jax.ad_checkpoint import checkpoint_name
+
+from .registry import KEPT_IN_SEGMENT, register
 from ..base import MXNetError
 
 
@@ -553,15 +555,19 @@ def _layer_norm(data, gamma, beta, axis=-1, eps=1e-5, output_mean_var=False):
 
 
 @register("RMSNorm", arg_names=("data", "gamma"))
-def _rms_norm(data, gamma, axis=-1, eps=1e-6):
+def _rms_norm(data, gamma=None, axis=-1, eps=1e-6, no_gain=False):
     """``data / sqrt(mean(data**2) + eps) * gamma`` over ``axis``, the
     statistic and the products in float32 whatever the data's type, the
-    result in the data's type. An op and not a composition because a block
-    under a symbolic trace cannot name the type it has to cast back to."""
+    result in the data's type; ``no_gain=True`` takes no ``gamma`` and leaves
+    the result at a root mean square of 1. An op and not a composition
+    because a block under a symbolic trace cannot name the type it has to
+    cast back to."""
     ax = int(axis) % data.ndim
     xf = data.astype(jnp.float32)
     inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=ax, keepdims=True)
                     + jnp.float32(eps))
+    if no_gain:
+        return (xf * inv).astype(data.dtype)
     g = _per_channel(gamma.astype(jnp.float32), ax, data.ndim)
     return (xf * inv * g).astype(data.dtype)
 
@@ -912,30 +918,260 @@ def _flash_attention_op(query, key, value, causal=False, scale=None,
 
 @register("_contrib_rotary_embedding", aliases=["contrib_rotary_embedding"],
           arg_names=("data",))
-def _rotary_embedding(data, theta=10000.0):
+def _rotary_embedding(data, theta=10000.0, rotary_dim=None):
     """Rotary positions on (..., T, D), half-rotation form: with ``x1, x2``
     the two halves of the last axis, ``[x1 cos - x2 sin, x2 cos + x1 sin]``
     at angle ``position * theta**(-2i/D)``; the position is the index along
-    the second-to-last axis. Angles and products in float32,
+    the second-to-last axis. ``rotary_dim`` = R < D turns the first R
+    channels alone (halves of R/2, angles ``theta**(-2i/R)``) and leaves
+    channels R..D-1 as they are. Angles and products in float32,
     the result in the data's type. An op because the positions are an iota
     of a length a symbolic trace does not know."""
     t, d = data.shape[-2], data.shape[-1]
-    if d % 2:
-        raise MXNetError(f"rotary embedding needs an even last axis, got {d}")
-    half = d // 2
+    r = d if rotary_dim is None else int(rotary_dim)
+    if r % 2 or not 0 < r <= d:
+        raise MXNetError(f"rotary embedding needs an even number of turned "
+                         f"channels within the last axis, got {r} of {d}")
+    half = r // 2
     inv_freq = jnp.float32(theta) ** (
-        -jnp.arange(half, dtype=jnp.float32) * jnp.float32(2.0 / d))
+        -jnp.arange(half, dtype=jnp.float32) * jnp.float32(2.0 / r))
     pos = jnp.arange(t, dtype=jnp.float32)
-    angle = pos[:, None] * inv_freq[None, :]                 # (T, D/2)
+    angle = pos[:, None] * inv_freq[None, :]                 # (T, R/2)
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     # [x1, x2] * [cos, cos] + [x2, x1] * [-sin, sin]: the halves meet by a
     # roll of the whole axis. Joining two computed halves instead makes
     # XLA:TPU write half a lane tile at a time, and its float32 form at
     # (1, 16, 4096, 128) fails a check inside the compiler (PERF.md, PR 31)
     xf = data.astype(jnp.float32)
-    out = xf * jnp.concatenate([cos, cos], axis=-1) \
-        + jnp.roll(xf, half, axis=-1) * jnp.concatenate([-sin, sin], axis=-1)
+    if r == d:
+        out = xf * jnp.concatenate([cos, cos], axis=-1) \
+            + jnp.roll(xf, half, axis=-1) * jnp.concatenate([-sin, sin], axis=-1)
+        return out.astype(data.dtype)
+    # the same without a join: behind channel R the tables are 1 and 0, and
+    # within it x2 comes from a roll to the left, x1 from one to the right
+    still = jnp.zeros((t, d - r), jnp.float32)
+    lane = lax.broadcasted_iota(jnp.int32, (t, d), 1)
+    swapped = jnp.where(lane < half, jnp.roll(xf, -half, axis=-1),
+                        jnp.roll(xf, half, axis=-1))
+    out = xf * jnp.concatenate([cos, cos, still + 1.0], axis=-1) \
+        + swapped * jnp.concatenate([-sin, sin, still], axis=-1)
     return out.astype(data.dtype)
+
+
+# ---------------------------------------------------------------- routed experts
+def top1(scores):
+    """(index, value) of the largest score of each row: the one top-1
+    choice of the routed-experts ops here and of ``parallel.expert_parallel``."""
+    idx = jnp.argmax(scores, axis=-1).astype(jnp.int32)
+    return idx, jnp.take_along_axis(scores, idx[..., None], axis=-1)[..., 0]
+
+
+@register("_contrib_moe_router", aliases=["contrib_moe_router"],
+          num_outputs=3)
+def _moe_router(*arrays):
+    """The router MLP of a layer of top-1 routed experts, in float32 at the
+    highest matmul precision whatever the compute type: (data (..., W),
+    down_weight (R, W), down_bias (R,), w1 (R, R), w2 (R, R), w3 (E, R)
+    [, state (..., R), mix (R,)]) -> (expert (...) int32, gate (...)
+    float32, state (..., R) float32)::
+
+        state = data down_weight^T + down_bias  [+ mix * the layer before's]
+        s = softmax(w3 gelu(w2 gelu(w1 state)));  expert = argmax s
+        gate = s[expert]
+
+    ``state`` is what the next layer's router mixes in. The choice carries no
+    gradient; the gate's value does. A top-1 choice flips where two scores
+    tie within rounding, so nothing here is rounded to the compute type."""
+    data, down_w, down_b, w1, w2, w3 = arrays[:6]
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    mm = lambda a, w: jnp.matmul(  # noqa: E731
+        a, f32(w).T, precision=lax.Precision.HIGHEST)
+    state = mm(f32(data), down_w) + f32(down_b)
+    if len(arrays) == 8:
+        state = state + f32(arrays[7]) * f32(arrays[6])
+    elif len(arrays) != 6:
+        raise MXNetError("_contrib_moe_router takes 6 inputs, or 8 with the "
+                         f"layer before's state and its mix, got {len(arrays)}")
+    hidden = jax.nn.gelu(mm(jax.nn.gelu(mm(state, w1), approximate=False), w2),
+                         approximate=False)
+    expert, gate = top1(jax.nn.softmax(mm(hidden, w3), axis=-1))
+    return expert, gate, state
+
+
+def _moe_sorted(expert, first, held, tile):
+    """Where each token goes when the tokens of the ``held`` experts from
+    ``first`` are sorted by expert, each group padded to whole tiles (an
+    empty one to one tile): (pos (T,) row of each token, the row count where
+    its expert is not held; src (rows,) token of each row, T where the row
+    is padding; tile_group (tiles,), the tiles behind the last group's
+    counted to it). Sizes are data, shapes are not: rows = (ceil(T / tile) +
+    held) * tile."""
+    t = expert.shape[0]
+    tiles = -(-t // tile) + held
+    local = expert.astype(jnp.int32) - jnp.int32(first)
+    here = (local >= 0) & (local < held)
+    onehot = (local[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :]) \
+        .astype(jnp.int32)
+    rank = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot, axis=1) - 1
+    group_tiles = jnp.maximum(1, -(-jnp.sum(onehot, axis=0) // tile))
+    ends = jnp.cumsum(group_tiles)
+    start = (ends - group_tiles)[jnp.clip(local, 0, held - 1)] * tile
+    pos = jnp.where(here, start + rank, tiles * tile).astype(jnp.int32)
+    src = jnp.full((tiles * tile,), t, jnp.int32).at[pos].set(
+        jnp.arange(t, dtype=jnp.int32), mode="drop")
+    tile_group = jnp.minimum(jnp.searchsorted(
+        ends, jnp.arange(tiles, dtype=jnp.int32), side="right"),
+        held - 1).astype(jnp.int32)
+    return pos, src, tile_group
+
+
+def _silu_mul(g, u):
+    """``silu(g) * u`` in float32, rounded once to the operands' type."""
+    gf = g.astype(jnp.float32)
+    return (gf * jax.nn.sigmoid(gf) * u.astype(jnp.float32)).astype(g.dtype)
+
+
+def _moe_plain(x, expert, gate, w_gate, w_up, w_down, first):
+    """The held experts' part of the layer, an expert at a time over ALL
+    tokens under a mask: ``held`` times the grouped form's products, for
+    where its kernels cannot run."""
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(w_gate.shape[0]):
+        h = _silu_mul(jnp.dot(x, w_gate[e].T), jnp.dot(x, w_up[e].T))
+        o = jnp.dot(h, w_down[e].T).astype(jnp.float32)
+        out = out + jnp.where((expert == first + e)[:, None],
+                              o * gate[:, None], 0.0)
+    return out.astype(x.dtype)
+
+
+def _moe_grouped_fwd(x, expert, gate, w_gate, w_up, w_down, first, tile):
+    from . import pallas_kernels as _pk
+    held = w_gate.shape[0]
+    pos, src, group = _moe_sorted(expert, first, held, tile)
+    gmm = functools.partial(_pk.moe_gmm, tile_group=group, tile=tile,
+                            transpose_rhs=True)
+    xs = jnp.take(x, src, axis=0, mode="fill", fill_value=0)
+    # named for a recomputed segment's policy (executor._GraphLowering), as
+    # the attention kernel's residuals are: with these kept a segment's
+    # backward pass runs none of the three forward products again
+    g = checkpoint_name(gmm(xs, w_gate), KEPT_IN_SEGMENT)
+    u = checkpoint_name(gmm(xs, w_up), KEPT_IN_SEGMENT)
+    o = gmm(_silu_mul(g, u), w_down)
+    # a token whose expert is elsewhere reads row ``rows``: out of range, 0
+    og = checkpoint_name(
+        jnp.take(o, pos, axis=0, mode="fill", fill_value=0), KEPT_IN_SEGMENT)
+    out = (og.astype(jnp.float32) * gate[:, None]).astype(x.dtype)
+    return out, (x, gate, pos, src, group, g, u, og, w_gate, w_up, w_down)
+
+
+def _moe_grouped_bwd(tile, res, dy):
+    from . import pallas_kernels as _pk
+    x, gate, pos, src, group, g, u, og, w_gate, w_up, w_down = res
+    held = w_gate.shape[0]
+    plan = dict(tile_group=group, tile=tile)
+    dyf = dy.astype(jnp.float32)
+    d_gate = jnp.sum(dyf * og.astype(jnp.float32), axis=-1)
+    dys = jnp.take((dyf * gate[:, None]).astype(x.dtype), src, axis=0,
+                   mode="fill", fill_value=0)
+    xs = jnp.take(x, src, axis=0, mode="fill", fill_value=0)
+    dh = _pk.moe_gmm(dys, w_down, **plan).astype(jnp.float32)
+    d_down = _pk.moe_tgmm(dys, _silu_mul(g, u), groups=held, **plan)
+    gf, uf = g.astype(jnp.float32), u.astype(jnp.float32)
+    sig = jax.nn.sigmoid(gf)
+    dg = (dh * uf * sig * (1.0 + gf * (1.0 - sig))).astype(x.dtype)
+    du = (dh * gf * sig).astype(x.dtype)
+    dxs = _pk.moe_gmm(dg, w_gate, **plan).astype(jnp.float32) \
+        + _pk.moe_gmm(du, w_up, **plan).astype(jnp.float32)
+    dx = jnp.take(dxs, pos, axis=0, mode="fill", fill_value=0).astype(x.dtype)
+    return (dx, d_gate.astype(gate.dtype),
+            _pk.moe_tgmm(dg, xs, groups=held, **plan),
+            _pk.moe_tgmm(du, xs, groups=held, **plan), d_down)
+
+
+@functools.lru_cache(maxsize=None)
+def _moe_experts_fn(first, route, tile):
+    """The held experts' part of a layer as one differentiable function of
+    (x, gate, w_gate, w_up, w_down) by ``route``: ``grouped`` sorts the
+    tokens by expert and runs each projection as ONE grouped product
+    (``pallas_kernels.moe_gmm``), its backward pass the transposed grouped
+    product for the tokens and the per-group outer product for the weights,
+    every gather a gather (no scatter-add); ``plain`` is ``_moe_plain`` under
+    ``jax.vjp``. Either way a DIFFERENTIATED trace counts one
+    ``mxtpu_moe_lowered_total{route=}``."""
+    def forward(x, expert, gate, w_gate, w_up, w_down):
+        """(the result, what the backward pass needs)."""
+        if route == "grouped":
+            return _moe_grouped_fwd(x, expert, gate, w_gate, w_up, w_down,
+                                    first, tile)
+        return jax.vjp(
+            lambda *a: _moe_plain(a[0], expert, *a[1:], first),
+            x, gate, w_gate, w_up, w_down)
+
+    def fwd(*args):
+        from ..observability import catalog as _catalog, metrics as _metrics
+        if _metrics.enabled():
+            _catalog.MOE_LOWERED.inc(route=route)
+        return forward(*args)
+
+    def bwd(res, dy):
+        dx, d_gate, *d_w = _moe_grouped_bwd(tile, res, dy) \
+            if route == "grouped" else res(dy)
+        return (dx, None, d_gate, *d_w)
+
+    core = jax.custom_vjp(lambda *args: forward(*args)[0])
+    core.defvjp(fwd, bwd)
+    return core
+
+
+@register("_contrib_moe_experts", aliases=["contrib_moe_experts"],
+          arg_names=("data", "expert", "gate", "gate_weight", "up_weight",
+                     "down_weight"), product=True)
+def _moe_experts(data, expert, gate, gate_weight, up_weight, down_weight,
+                 first_expert=0, num_experts=None):
+    """The part of a layer of top-1 routed experts that THIS holder's
+    experts give: data (..., W), expert (...) the expert each token chose
+    among ``num_experts``, gate (...) its gate value, and the stacked weights
+    of the ``held`` experts ``first_expert .. first_expert + held - 1``:
+    gate_weight and up_weight (held, F, W), down_weight (held, W, F)::
+
+        out[t] = gate[t] * down_e(silu(gate_e x[t]) * up_e x[t]),  e = expert[t]
+
+    and 0 for a token whose expert is held elsewhere: the shares of all
+    holders add up to the whole layer. Nothing is dropped and there is no
+    capacity: the tokens are sorted by expert, each projection is one
+    grouped product over the ``held`` groups (sizes are data, an empty group
+    is legal), and the result is put back in the tokens' order. Where the
+    grouped kernels cannot run (no TPU and no interpreter, widths that do not
+    tile, a mesh the op cannot read) the experts run one at a time over all
+    tokens under a mask. Under a named mesh that splits the batch each
+    device runs its own rows (``_pool_batch_split``); the weights are whole
+    on every device."""
+    from . import pallas_kernels as _pk
+    from ..observability import catalog as _catalog, metrics as _metrics
+    held, hidden, width = gate_weight.shape
+    if _metrics.enabled():
+        _catalog.MOE_EXPERTS_HELD.set(held)
+        _catalog.MOE_EXPERTS_ROUTED.set(
+            held if num_experts is None else int(num_experts))
+    x = data.reshape(-1, width)
+    grouped = _pk.moe_gmm_eligible(width, hidden, x.dtype.itemsize) \
+        and x.dtype == gate_weight.dtype and not jax.typeof(x).vma
+    split = _named_batch_split() if grouped else ()
+    if split is None or (split and x.shape[0] % split[0].shape[split[1]]):
+        grouped, split = False, ()
+    core = _moe_experts_fn(int(first_expert),
+                           "grouped" if grouped else "plain",
+                           _pk.MOE_TILE_ROWS)
+    args = (x, expert.reshape(-1).astype(jnp.int32),
+            gate.reshape(-1).astype(jnp.float32),
+            gate_weight, up_weight, down_weight)
+    if split:
+        from jax.sharding import PartitionSpec
+        mesh, axis = split
+        rows, whole = PartitionSpec(axis), PartitionSpec()
+        core = jax.shard_map(core, mesh=mesh, out_specs=rows, check_vma=False,
+                             in_specs=(rows,) * 3 + (whole,) * 3)
+    return core(*args).reshape(data.shape)
 
 
 @register("SVMOutput", arg_names=("data", "label"))

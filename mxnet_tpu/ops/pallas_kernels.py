@@ -51,7 +51,7 @@ from .registry import KEPT_IN_SEGMENT
 __all__ = ["flash_attention", "flash_attention_with_lse",
            "flash_forward_tiles",
            "softmax_cross_entropy", "max_pool_fwd", "max_pool_bwd",
-           "use_pallas"]
+           "moe_gmm", "moe_tgmm", "moe_gmm_eligible", "use_pallas"]
 
 _NEG_INF = -1e30  # avoid actual -inf inside kernels (exp/max corner cases)
 _I0 = np.int32(0)  # index-map zero: a Python 0 is i64 under jax_enable_x64
@@ -754,3 +754,142 @@ def max_pool_bwd(idx, dy, in_hw, kernel, stride, pad_lo):
         [pltpu.VMEM((depth, 1, n_w, cb, nb), dy.dtype),
          pltpu.VMEM((depth, 1, n_w, cb, nb), jnp.int8)] if depth else [],
         dy, idx)
+
+
+# ---------------------------------------------------------------------------
+# grouped matrix products (routed experts over tokens sorted by expert)
+# ---------------------------------------------------------------------------
+#: rows of one tile of the sorted tokens. A group (the tokens of one held
+#: expert) starts at a tile's first row and is padded with zero rows to its
+#: last tile's end, so a tile belongs to ONE group and a product over it is a
+#: plain matrix product against that group's weight; an empty group keeps one
+#: tile of zero rows, and the tiles behind the last group's count to it
+MOE_TILE_ROWS = 512
+_GMM_VMEM_LIMIT = 64 * 1024 * 1024
+_GMM_BLOCK_BYTES = 24 * 1024 * 1024     # the blocks of ``moe_gmm``
+_TGMM_BLOCK_BYTES = 40 * 1024 * 1024    # of ``moe_tgmm``: a float32 accumulator
+_GMM_MAX_CONTRACTION = 4096             # the contraction is one block
+
+
+def _gmm_cols(k, n, itemsize, tile):
+    """Columns of the result a block of ``moe_gmm`` holds: the widest of
+    1024..128 that divides ``n`` and fits beside a (tile, k) block of rows,
+    both double buffered, and the float32 product; None where none does."""
+    for tn in (1024, 512, 256, 128):
+        blocks = 2 * (tile * k + k * tn + tile * tn) * itemsize + tile * tn * 4
+        if n % tn == 0 and blocks <= _GMM_BLOCK_BYTES:
+            return tn
+    return None
+
+
+def _tgmm_cols(k, n, itemsize, tile):
+    """Rows of a group's (n, k) weight gradient a block of ``moe_tgmm``
+    holds, by the same rule."""
+    for tn in (1024, 512, 256, 128):
+        blocks = (2 * (tile * tn + tile * k) * itemsize + tn * k * 4
+                  + 2 * tn * k * itemsize)
+        if n % tn == 0 and blocks <= _TGMM_BLOCK_BYTES:
+            return tn
+    return None
+
+
+def moe_gmm_eligible(width, hidden, itemsize, tile=MOE_TILE_ROWS):
+    """Whether the three kernels take an expert layer of these widths: whole
+    lane blocks, a contraction that is one block, blocks that fit in VMEM in
+    both directions (the forward contracts over ``width`` and ``hidden``, the
+    backward over the other of each pair)."""
+    return (use_pallas() and width % 128 == 0 and hidden % 128 == 0
+            and max(width, hidden) <= _GMM_MAX_CONTRACTION and tile % 8 == 0
+            and all(_gmm_cols(a, b, itemsize, tile) is not None
+                    and _tgmm_cols(a, b, itemsize, tile) is not None
+                    for a, b in ((width, hidden), (hidden, width))))
+
+
+def _gmm_kernel(group_ref, lhs_ref, rhs_ref, out_ref, *, dims):
+    del group_ref       # the index maps read it
+    out_ref[...] = lax.dot_general(
+        lhs_ref[...], rhs_ref[0], dims,
+        preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+
+def moe_gmm(lhs, rhs, tile_group, transpose_rhs=False, tile=MOE_TILE_ROWS):
+    """``out[r] = lhs[r] @ rhs[g]`` (``rhs[g].T`` with ``transpose_rhs``) for
+    every row ``r`` of the tiles of group ``g``: lhs (tiles * tile, k) sorted
+    by group as ``MOE_TILE_ROWS`` says, rhs (groups, k, n) or (groups, n, k),
+    ``tile_group`` (tiles,) int32 the group of each tile in order. Group
+    sizes are data; the grid is not: every tile is multiplied, the rows that
+    hold no token are zeros, so a step's time does not follow the routing. A
+    grid over the tiles in use alone is the faster form (PERF.md 7.3(b))."""
+    m, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tn = _gmm_cols(k, n, lhs.dtype.itemsize, tile)
+    dims = (((1,), (1,)), ((), ())) if transpose_rhs \
+        else (((1,), (0,)), ((), ()))
+    with jax.enable_x64(False):
+        if transpose_rhs:
+            rhs_spec = pl.BlockSpec((1, tn, k),
+                                    lambda j, i, group: (group[i], j, 0))
+        else:
+            rhs_spec = pl.BlockSpec((1, k, tn),
+                                    lambda j, i, group: (group[i], 0, j))
+        return pl.pallas_call(
+            functools.partial(_gmm_kernel, dims=dims),
+            name="moe_gmm_t" if transpose_rhs else "moe_gmm",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(n // tn, m // tile),
+                in_specs=[pl.BlockSpec((tile, k), lambda j, i, group: (i, 0)),
+                          rhs_spec],
+                out_specs=pl.BlockSpec((tile, tn), lambda j, i, group: (i, j))),
+            out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype, **_vma_kw(lhs)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_GMM_VMEM_LIMIT),
+            interpret=_interpret())(tile_group, lhs, rhs)
+
+
+def _tgmm_kernel(group_ref, dy_ref, x_ref, out_ref, acc_ref):
+    i, last = pl.program_id(1), pl.num_programs(1) - 1
+    g = group_ref[i]
+    opens = jnp.logical_or(i == 0, group_ref[jnp.maximum(i - 1, 0)] != g)
+    closes = jnp.logical_or(i == last,
+                            group_ref[jnp.minimum(i + 1, last)] != g)
+
+    @pl.when(opens)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += lax.dot_general(
+        dy_ref[...], x_ref[...], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(closes)
+    def _():
+        out_ref[0] = acc_ref[...].astype(out_ref.dtype)
+
+
+def moe_tgmm(dy, x, tile_group, groups, tile=MOE_TILE_ROWS):
+    """``out[g] = dy[rows of g].T @ x[rows of g]``, (groups, n, k): each
+    group's weight gradient in the weight's own (out, in) layout, from dy
+    (rows, n) and x (rows, k) sorted as for ``moe_gmm``. A group's tiles are
+    consecutive and every group has one, so every block of the result is
+    written once; rows that hold no token are zero on both sides."""
+    m, n = dy.shape
+    k = x.shape[1]
+    tn = _tgmm_cols(k, n, x.dtype.itemsize, tile)
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            _tgmm_kernel, name="moe_gmm_dw",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(n // tn, m // tile),
+                in_specs=[
+                    pl.BlockSpec((tile, tn), lambda j, i, group: (i, j)),
+                    pl.BlockSpec((tile, k), lambda j, i, group: (i, 0))],
+                out_specs=pl.BlockSpec((1, tn, k),
+                                       lambda j, i, group: (group[i], j, 0)),
+                scratch_shapes=[pltpu.VMEM((tn, k), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((groups, n, k), x.dtype,
+                                           **_vma_kw(x)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_GMM_VMEM_LIMIT),
+            interpret=_interpret())(tile_group, dy, x)

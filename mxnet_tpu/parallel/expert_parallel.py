@@ -1,18 +1,26 @@
-"""Expert parallelism — Mixture-of-Experts with token dispatch over the
-'ep' mesh axis.
+"""Expert parallelism — the exchange over the 'ep' mesh axis of a layer of
+top-1 routed experts.
 
-Absent from the reference (SURVEY.md §2.3 "Expert parallelism: Absent");
-built first-class here because EP is how modern long-context/distributed
-workloads scale FFN capacity. TPU-native shape: experts live one (or more)
-per device along 'ep'; tokens route to their expert via ONE all_to_all,
-run the expert FFN as dense batched matmuls on the MXU, and return via a
-second all_to_all. Capacity-factor truncation keeps every shape static for
-XLA; dropped tokens fall back to the residual path (standard Switch-style
-behavior).
+Absent from the reference (SURVEY.md §2.3 "Expert parallelism: Absent").
+The layer itself lives in ``ops/nn.py``: ``_contrib_moe_router`` chooses,
+``_contrib_moe_experts`` is told which experts its holder has and computes
+their part of the result for ALL tokens it is given, dropless, as a grouped
+product over tokens sorted by expert (``gluon.contrib.transformer.
+RoutedExperts`` on the ``gluon`` path; the benchmark's ``zaya1_8b.train``
+runs one holder's share of such a layer on one chip, with no exchange and
+nothing standing in for it). This module is the mesh half: what moves
+tokens between holders when experts live one (or more) per device along
+'ep'. It has ONE all_to_all out and one back around dense per-expert
+matmuls, with a CAPACITY so that every shape is static: tokens past an
+expert's capacity fall back to the residual path (Switch-style). The top-1
+choice is the ops' own (``ops.nn.top1``): one routing code, not two. A
+dropless exchange (all_gather of the tokens' routes, then each device's
+``_contrib_moe_experts`` over the gathered tokens and a reduce_scatter of
+the parts) is what the four-chip cell of this layer needs and is not built
+yet (ROADMAP R3).
 
 Surfaces mirror tensor_parallel.py:
-- ``moe_dispatch``/``moe_combine``/``ep_moe_ffn`` — functional pieces for
-  use INSIDE shard_map regions (axis_name = 'ep');
+- ``ep_moe_ffn`` — for use INSIDE shard_map regions (axis_name = 'ep');
 - ``MoEParams.init`` + ``moe_ffn_reference`` — a single-device reference
   implementation tests compare the sharded path against.
 """
@@ -56,11 +64,10 @@ class MoEParams(NamedTuple):
 
 
 def top1_gate(x, w_gate):
-    """Switch-style top-1 gating: (expert id, gate probability) per token."""
-    logits = jnp.einsum("td,de->te", x, w_gate)
-    probs = jax.nn.softmax(logits, axis=-1)
-    idx = jnp.argmax(probs, axis=-1)
-    return idx, jnp.take_along_axis(probs, idx[:, None], axis=1)[:, 0]
+    """Switch-style top-1 gating: (expert id, gate probability) per token,
+    chosen as the routed-experts ops choose (``ops.nn.top1``)."""
+    from ..ops.nn import top1
+    return top1(jax.nn.softmax(jnp.einsum("td,de->te", x, w_gate), axis=-1))
 
 
 def _expert_ffn(w1, b1, w2, b2, tokens):

@@ -65,6 +65,9 @@ def _invoke_sym(op_name: str, sym_inputs: List[Symbol], kwargs: Dict[str, Any]) 
                 if op_name == "LeakyReLU" and an == "gamma" \
                         and attrs.get("act_type", "leaky") != "prelu":
                     continue
+                if op_name == "RMSNorm" and an == "gamma" \
+                        and attrs.get("no_gain"):
+                    continue
                 vnode = _Node(None, f"{name}_{an}", {}, [])
                 final.append((vnode, 0))
         entries = final

@@ -337,6 +337,94 @@ def test_looped_decoder_cell_step_fits_and_keeps_its_kernels_on_v5e(
     assert kinds and set(kinds) == {"bf16"}, sorted(set(kinds))
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_routed_experts_op_under_a_named_mesh_on_v5e(
+        four_chips, no_compile_cache, monkeypatch, chips, dtype):
+    """The expert layer of ``zaya1_8b.train`` at its widths (2,048 x 2,048,
+    8 experts held of 16), differentiated under the mesh the trainer names:
+    three grouped products forward, three transposed and three per-group
+    outer products backward, all Mosaic kernels; on a ``dp`` mesh of four
+    each chip sorts and multiplies its own rows (``shard_map``) against the
+    whole held weights. float32 is what the capture's op-by-op forward
+    compiles."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from mxnet_tpu.ops import get_op
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    experts = get_op("_contrib_moe_experts").fn
+
+    def loss(x, gate, w_gate, w_up, w_down, expert):
+        out = experts(x, expert, gate, w_gate, w_up, w_down, first_expert=0,
+                      num_experts=16)
+        return jnp.sum(out.astype(jnp.float32))
+
+    mesh = Mesh(np.array(four_chips[:chips]), ("dp",))
+    rows = NamedSharding(mesh, PartitionSpec("dp"))
+    whole = NamedSharding(mesh, PartitionSpec())
+    x = jax.ShapeDtypeStruct((4, 2048, 2048), dtype, sharding=rows)
+    gate = jax.ShapeDtypeStruct((4, 2048), jnp.float32, sharding=rows)
+    expert = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=rows)
+    w = jax.ShapeDtypeStruct((8, 2048, 2048), dtype, sharding=whole)
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), x, gate, w,
+                        w, w, expert)
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+
+
+def test_routed_experts_cell_step_fits_and_keeps_its_kernels_on_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """The cell ``zaya1_8b.train``'s own step (``harness.build_program``, two
+    rows of 8,192 tokens, SGD momentum, bfloat16), compiled for a described
+    v5e. Each of its 4 layers is a recomputed segment; every expert layer
+    takes the grouped route (4 differentiated traces) and every attention
+    forward the Pallas kernel. The step takes 8.5 to 10.5 GB, of which 3.96
+    GB are its arguments; its Pallas calls are the 4 attention forwards and
+    36 grouped products: 12 forward (a segment keeps their results: its
+    backward pass runs none of them again), 12 for the tokens' gradient and
+    12 for the weights'. The logits are bfloat16 and no float32 buffer of their
+    size stands in the step. Weights are zeros (shapes are all a compile
+    reads)."""
+    import re
+    sys.path.insert(0, REPO)
+    from chipbench import harness
+    from mxnet_tpu.observability import catalog
+    bench = harness.load_json(REPO, "BENCHMARK.json")
+    _cell, cfg, _mix, _limits, ref = harness.find_cell(bench, "zaya1_8b.train")
+    monkeypatch.setattr(mx.init, "Xavier", mx.init.Zero)
+    monkeypatch.setattr(ref, "init", lambda c, k: [
+        jnp.zeros(shape, jnp.float32) for _k, shape, _t in ref.leaf_specs(c)])
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    net, trainer, _mesh, _t = harness.build_program(cfg, ref, 1, jax.devices()[:1])
+    grouped = catalog.MOE_LOWERED.value(route="grouped")
+    trainer._capture(2, sample_arrays=None)
+    spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    state = jax.tree_util.tree_map(spec, (
+        trainer._params, trainer._aux, trainer._opt_state, trainer._guard_state))
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((cfg["batch_per_chip"], cfg["seq_len"]),
+                               jnp.int32, sharding=one_chip)
+    step = jax.jit(trainer._step_fn.__wrapped__, donate_argnums=(0, 1, 2, 3))
+    compiled = step.lower(*state, rng, ids, ids).compile()
+    assert catalog.MOE_LOWERED.value(route="grouped") - grouped == \
+        cfg["num_hidden_layers"]
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 8.5e9 < peak < 10.5e9, peak
+    text = compiled.as_text()
+    assert "f64[" not in text
+    layers = cfg["num_hidden_layers"]
+    assert text.count('custom_call_target="tpu_custom_call"') == layers * 10
+    for name, calls in (("moe_gmm_t", 3), ("moe_gmm_dw", 3)):
+        assert len(re.findall(r"%%(?:jvp_)?%s[_.\d]* = " % name, text)) \
+            == calls * layers, name
+    entry = text[text.index("ENTRY"):].split("\n", 1)[1]
+    logits = re.compile(r"= (\w+)\[(?:\d,)?%d,%d\]" % (
+        cfg["batch_per_chip"] * cfg["seq_len"], cfg["vocab_held"]))
+    kinds = [m.group(1) for m in map(logits.search, entry.splitlines()) if m]
+    assert kinds and set(kinds) == {"bf16"}, sorted(set(kinds))
+
+
 def test_rtc_does_not_choose_interpret_mode(monkeypatch):
     """No chip and no request for the interpreter: the launch fails, it does
     not quietly run the kernel on the host."""

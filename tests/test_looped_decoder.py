@@ -289,7 +289,7 @@ def test_the_ops_that_call_themselves_products():
     from mxnet_tpu.ops.registry import _REGISTRY
     assert {op.name for op in _REGISTRY.values() if op.product} == {
         "FullyConnected", "dot", "batch_dot", "Convolution", "Deconvolution",
-        "_contrib_flash_attention"}
+        "_contrib_flash_attention", "_contrib_moe_experts"}
 
 
 @pytest.mark.parametrize("hidden,kept", [(4, 1), (8, 1), (9, 0), (24, 0)])

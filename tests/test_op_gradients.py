@@ -218,6 +218,16 @@ SPEC = {
     "_contrib_flash_attention": dict(
         inputs=[u(1, 1, 4, 4), u(1, 1, 4, 4), u(1, 1, 4, 4)],
         tol=dict(rtol=3e-2, atol=3e-3)),
+    # routed experts: the expert each token chose is pinned (an integer
+    # choice); tokens 2 of 6 chose an expert held elsewhere
+    "_contrib_moe_experts": dict(
+        inputs=[u(6, 4), u(6, low=0.2, high=1.0), u(2, 3, 4), u(2, 3, 4),
+                u(2, 4, 3)],
+        fixed={1: const(np.array([0, 1, 3, 0, 1, 2], "float32"))},
+        attrs={"first_expert": 0, "num_experts": 4}),
+    "_contrib_moe_router": dict(
+        skip="an argmax and an integer output: the gate's gradient is held "
+             "to the plain reference in test_zaya_decoder"),
     "_contrib_fft": dict(inputs=[u(2, 8)]),
     "_contrib_ifft": dict(inputs=[u(2, 16)]),
     "_contrib_count_sketch": dict(
