@@ -1,7 +1,12 @@
-"""The convolution/dot kernels' share of their roofline: the least time the
-chip could take for the FLOPs the step's convolutions and dense layers require
-(compute-bound at these shapes) over the device time per step of the trace
-events in XLA's convolution categories, fused epilogues included."""
+"""The products' share of their roofline: the least time the chip could take
+for the FLOPs the step's products REQUIRE (the family's reference: every
+convolution and dense layer, attention's scores and values, the held experts;
+compute-bound at these shapes; nothing recomputed counts) over the device
+time per step of the events that do products, whatever implements them
+(``trace.is_product``): XLA's convolution categories, fused epilogues
+included, and every Pallas custom call but the bandwidth kernels that
+``trace.BANDWIDTH_KERNELS`` names. Each event counts by its self time, so a
+``while`` around a scan's products counts them once."""
 
 
 def read(run):

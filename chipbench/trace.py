@@ -19,6 +19,17 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 HOST_PLANE = "/host:CPU"
 # TPU XLA turns dots into convolutions: both land in these categories.
 MATMUL_CATEGORIES = ("convolution", "convolution fusion")
+# A Pallas kernel is a custom call. Each counts as products unless its name
+# (as ``ops/pallas_kernels.py`` gives it, behind JAX's ``jvp_`` where the
+# kernel is differentiated) begins with one of these kernels, which do no
+# products: a new one that does none is added here by a ``benchmark`` PR.
+CUSTOM_CALL = "custom-call"
+BANDWIDTH_KERNELS = ("max_pool_fwd", "max_pool_bwd", "cross_entropy_lse")
+# XLA's own custom calls (``X64SplitLow``, ``X64SplitHigh``, ``X64Combine``
+# in every cell's trace) name another target in the event's HLO text: no
+# kernel, no products. A text that names no target counts as a kernel.
+TARGET = "custom_call_target="
+PALLAS_TARGET = TARGET + '"tpu_custom_call"'
 COLLECTIVE = re.compile(
     r"\b(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)")
 
@@ -172,16 +183,54 @@ def subtract(a, b):
     return out
 
 
+def self_times(events):
+    """Each event's duration less the union of the events of the same line
+    that it encloses: a ``while``, ``conditional`` or ``call`` holds the ops
+    of its body, and each of those counts once, by itself. Of two events
+    that overlap without one enclosing the other, each keeps its whole
+    duration; of two with the same interval, the first encloses the second."""
+    own = [d for _s, d, _m in events]
+    inner, open_ = {}, []   # enclosing event -> enclosed intervals; a chain
+    for i in sorted(range(len(events)),
+                    key=lambda i: (events[i][0], -events[i][1])):
+        s, d, _m = events[i]
+        while open_ and open_[-1][1] < s + d:
+            open_.pop()
+        if open_:
+            inner.setdefault(open_[-1][0], []).append((s, s + d))
+        open_.append((i, s + d))
+    for i, intervals in inner.items():
+        own[i] -= total(union(intervals))
+    return own
+
+
 # ------------------------------------------------------------- reduction
 def _short(hlo_name):
     return hlo_name.split(" = ")[0].lstrip("%")[:64]
+
+
+def is_product(hlo_text, category):
+    """Whether an event does the step's products: XLA's convolution
+    categories (fused epilogues included) and every Pallas kernel but the
+    bandwidth kernels."""
+    if category in MATMUL_CATEGORIES:
+        return True
+    if category != CUSTOM_CALL or (TARGET in hlo_text
+                                   and PALLAS_TARGET not in hlo_text):
+        return False
+    name = _short(hlo_text)
+    return not (name[4:] if name.startswith("jvp_") else name).startswith(
+        BANDWIDTH_KERNELS)
 
 
 def reduce(planes, step_module="jit_train_step", spans=()):
     """Device numbers of one traced window.
 
     Per device: busy_ps (union of its op intervals), per-step time by
-    category for the runs of ``step_module``, exposed collective time.
+    category for the runs of ``step_module`` (each event by its self time,
+    so the categories sum to no more than busy_ps), exposed collective time.
+    ``matmul_s_per_step`` is the products' time (``is_product``),
+    ``other_s_per_step`` every other non-collective event's.
     ``spans`` names host annotations (the program's spans); each long idle
     gap is attributed to those open at its midpoint.
     """
@@ -195,8 +244,8 @@ def reduce(planes, step_module="jit_train_step", spans=()):
         mods = [e for e in p["lines"].get("XLA Modules", [])
                 if meta.get(e[2], ("",))[0].startswith(step_module)]
         busy = union((s, s + d) for s, d, _ in ops)
-        by_cat, by_op, coll, other = {}, {}, [], []
-        for s, d, mid in ops:
+        by_cat, by_op, coll, other, products = {}, {}, [], [], 0
+        for (s, d, mid), own in zip(ops, self_times(ops)):
             nm, cat = meta.get(mid, ("", None))
             if COLLECTIVE.search(nm) or COLLECTIVE.search(cat or ""):
                 cat = "collective"
@@ -204,9 +253,11 @@ def reduce(planes, step_module="jit_train_step", spans=()):
             else:
                 other.append((s, s + d))
             cat = cat or "uncategorised"
-            by_cat[cat] = by_cat.get(cat, 0) + d
+            by_cat[cat] = by_cat.get(cat, 0) + own
             key = (_short(nm), cat)
-            by_op[key] = by_op.get(key, 0) + d
+            by_op[key] = by_op.get(key, 0) + own
+            if is_product(nm, cat):
+                products += own
         # the collectives' asynchronous spans: start to done
         for s, d, mid in p["lines"].get("Async XLA Ops", []):
             if COLLECTIVE.search(meta.get(mid, ("",))[0]):
@@ -216,22 +267,21 @@ def reduce(planes, step_module="jit_train_step", spans=()):
             "name": p["name"], "steps": len(mods),
             "step_ps": sorted(d for _, d, _ in mods),
             "busy": busy, "busy_ps": total(busy), "by_cat": by_cat,
-            "by_op": by_op, "collective_ps": total(union(coll)),
+            "by_op": by_op, "products_ps": products,
+            "collective_ps": total(union(coll)),
             "exposed_collective_ps": total(exposed)})
     if not devices:
         return None
-    matmul = lambda d: sum(v for k, v in d["by_cat"].items()  # noqa: E731
-                           if k in MATMUL_CATEGORIES)
     out = {"devices": devices, "n_devices": len(devices)}
     fullest = max(devices, key=lambda d: d["busy_ps"])
     steps = max(fullest["steps"], 1)
     out["steps"] = fullest["steps"]
     out["busy_s"] = sum(d["busy_ps"] for d in devices) / len(devices) / 1e12
     out["busy_fullest_s"] = fullest["busy_ps"] / 1e12
-    out["matmul_s_per_step"] = matmul(fullest) / steps / 1e12
+    out["matmul_s_per_step"] = fullest["products_ps"] / steps / 1e12
     out["other_s_per_step"] = (sum(v for k, v in fullest["by_cat"].items()
                                    if k != "collective")
-                               - matmul(fullest)) / steps / 1e12
+                               - fullest["products_ps"]) / steps / 1e12
     out["exposed_collective_s_per_step"] = max(
         d["exposed_collective_ps"] / max(d["steps"], 1) for d in devices) / 1e12
     out["collective_s_per_step"] = max(
