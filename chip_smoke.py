@@ -11,9 +11,10 @@ the full width of ResNet-50 (random weights from ``--seed``), in ONE process:
               ModelConfig (buckets 1/8/32): answers match a direct hybridized
               forward, executor on the TPU device
   kernels     nd.contrib.flash_attention (B*H=16, T=2048, D=128, bf16, causal;
-              forward and gradient; again at (16, 4096, 128) under
-              jax.checkpoint with a recomputed segment's policy: one forward
-              kernel, the plain call's gradients) and the
+              the forward kernel and the backward kernel; again at
+              (16, 4096, 128) under jax.checkpoint with a recomputed
+              segment's policy: one forward kernel, the backward kernel,
+              the plain call's gradients) and the
               softmax_cross_entropy op at (4096, 32768) bf16, each against its
               jnp reference and each shown to lower to a Pallas kernel
               (``tpu_custom_call``)
@@ -37,6 +38,7 @@ import argparse
 import functools
 import json
 import os
+import re
 import sys
 import tempfile
 import time
@@ -301,10 +303,11 @@ def phase_kernels(cfg, seed, dev, on_tpu):
         "_contrib_flash_attention", normalize_attrs({"causal": True})
     ).lower(q._data, k._data, v._data))
 
-    # ---- the same kernel inside a recomputed segment: under jax.checkpoint
+    # ---- the same kernels inside a recomputed segment: under jax.checkpoint
     # with the lowering's policy the named residuals (out, log-sum-exp) are
-    # kept through Mosaic's custom call, the forward kernel runs once, and
-    # the gradients are those of the plain call
+    # kept through Mosaic's custom call, the forward kernel runs once, the
+    # backward pass is the backward kernel (no scan), and the gradients are
+    # those of the plain call
     from mxnet_tpu.ops.registry import KEPT_IN_SEGMENT
     qs, ks, vs, ws = [a._data for a in (
         mx.nd.random_normal(shape=cfg["fa_kept"], dtype=dt) for _ in range(4))]
@@ -314,8 +317,12 @@ def phase_kernels(cfg, seed, dev, on_tpu):
     kept = jax.jit(jax.grad(jax.checkpoint(
         seg_loss, policy=jax.checkpoint_policies.save_only_these_names(
             KEPT_IN_SEGMENT)), argnums=(0, 1, 2))).lower(qs, ks, vs).compile()
-    kept_calls = kept.as_text().count('custom_call_target="tpu_custom_call"')
-    assert kept_calls == (1 if on_tpu else 0), kept_calls
+    kept_text = kept.as_text()
+    kept_calls = {name: len(re.findall(r"%%\w*%s[_.\d]* = " % name,
+                                       kept_text))
+                  for name in ("flash_attention_fwd", "flash_attention_bwd")}
+    assert set(kept_calls.values()) == {1 if on_tpu else 0}, kept_calls
+    assert (" while(" in kept_text) != on_tpu, "the backward is a scan"
     plain = jax.jit(jax.grad(seg_loss, argnums=(0, 1, 2)))(qs, ks, vs)
     kept_err = {n: rel_err(g, p) for n, g, p in
                 zip(("dq", "dk", "dv"), kept(qs, ks, vs), plain)}
@@ -349,18 +356,18 @@ def phase_kernels(cfg, seed, dev, on_tpu):
     # float32 at the highest precision
     from mxnet_tpu.ops import nn as ops_nn
     from mxnet_tpu.ops.registry import get_op
-    T, W, F, held = cfg["moe"]
+    n_tok, W, F, held = cfg["moe"]
     mdt = jnp.dtype(cfg["moe_dtype"])
     rs = np.random.RandomState(seed + 1)
     load = rs.dirichlet(np.full(2 * held, 0.7))
     load[1] = 0.0                                   # expert 1 gets no token
-    expert = jnp.asarray(rs.choice(2 * held, T, p=load / load.sum()),
+    expert = jnp.asarray(rs.choice(2 * held, n_tok, p=load / load.sum()),
                          jnp.int32)
-    xm = jnp.asarray(rs.randn(T, W), mdt)
-    gate_m = jnp.asarray(rs.uniform(0.1, 1.0, T), jnp.float32)
+    xm = jnp.asarray(rs.randn(n_tok, W), mdt)
+    gate_m = jnp.asarray(rs.uniform(0.1, 1.0, n_tok), jnp.float32)
     wm = [jnp.asarray(rs.randn(held, *sh) / np.sqrt(sh[1]), mdt)
           for sh in ((F, W), (F, W), (W, F))]
-    ym = jnp.asarray(rs.randn(T, W), jnp.float32)
+    ym = jnp.asarray(rs.randn(n_tok, W), jnp.float32)
     moe_op = functools.partial(get_op("_contrib_moe_experts").fn,
                                first_expert=0, num_experts=2 * held)
     def moe_loss(fn, x, g, *w):
@@ -396,13 +403,13 @@ def phase_kernels(cfg, seed, dev, on_tpu):
 
     placed = on_device([out._data, q.grad._data, logits._data], [dev])
     emit(phase="kernels", ok=True, routed_experts=dict(
-        shape=[T, W, F, held], dtype=str(mdt), pallas=moe_pallas,
+        shape=[n_tok, W, F, held], dtype=str(mdt), pallas=moe_pallas,
         tokens_per_held_expert=counts.tolist(), rel_err=moe_err,
         first_call_s=round(moe_s, 2), forward_and_gradient_ms=round(moe_ms, 3)),
         flash_attention=dict(
         shape=[B * H, T, D], dtype=dt, rel_err=fa_err, pallas=fa_pallas,
         seconds=round(fa_s, 2)), flash_attention_in_segment=dict(
-        shape=list(cfg["fa_kept"]), dtype=dt, forward_kernels=kept_calls,
+        shape=list(cfg["fa_kept"]), dtype=dt, kernels=kept_calls,
         rel_err_to_plain=kept_err, seconds=round(kept_s, 2)),
         softmax_cross_entropy=dict(
         shape=[N, C], dtype=cfg["ce_dtype"], rel_err=ce_err, value=got_ce,

@@ -94,6 +94,16 @@ FLASH_ATTENTION_LOWERED = _m.counter(
     "step; a long-context net that reads route=\"xla\" on a TPU fell back "
     "silently and pays for the scores.")
 
+FLASH_ATTENTION_BWD_LOWERED = _m.counter(
+    "mxtpu_flash_attention_bwd_lowered_total",
+    "Traces of flash attention's BACKWARD pass, labeled route= by what it "
+    "lowered to: \"pallas\" (the Mosaic kernel flash_attention_bwd, where "
+    "the forward ran its kernel and a row's dq fits in VMEM) or \"scan\" "
+    "(the blockwise lax.scan in float32, every other shape). "
+    "Counted once per differentiated trace: a capture of a decoder's step "
+    "reads its attention calls, never steps; a net whose pallas count "
+    "stays 0 on a TPU runs the scan.")
+
 REMAT_SEGMENTS = _m.counter(
     "mxtpu_remat_segments_total",
     "Segments of a symbol graph lowered as one function under "

@@ -10,11 +10,15 @@ otherwise materialize the T×T score matrix in HBM.
 
 Kernels:
 * ``flash_attention``      — O(T·block) memory attention, fwd in Pallas with a
-                             per-row log-sum-exp side output; bwd is a
-                             blockwise ``lax.scan`` (recompute, never holds a
-                             full T×T block). Used by ``parallel.ring_attention``
-                             as the per-ring-step partial, and exposed as
-                             ``mx.nd.contrib.flash_attention``.
+                             per-row log-sum-exp side output; bwd in Pallas
+                             where the fwd ran there (``flash_attention_bwd``:
+                             recomputes p from the log-sum-exp, dk and dv a
+                             key block, dq summed in VMEM for the row, blocks
+                             above the diagonal skipped), else a blockwise
+                             ``lax.scan``; neither holds a full T×T block.
+                             Used by ``parallel.ring_attention`` as the
+                             per-ring-step partial (its backward, the scan),
+                             and exposed as ``mx.nd.contrib.flash_attention``.
 * ``softmax_cross_entropy`` — row-fused logsumexp - logit[label], no
                              materialized softmax; grad is the classic
                              ``softmax - onehot`` (fused by XLA, the label's
@@ -29,7 +33,8 @@ path runs. All three paths share one numerics contract and one test suite.
 The package turns ``jax_enable_x64`` on, under which a bare Python ``0`` in a
 BlockSpec index map traces as i64 and Mosaic refuses the kernel. Every
 integer an index map returns is therefore spelled ``_I0`` / built from the
-i32 grid indices; tests/test_chip_bringup.py compiles both kernels for the
+i32 grid indices with ``lax`` integer ops (``jnp``'s floor division does
+not lower there); tests/test_chip_bringup.py compiles the kernels for the
 described v5e with the package imported.
 """
 from __future__ import annotations
@@ -262,9 +267,194 @@ def _flash_core_fwd(q, k, v, scale, causal, q_offset, k_offset, block_k,
     return out, (q, k, v, out, lse)
 
 
+_FA_BWD_BLOCK = 1024               # rows of q and of k a backward step holds
+_FA_BWD_VMEM_LIMIT = 64 << 20      # of v5e's 128 MiB; the default scope is 16
+_FA_BWD_ROW_BYTES = 32 << 20       # a row's dq: its float32 sum and its result
+
+
+def _fa_bwd_fits(q) -> bool:
+    """Whether one row's dq (a (T, D) float32 sum and the double-buffered
+    result in q's type) fits the backward kernel's VMEM: T up to 32,768 at
+    D = 128 in bfloat16, 21,504 in float32. A longer row keeps the scan."""
+    t = -(-q.shape[-2] // _FA_BWD_BLOCK) * _FA_BWD_BLOCK
+    return t * q.shape[-1] * (4 + 2 * q.dtype.itemsize) <= _FA_BWD_ROW_BYTES
+
+
+def _fa_bwd_kernel(offs_ref, k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
+                   dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc, *, scale,
+                   causal, block_q, block_k, nq_total, nk_total, tq_total,
+                   tk_total):
+    """Grid (BH, nK, nQ); q is the innermost (sequential) axis, and one row
+    (bh) is the whole of both others. The scores are held transposed,
+    (bk, bq), so the row log-sum-exp and ``delta`` of the queries arrive as
+    (1, bq) rows and broadcast over sublanes: dv += pᵀ·dO and dk += dsᵀ·q
+    are plain products, dq[q block] += (dsᵀ)ᵀ·k contracts the key axis of
+    both. dk and dv accumulate in float32 scratch and are written at the last
+    q step; dq accumulates for the whole row in float32 scratch and is
+    written once, at the row's last step. ``scale`` multiplies dk and dq
+    there, once."""
+    ik, iq = pl.program_id(1), pl.program_id(2)
+    scale = jnp.float32(scale)
+
+    @pl.when((ik == 0) & (iq == 0))
+    def _init_row():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(iq == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    q0 = iq * block_q + offs_ref[0]
+    k0 = ik * block_k + offs_ref[1]
+    q_ragged = tq_total % block_q != 0
+    k_ragged = tk_total % block_k != 0
+
+    def block(masked):
+        k, v = k_ref[0], v_ref[0]                        # (bk, D)
+        q, do = q_ref[0], do_ref[0]                      # (bq, D)
+        lse, delta = lse_ref[0], delta_ref[0]            # (1, bq)
+        # the grid pads the last block of each axis: its rows may hold
+        # anything, and three of the five products sum over them
+        if q_ragged:
+            qrow = lax.broadcasted_iota(jnp.int32, q.shape, 0) + iq * block_q
+            q = jnp.where(qrow < tq_total, q, jnp.zeros_like(q))
+            do = jnp.where(qrow < tq_total, do, jnp.zeros_like(do))
+            qcol = lax.broadcasted_iota(jnp.int32, delta.shape, 1) \
+                + iq * block_q
+            delta = jnp.where(qcol < tq_total, delta, jnp.float32(0.0))
+        if k_ragged:
+            krow = lax.broadcasted_iota(jnp.int32, k.shape, 0) + ik * block_k
+            k = jnp.where(krow < tk_total, k, jnp.zeros_like(k))
+            v = jnp.where(krow < tk_total, v, jnp.zeros_like(v))
+        st = lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)   # (bk, bq)
+        pt = jnp.exp(st * scale - lse)
+        keep = []
+        if q_ragged:
+            keep.append(lax.broadcasted_iota(jnp.int32, pt.shape, 1)
+                        + iq * block_q < tq_total)
+        if k_ragged:
+            keep.append(lax.broadcasted_iota(jnp.int32, pt.shape, 0)
+                        + ik * block_k < tk_total)
+        if masked:
+            kpos = lax.broadcasted_iota(jnp.int32, pt.shape, 0) + k0
+            qpos = lax.broadcasted_iota(jnp.int32, pt.shape, 1) + q0
+            keep.append(qpos >= kpos)
+        if keep:
+            pt = jnp.where(functools.reduce(jnp.logical_and, keep), pt,
+                           jnp.float32(0.0))
+        dv_acc[...] += lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dpt = lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)  # (bk, bq)
+        dst = (pt * (dpt - delta)).astype(q.dtype)
+        dk_acc[...] += lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+        dq_acc[rows, :] += lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                    # (bq, D)
+
+    if causal:
+        # a query block wholly before this key block is all mask and is not
+        # computed; one that the diagonal crosses is masked, the rest not
+        seen = q0 + (block_q - 1) >= k0
+        crossed = q0 < k0 + (block_k - 1)
+        pl.when(seen & crossed)(lambda: block(True))
+        pl.when(seen & jnp.logical_not(crossed))(lambda: block(False))
+    else:
+        block(False)
+
+    @pl.when(iq == nq_total - 1)
+    def _finalize():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when((ik == nk_total - 1) & (iq == nq_total - 1))
+    def _finalize_row():
+        dq_ref[0] = (dq_acc[pl.ds(0, tq_total), :] * scale).astype(
+            dq_ref.dtype)
+
+
+def _fa_bwd_pallas(q, k, v, out, lse, g, scale, causal, q_offset, k_offset,
+                   block_q=_FA_BWD_BLOCK, block_k=_FA_BWD_BLOCK):
+    """(dq, dk, dv) in the inputs' types by ONE pallas_call,
+    ``flash_attention_bwd``, over (BH, nK, nQ). It recomputes
+    p = exp(q·kᵀ·scale − lse) from the forward's log-sum-exp; the products
+    take q, k, v and dO in their own type with float32 accumulation, p and ds
+    are float32 and cast to that type only as a product's operand. A block
+    wholly above the diagonal is skipped, and its index map repeats the next
+    block that is computed, so it is not even read. One kernel and not two
+    (dk/dv, then dq): with dq summed in VMEM for the whole row, s and dp are
+    computed once, five products a block and not seven (4.46 against 7.50
+    ms a call at (16, 8192, 128) bfloat16; PERF.md, PR 36)."""
+    BH, Tq, D = q.shape
+    Tk = k.shape[1]
+    block_q = min(block_q, Tq)
+    block_k = min(block_k, Tk)
+    nq, nk = pl.cdiv(Tq, block_q), pl.cdiv(Tk, block_k)
+    offs = jnp.asarray([q_offset, k_offset], jnp.int32)
+    # delta = rowsum(dO∘O), once, in XLA where it fuses with its reads; it
+    # and the log-sum-exp as (1, T) rows, as the transposed scores take them
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    rows = [x.reshape(BH, 1, Tq) for x in (lse, delta)]
+
+    def first_q(ik, offs):
+        """The first query block that sees key block ``ik``."""
+        if not causal:
+            return _I0
+        k0 = ik * block_k + offs[1] - offs[0]
+        return lax.min(lax.div(lax.max(k0, _I0), np.int32(block_q)),
+                       np.int32(nq - 1))
+
+    kv_at = lambda b, ik, iq, offs: (b, ik, _I0)  # noqa: E731
+    q_at = lambda b, ik, iq, offs: (  # noqa: E731
+        b, lax.max(iq, first_q(ik, offs)), _I0)
+    row_at = lambda b, ik, iq, offs: (  # noqa: E731
+        b, _I0, lax.max(iq, first_q(ik, offs)))
+    dk, dv, dq = pl.pallas_call(
+        functools.partial(_fa_bwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, nq_total=nq,
+                          nk_total=nk, tq_total=Tq, tk_total=Tk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BH, nk, nq),
+            in_specs=[
+                pl.BlockSpec((1, block_k, D), kv_at),
+                pl.BlockSpec((1, block_k, D), kv_at),
+                pl.BlockSpec((1, block_q, D), q_at),
+                pl.BlockSpec((1, block_q, D), q_at),
+                pl.BlockSpec((1, 1, block_q), row_at),
+                pl.BlockSpec((1, 1, block_q), row_at),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, D), kv_at),
+                pl.BlockSpec((1, block_k, D), kv_at),
+                pl.BlockSpec((1, Tq, D), lambda b, ik, iq, offs: (b, _I0, _I0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                            pltpu.VMEM((block_k, D), jnp.float32),
+                            pltpu.VMEM((nq * block_q, D), jnp.float32)]),
+        name="flash_attention_bwd",   # the custom call's in a device trace
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype, **_vma_kw(k)),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype, **_vma_kw(v)),
+                   jax.ShapeDtypeStruct(q.shape, q.dtype, **_vma_kw(q))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_FA_BWD_VMEM_LIMIT),
+        interpret=_interpret(),
+    )(offs, k, v, q, g, *rows)
+    return dq, dk, dv
+
+
 def flash_attention_bwd(q, k, v, out, lse, g, scale, causal,
                         q_offset=0, k_offset=0, block_k=128):
     """Blockwise (flash) backward: scan over k blocks, O(T·block_k) memory.
+    The backward of every shape the forward kernel does not take, and ring
+    attention's per-step partial (traced offsets inside its own loop).
 
     Standard recompute form: D = rowsum(dO∘O); per k-block
     p = exp(q·kᵀ·scale − lse); dv += pᵀ·dO; dp = dO·vᵀ;
@@ -317,7 +507,18 @@ def flash_attention_bwd(q, k, v, out, lse, g, scale, causal,
 
 def _flash_core_bwd(scale, causal, q_offset, k_offset, block_k, kernel,
                     res, g):
+    """The Pallas kernel where the forward ran its kernel and a row's dq
+    fits in VMEM (``_fa_bwd_fits``); the blockwise scan everywhere else.
+    Each trace counts ``mxtpu_flash_attention_bwd_lowered_total{route=}``."""
+    from ..observability import catalog as _catalog, metrics as _metrics
     q, k, v, out, lse = res
+    pallas = kernel and flash_forward_tiles(q, k) and _fa_bwd_fits(q)
+    if _metrics.enabled():
+        _catalog.FLASH_ATTENTION_BWD_LOWERED.inc(
+            route="pallas" if pallas else "scan")
+    if pallas:
+        return _fa_bwd_pallas(q, k, v, out, lse, g, scale, causal, q_offset,
+                              k_offset)
     dq, dk, dv = flash_attention_bwd(q, k, v, out, lse, g, scale, causal,
                                      q_offset, k_offset, block_k)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
@@ -332,10 +533,12 @@ def flash_attention(q, k, v, causal: bool = False,
                     kernel: bool = True):
     """Memory-efficient attention. q,k,v: (B, H, T, D) → (B, H, Tq, D).
 
-    Differentiable (custom VJP, blockwise backward). On TPU the forward is a
-    Pallas kernel where the shape tiles (D % 128 == 0, T % 8 == 0, see
-    ``flash_forward_tiles``) and ``kernel`` is left on; elsewhere a jnp
-    reference path with identical numerics.
+    Differentiable (custom VJP). On TPU the forward is a Pallas kernel where
+    the shape tiles (D % 128 == 0, T % 8 == 0, see ``flash_forward_tiles``)
+    and ``kernel`` is left on; elsewhere a jnp reference path with identical
+    numerics. The backward is a Pallas kernel wherever the forward was and a
+    row's dq fits in VMEM (``_fa_bwd_fits``), else the blockwise scan
+    (``flash_attention_bwd``).
     """
     B, H, Tq, Dh = q.shape
     sc = scale if scale is not None else 1.0 / (Dh ** 0.5)

@@ -81,20 +81,31 @@ def test_flash_attention_kernel_compiles_for_v5e(one_chip, no_compile_cache,
              s, s, s)
 
 
+@pytest.mark.parametrize("shape,dtype", [
+    ((2, 8, 2048, 128), jnp.bfloat16),      # the smoke's shape
+    ((1, 1, 32768, 128), jnp.bfloat16),     # the longest row _fa_bwd_fits
+    ((1, 1, 21504, 128), jnp.float32),      # takes, in each type
+])
 def test_flash_attention_grad_compiles_for_v5e(one_chip, no_compile_cache,
-                                               monkeypatch):
-    """The public entry point, forward kernel plus blockwise backward, at
-    the smoke's shape (use_pallas asks the default backend, which is the CPU
-    here: steered in the test, as the guide says)."""
+                                               monkeypatch, shape, dtype):
+    """The public entry point, forward kernel plus the backward kernel, at
+    the smoke's shape and at the longest rows whose dq the backward kernel
+    holds in VMEM (use_pallas asks the default backend, which is the CPU
+    here: steered in the test, as the guide says): one
+    ``flash_attention_bwd`` custom call, and no ``while`` op (the blockwise
+    scan is not there)."""
+    import re
     monkeypatch.setattr(pk, "use_pallas", lambda: True)
-    s = jax.ShapeDtypeStruct((2, 8, 2048, 128), jnp.bfloat16,
-                             sharding=one_chip)
+    assert pk._fa_bwd_fits(jax.ShapeDtypeStruct(shape[-2:], dtype))
+    s = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def loss(q, k, v):
         out = pk.flash_attention(q, k, v, causal=True)
         return jnp.sum(out.astype(jnp.float32))
 
-    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), s, s, s)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), s, s, s)
+    assert len(re.findall(r"%\w*flash_attention_bwd[_.\d]* = ", text)) == 1
+    assert " while(" not in text
 
 
 @pytest.mark.parametrize("shape,dtype", [
@@ -251,15 +262,15 @@ def test_sunk_pool_leaves_no_full_size_pass_on_v5e(four_chips,
                 "a full-size pass over the stem map: " + line[:200]
 
 
-@pytest.mark.parametrize("chips,kernels", [(1, 2), (4, 1)],
+@pytest.mark.parametrize("chips,kernels", [(1, 3), (4, 2)],
                          ids=["one-chip", "dp4"])
 def test_attention_and_cross_entropy_ops_under_a_named_mesh_on_v5e(
         four_chips, no_compile_cache, monkeypatch, chips, kernels):
     """The ops a decoder LM's step is made of, differentiated under the mesh
-    ``DataParallelTrainer`` names: on one chip the attention forward and the
-    cross-entropy's log-sum-exp are Mosaic kernels; on a ``dp`` mesh of four
-    each chip runs the attention kernel on its own rows (``shard_map``; jit
-    would refuse to partition it) and the log-sum-exp is XLA's."""
+    ``DataParallelTrainer`` names: on one chip the attention forward, its
+    backward and the cross-entropy's log-sum-exp are Mosaic kernels; on a ``dp`` mesh of four each chip runs the attention kernels on
+    its own rows (``shard_map``; jit would refuse to partition them) and the
+    log-sum-exp is XLA's."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
     from mxnet_tpu.ops import get_op
     monkeypatch.setattr(pk, "use_pallas", lambda: True)
@@ -295,7 +306,8 @@ def test_looped_decoder_cell_step_fits_and_keeps_its_kernels_on_v5e(
     own copy of the weights are both past the chip), its XLA products are
     under 36 TFLOP (35.0; 40.4 when every segment and every exit ran twice,
     30.4 with every product kept), every attention forward is the Pallas
-    kernel, once (16 calls), and every exit's log-sum-exp too (4); no
+    kernel, once (16 calls), its backward the backward kernel (16 calls),
+    and every exit's log-sum-exp a kernel too (4); no
     [4096, 49152] float32 buffer stands alone in
     the step: one exit's logits are bfloat16 and the label's column is found
     by an iota inside the fused gradient, not by a one-hot. Weights are
@@ -329,7 +341,7 @@ def test_looped_decoder_cell_step_fits_and_keeps_its_kernels_on_v5e(
     assert "f64[" not in text
     calls = cfg["num_hidden_layers"] * cfg["total_ut_steps"]
     assert text.count('custom_call_target="tpu_custom_call"') == \
-        calls + cfg["total_ut_steps"]
+        2 * calls + cfg["total_ut_steps"]
     entry = text[text.index("ENTRY"):].split("\n", 1)[1]
     logits = re.compile(r"= (\w+)\[(?:1,)?%d,%d\]" % (
         cfg["seq_len"], cfg["vocab_held"]))
@@ -378,8 +390,8 @@ def test_routed_experts_cell_step_fits_and_keeps_its_kernels_on_v5e(
     v5e. Each of its 4 layers is a recomputed segment; every expert layer
     takes the grouped route (4 differentiated traces) and every attention
     forward the Pallas kernel. The step takes 8.5 to 10.5 GB, of which 3.96
-    GB are its arguments; its Pallas calls are the 4 attention forwards and
-    36 grouped products: 12 forward (a segment keeps their results: its
+    GB are its arguments; its Pallas calls are the 4 attention forwards, the
+    4 attention backwards and 36 grouped products: 12 forward (a segment keeps their results: its
     backward pass runs none of them again), 12 for the tokens' gradient and
     12 for the weights'. The logits are bfloat16 and no float32 buffer of their
     size stands in the step. Weights are zeros (shapes are all a compile
@@ -414,7 +426,7 @@ def test_routed_experts_cell_step_fits_and_keeps_its_kernels_on_v5e(
     text = compiled.as_text()
     assert "f64[" not in text
     layers = cfg["num_hidden_layers"]
-    assert text.count('custom_call_target="tpu_custom_call"') == layers * 10
+    assert text.count('custom_call_target="tpu_custom_call"') == layers * 11
     for name, calls in (("moe_gmm_t", 3), ("moe_gmm_dw", 3)):
         assert len(re.findall(r"%%(?:jvp_)?%s[_.\d]* = " % name, text)) \
             == calls * layers, name

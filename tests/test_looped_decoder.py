@@ -341,8 +341,8 @@ def test_flash_attention_keeps_its_residuals_under_a_policy(
         monkeypatch, rng, route):
     """Under ``jax.checkpoint`` with the segments' policy the attention's
     forward is traced once (``out`` and the log-sum-exp are kept for the
-    blockwise backward), under a bare one twice; the gradient is the
-    unwrapped call's."""
+    backward: the backward kernel, or the blockwise scan), under a bare one
+    twice; the gradient is the unwrapped call's."""
     from mxnet_tpu.ops import pallas_kernels as pk
     from mxnet_tpu.ops.registry import KEPT_IN_SEGMENT
     monkeypatch.setenv("MXTPU_PALLAS_INTERPRET",
@@ -354,7 +354,7 @@ def test_flash_attention_keeps_its_residuals_under_a_policy(
     def forwards(fn):
         text = str(jax.make_jaxpr(jax.grad(fn, argnums=(0, 1, 2)))(q, k, v))
         if route == "kernel":
-            return text.count("pallas_call")
+            return text.count("name=flash_attention_fwd")
         # the plain form is two products (q k^T, p v); the blockwise
         # backward's scan body holds five of its own
         return (text.count("dot_general") - 5) // 2
