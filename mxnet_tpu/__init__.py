@@ -14,6 +14,9 @@ Public surface (mirrors ``python/mxnet``):
 """
 from __future__ import annotations
 
+import time as _time
+_t0 = _time.perf_counter()   # the span mxnet_tpu.import, recorded at the end
+
 __version__ = "0.1.0"
 
 import jax as _jax
@@ -91,9 +94,19 @@ _lazy = {
 }
 
 
+from .observability import metrics as _metrics, spans as _spans  # noqa: E402
+
+
 def __getattr__(name):
     if name in _lazy:
+        t0 = _time.perf_counter()
         mod = _importlib.import_module(_lazy[name], __name__)
         globals()[name] = mod
+        if _metrics.enabled():
+            _spans.record("mxnet_tpu.import", t0, _time.perf_counter())
         return mod
     raise AttributeError(f"module 'mxnet_tpu' has no attribute {name!r}")
+
+
+if _metrics.enabled():
+    _spans.record("mxnet_tpu.import", _t0, _time.perf_counter())

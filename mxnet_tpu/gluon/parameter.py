@@ -17,6 +17,7 @@ from .. import autograd, initializer
 from ..base import MXNetError
 from ..context import Context, cpu, current_context
 from ..ndarray import NDArray, array as nd_array, zeros as nd_zeros
+from ..observability import spans as _spans
 
 __all__ = ["DeferredInitializationError", "Parameter", "Constant", "ParameterDict"]
 
@@ -136,6 +137,7 @@ class Parameter:
     def list_grad(self):
         return [self.grad]
 
+    @_spans.span("gluon.set_data")
     def set_data(self, data) -> None:
         if self._data is None:
             if self._deferred_init is not None:
@@ -267,6 +269,7 @@ class ParameterDict:
                 raise MXNetError(f"duplicate parameter name {k!r}")
             self._params[k] = v
 
+    @_spans.span("gluon.initialize")
     def initialize(self, init=initializer.Uniform(), ctx=None, verbose=False,
                    force_reinit=False) -> None:
         for p in self.values():

@@ -13,6 +13,9 @@ process-wide listener (idempotent install) and translate:
 - ``/jax/core/compile/backend_compile_duration`` →
   ``mxtpu_jit_backend_compiles_total`` + ``mxtpu_jit_compile_ms`` histogram
 - ``/jax/compilation_cache/cache_hits``        → ``mxtpu_jit_cache_hits_total``
+- ``/jax/compilation_cache/cache_retrieval_time_sec`` → a ``jit.cache_load``
+  record, inside the ``jit.compile`` record of the same compile: a compile
+  whose record encloses none ran the compiler, one that does was loaded
 
 The listener respects the live ``MXNET_TELEMETRY`` switch, and registration
 itself costs nothing between compiles.
@@ -45,6 +48,7 @@ JIT_CACHE_HITS = _metrics.counter(
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 _lock = threading.Lock()
 _installed = False
@@ -80,6 +84,8 @@ def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
         JIT_COMPILES.inc()
         JIT_COMPILE_MS.observe(duration_secs * 1000.0)
         _spans.record("jit.compile", now - duration_secs, now)
+    elif event == _CACHE_LOAD_EVENT:
+        _spans.record("jit.cache_load", now - duration_secs, now)
 
 
 def _on_event(event: str, **kwargs) -> None:

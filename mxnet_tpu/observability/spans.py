@@ -36,20 +36,24 @@ from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from . import metrics as _metrics
 
-__all__ = ["span", "active_spans", "record", "records", "Record", "SPAN_MS"]
+__all__ = ["span", "active_spans", "record", "records", "dropped", "Record",
+           "SPAN_MS"]
 
 SPAN_MS = _metrics.histogram(
     "mxtpu_span_ms", "Duration of instrumented spans, by span name.")
 
 # The ring has to hold one whole benchmark run (set-up, the 20 s window, the
 # 3 s traced tail, and the reference's compiles after them) at the shortest
-# step of any cell PERF.md section 7.3 lists. Measured on the chip (PR 24,
-# ResNet-50): set-up leaves 5,300 records, 5,100 of them the jit.trace of
-# every inner jit of the capture and of the step, and the reference 7,000
-# more after the window; a step leaves 4 records and its batch 4. At ~25 ms a
-# step (ResNet-50 at batch 64) that is 5,300 + 920 x 8 + 7,000 = 19,700: the
-# ring holds it 1.6 times, and the 100 ms cells of today (14,700) 2.2 times.
-# At ~250 bytes a record it is 8 MB when full.
+# step of any cell PERF.md section 7.3 lists. Measured on the chip (cold
+# traced runs, with the set-up spans: every parameter's gluon.set_data, the
+# imports, and a jit.cache_load for each executable loaded): set-up leaves
+# 3,900-5,740 records, most of them the jit.trace of every inner jit of the
+# capture and of the step; a whole run leaves 15,348 in resnet50_v1.train_fed
+# (the most), 14,339 in resnet50_v1.train and 8,006 in zaya1_8b.train, a
+# step 4 records and a fed batch 4 more. At ~25 ms a step (ResNet-50 at
+# batch 64) a run would leave ~6,000 + 920 x 8 + 8,000 = 21,400: the ring
+# holds it 1.5 times, and the fullest run of today 2.1 times. dropped() says
+# when it did not. At ~250 bytes a record it is 8 MB when full.
 RING_RECORDS = 32768
 
 
@@ -64,6 +68,7 @@ class Record(NamedTuple):
 
 _ring: deque = deque(maxlen=RING_RECORDS)
 _ring_lock = threading.Lock()
+_dropped = 0
 _tls = threading.local()
 
 
@@ -82,11 +87,14 @@ def active_spans() -> Tuple[str, ...]:
 def record(name: str, t0: float, t1: float, unit=None) -> None:
     """Append a finished region that was timed elsewhere (``jit_hooks``'s
     trace/compile events) under the span open on this thread."""
+    global _dropped
     st = _stack()
     parent = st[-1] if st else None
     if unit is None and parent is not None:
         unit = parent.unit
     with _ring_lock:
+        if len(_ring) == _ring.maxlen:
+            _dropped += 1
         _ring.append(Record(name, t0, t1, threading.get_ident(),
                             parent.name if parent is not None else None, unit))
 
@@ -95,6 +103,12 @@ def records() -> List[Record]:
     """A copy of the ring, oldest first."""
     with _ring_lock:
         return list(_ring)
+
+
+def dropped() -> int:
+    """How many records the full ring has pushed out since the process
+    started: a reader of the whole run sees all of it only while this is 0."""
+    return _dropped
 
 
 def _profiler_recording() -> bool:

@@ -221,6 +221,7 @@ class DataParallelTrainer:
         step.sync_to_net()                     # write back into net params
     """
 
+    @_spans.span("trainer.init")
     def __init__(self, net, loss, optimizer="sgd", optimizer_params=None,
                  mesh: Optional[Mesh] = None, data_axis: str = "dp",
                  compute_dtype=None, donate: bool = True, kvstore=None,
@@ -1234,6 +1235,7 @@ class DataParallelTrainer:
             self._params, self._aux, self._opt_state, self._guard_state,
             rng, *specs)
 
+    @_spans.span("trainer.sync_to_net")
     def sync_to_net(self) -> None:
         """Write the trained params/aux back into the gluon net (resharded
         onto each parameter's home device).  Pass-re-homed parameters are

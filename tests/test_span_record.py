@@ -127,10 +127,12 @@ def test_two_threads_at_once_keep_their_own_parents_and_units(ring):
 def test_ring_is_bounded_and_drops_the_oldest(monkeypatch):
     monkeypatch.setenv("MXNET_TELEMETRY", "1")
     monkeypatch.setattr(spans, "_ring", collections.deque(maxlen=4))
+    monkeypatch.setattr(spans, "_dropped", 0)
     for i in range(7):
         with spans.span("s%d" % i):
             pass
     assert [r.name for r in spans.records()] == ["s3", "s4", "s5", "s6"]
+    assert spans.dropped() == 3                          # and counts them
     assert spans.records() is not spans.records()       # a copy
     # the real ring holds a whole benchmark run (the reckoning is in spans.py)
     assert spans.RING_RECORDS >= 1.5 * (5300 + 920 * 8 + 7000)
