@@ -104,6 +104,24 @@ FLASH_ATTENTION_BWD_LOWERED = _m.counter(
     "reads its attention calls, never steps; a net whose pallas count "
     "stays 0 on a TPU runs the scan.")
 
+FLASH_ATTENTION_FWD_BLOCKS = _m.counter(
+    "mxtpu_flash_attention_fwd_blocks_total",
+    "(query block, key block) pairs of the Pallas attention forward "
+    "(flash_attention_fwd) by what the kernel does with them, labeled kind=: "
+    "\"full\" (computed without a mask: wholly below the diagonal, or not "
+    "causal, and the key axis not ragged), \"masked\" (crossed by the "
+    "diagonal, or any computed block where the key length is no multiple of "
+    "the block) or \"skipped\" (wholly above the diagonal: neither "
+    "visited nor read where the row's keys fit in VMEM, a grid step that "
+    "copies nothing where they do not), summed "
+    "over the batch x heads rows. Counted once per DIFFERENTIATED trace of "
+    "flash attention (its forward rule: a capture of a decoder's step reads "
+    "its attention calls, never steps, as "
+    "mxtpu_flash_attention_bwd_lowered_total) and per trace of the "
+    "forward-only flash_attention_with_lse; a plain forward trace counts "
+    "nothing (a recomputed segment traces that primal a second time), nor "
+    "does one whose offsets are traced (ring attention).")
+
 REMAT_SEGMENTS = _m.counter(
     "mxtpu_remat_segments_total",
     "Segments of a symbol graph lowered as one function under "

@@ -10,7 +10,11 @@ otherwise materialize the T×T score matrix in HBM.
 
 Kernels:
 * ``flash_attention``      — O(T·block) memory attention, fwd in Pallas with a
-                             per-row log-sum-exp side output; bwd in Pallas
+                             per-row log-sum-exp side output (masks built
+                             only in blocks the diagonal crosses; a row's
+                             keys held in VMEM where they fit, so a block
+                             above the diagonal is neither visited nor
+                             read); bwd in Pallas
                              where the fwd ran there (``flash_attention_bwd``:
                              recomputes p from the log-sum-exp, dk and dv a
                              key block, dq summed in VMEM for the row, blocks
@@ -83,49 +87,61 @@ def use_pallas() -> bool:
 # flash attention
 # ---------------------------------------------------------------------------
 
-def _fa_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-               acc_ref, m_ref, l_ref, *, scale, causal, block_q, block_k,
-               nk_total, tk_total):
-    """Grid (BH, nQ, nK); k is the innermost (sequential) axis.
+def _fa_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
+               m_ref, l_ref, *, scale, causal, block_q, block_k, nk_total,
+               tk_total, whole_row):
+    """The online softmax of one q block over its key blocks, in ascending
+    order. Scratch (acc, m, l) carries its state; it is reset before the
+    first key block, and after the last the normalized output and the row
+    log-sum-exp are written out.
 
-    Scratch (acc, m, l) carries the online-softmax state across k iterations
-    for one (bh, q-block); at the final k step the normalized output and the
-    row log-sum-exp are written out.
+    ``whole_row``: grid (BH, nQ); k_ref and v_ref hold every key of the row,
+    and the key blocks are two loops in the kernel, those wholly below the
+    diagonal and then those it crosses; a block above it is neither visited
+    nor read. Else grid (BH, nQ, nK), k the innermost, sequential, axis, a
+    block above the diagonal a step that computes nothing. A block the
+    diagonal crosses, or any block where the key axis is ragged, is masked;
+    a block wholly below it is computed without a mask. Each select left out
+    is an identity in the block it is left out of, so every path sums the
+    same numbers in the same order.
     """
-    iq, ik = pl.program_id(1), pl.program_id(2)
+    iq = pl.program_id(1)
     # Mosaic can't legalize f64 constants: pin every python-float scalar to f32
     scale = jnp.float32(scale)
     neg_inf = jnp.float32(_NEG_INF)
+    k_ragged = tk_total % block_k != 0
 
-    @pl.when(ik == 0)
-    def _init():
+    def init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, neg_inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
     # global positions: q_offset/k_offset arrive via SMEM (they are traced
     # values in the ring-attention loop, so they can't be python ints baked
-    # into the kernel). A key block that lies wholly past this query block's
-    # last position is all mask: it changes nothing and is not computed
+    # into the kernel)
     q0 = iq * block_q + offs_ref[0]
-    k0 = ik * block_k + offs_ref[1]
-    seen = (k0 <= q0 + (block_q - 1)) if causal else True
 
-    @pl.when(seen)
-    def _block():
+    def block(ik, masked):
         # the products run in the operands' own type (bfloat16 at the MXU's
         # full rate), accumulated in float32; the softmax is float32
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]           # (bq, D), (bk, D) x 2
-        # zero the ragged tail (padded block rows may hold garbage/NaN)
-        krow = lax.broadcasted_iota(jnp.int32, v.shape, 0) + ik * block_k
-        v = jnp.where(krow < tk_total, v, jnp.zeros_like(v))
+        k0 = ik * block_k + offs_ref[1]
+        q = q_ref[0]                                     # (bq, D)
+        if whole_row:
+            rows = pl.ds(pl.multiple_of(ik * block_k, block_k), block_k)
+            k, v = k_ref[0, rows, :], v_ref[0, rows, :]  # (bk, D) x 2
+        else:
+            k, v = k_ref[0], v_ref[0]
+        if k_ragged:
+            # zero the ragged tail (padded block rows may hold garbage/NaN)
+            krow = lax.broadcasted_iota(jnp.int32, v.shape, 0) + ik * block_k
+            v = jnp.where(krow < tk_total, v, jnp.zeros_like(v))
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-
-        # mask ragged tail of the key axis (grid pads the last block)
-        k_idx = lax.broadcasted_iota(jnp.int32, s.shape, 1) + ik * block_k
-        s = jnp.where(k_idx < tk_total, s, neg_inf)
-        if causal:
+        if k_ragged:
+            # mask ragged tail of the key axis (grid pads the last block)
+            k_idx = lax.broadcasted_iota(jnp.int32, s.shape, 1) + ik * block_k
+            s = jnp.where(k_idx < tk_total, s, neg_inf)
+        if masked:
             qpos = lax.broadcasted_iota(jnp.int32, s.shape, 0) + q0
             kpos = lax.broadcasted_iota(jnp.int32, s.shape, 1) + k0
             s = jnp.where(qpos >= kpos, s, neg_inf)
@@ -134,7 +150,9 @@ def _fa_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         blk_max = jnp.max(s, axis=1)[:, None]            # (bq, 1)
         m_new = jnp.maximum(m_prev, jnp.broadcast_to(blk_max, m_prev.shape))
         p = jnp.exp(s - m_new[:, :1])                    # (bq, bk)
-        p = jnp.where(s <= neg_inf / 2, jnp.float32(0.0), p)
+        if masked or k_ragged:
+            # a row masked so far has m_new at the mask's own value
+            p = jnp.where(s <= neg_inf / 2, jnp.float32(0.0), p)
         corr = jnp.exp(m_prev[:, :1] - m_new[:, :1])     # (bq, 1)
         l_ref[...] = l_ref[...] * jnp.broadcast_to(corr, l_ref.shape) \
             + jnp.broadcast_to(jnp.sum(p, axis=1)[:, None], l_ref.shape)
@@ -143,14 +161,47 @@ def _fa_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    @pl.when(ik == nk_total - 1)
-    def _finalize():
+    def finalize():
         l = l_ref[...][:, :1]                            # (bq, 1)
         safe_l = jnp.maximum(l, jnp.float32(1e-30))
         o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
         m = m_ref[...][:, :1]
         lse = jnp.where(l <= jnp.float32(0.0), neg_inf, m + jnp.log(safe_l))
         lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+
+    if whole_row:
+        init()
+        n_full = n_seen = np.int32(nk_total)
+        if causal:
+            # key blocks [0, n_full) end at or before q0, the q block's
+            # first position; [n_full, n_seen) start at or before its last
+            # (lax.div truncates: both operands are kept non-negative)
+            rel = q0 - offs_ref[1]
+            n_seen = jnp.minimum(lax.div(
+                jnp.maximum(rel + (block_q - 1 + block_k), _I0),
+                np.int32(block_k)), n_seen)
+            n_full = jnp.minimum(lax.div(jnp.maximum(rel + 1, _I0),
+                                         np.int32(block_k)), n_seen)
+        lax.fori_loop(_I0, n_full, lambda ik, c: (block(ik, False), c)[1],
+                      _I0)
+        if causal:
+            lax.fori_loop(n_full, n_seen,
+                          lambda ik, c: (block(ik, True), c)[1], _I0)
+        finalize()
+        return
+    ik = pl.program_id(2)
+    pl.when(ik == 0)(init)
+    if causal:
+        # a key block wholly past this query block's last position is all
+        # mask: it changes nothing and is not computed
+        k0 = ik * block_k + offs_ref[1]
+        seen = k0 <= q0 + (block_q - 1)
+        crossed = q0 < k0 + (block_k - 1)
+        pl.when(seen & crossed)(lambda: block(ik, True))
+        pl.when(seen & jnp.logical_not(crossed))(lambda: block(ik, False))
+    else:
+        block(ik, False)
+    pl.when(ik == nk_total - 1)(finalize)
 
 
 def _vma_kw(x):
@@ -159,31 +210,96 @@ def _vma_kw(x):
     return {"vma": vma} if vma else {}
 
 
+def _fa_blocks(nq, nk, block_q, block_k, q_offset, k_offset, causal,
+               k_ragged):
+    """(nq, nk) array of what the forward does with each (q block, k block)
+    for offsets known at trace time, by the kernel's own rule (its
+    ``seen`` and ``crossed``, or the bounds of its two loops over key
+    blocks): "skipped" (wholly above the diagonal), "full" (computed
+    without a mask) or "masked" (crossed by the diagonal, or the key axis
+    ragged)."""
+    q0 = np.arange(nq)[:, None] * block_q + q_offset
+    k0 = np.arange(nk)[None, :] * block_k + k_offset
+    seen = (k0 <= q0 + block_q - 1) if causal else np.ones((nq, nk), bool)
+    masked = (q0 < k0 + block_k - 1) if causal else np.zeros((nq, nk), bool)
+    return np.where(seen, np.where(masked | k_ragged, "masked", "full"),
+                    "skipped")
+
+
+_FA_VMEM_LIMIT = 64 << 20   # of v5e's 128 MiB; the default scope is 16
+_FA_KV_ROW_BYTES = 32 << 20  # a row's keys and values, double-buffered
+
+
+def _fa_whole_row(k, block_k) -> bool:
+    """Whether the forward holds the row's every key and value in VMEM
+    (both double-buffered: T up to 32,768 at D = 128 in bfloat16, 16,384 in
+    float32) and the key axis tiles by ``block_k``. Else the grid steps over
+    the key blocks."""
+    _, tk, d = k.shape
+    return tk % block_k == 0 and 4 * tk * d * k.dtype.itemsize \
+        <= _FA_KV_ROW_BYTES
+
+
 def _fa_pallas(q, k, v, scale, causal, q_offset, k_offset,
-               block_q=512, block_k=512):
+               block_q=512, block_k=512, count=False):
     """q,k,v: (BH, T, D) → (out (BH,Tq,D), lse (BH,Tq)) via pallas_call.
     Blocks of 512 x 512 scores (1 MiB in float32): at 128 x 128 the grid's
     own steps, 16,384 a call at (16, 4096, 128), were most of the kernel's
-    9.4 ms (PERF.md, PR 31)."""
+    9.4 ms, measured on a v5e (PERF.md).
+
+    Where the row's keys and values fit in VMEM and tile (``_fa_whole_row``)
+    the grid is (BH, nQ), each row's keys and values are copied in once, and
+    the kernel loops over the key blocks a q block sees, with offsets known
+    or traced alike: a block above the diagonal costs nothing. Else the grid
+    is (BH, nQ, nK), and a step above the diagonal names the last key block
+    its q block computes, so the pipeline copies nothing for it. With
+    ``count`` the trace adds its blocks by kind to
+    ``mxtpu_flash_attention_fwd_blocks_total`` where the offsets are Python
+    ints, and nothing where they are traced (ring attention)."""
+    from ..observability import catalog as _catalog, metrics as _metrics
     BH, Tq, D = q.shape
     Tk = k.shape[1]
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
     nq, nk = pl.cdiv(Tq, block_q), pl.cdiv(Tk, block_k)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
+    if count and _metrics.enabled() and all(
+            isinstance(o, (int, np.integer)) for o in (q_offset, k_offset)):
+        kinds = _fa_blocks(nq, nk, block_q, block_k, q_offset, k_offset,
+                           causal, Tk % block_k != 0)
+        for kind in ("full", "masked", "skipped"):
+            _catalog.FLASH_ATTENTION_FWD_BLOCKS.inc(
+                BH * int(np.sum(kinds == kind)), kind=kind)
+
+    whole_row = _fa_whole_row(k, block_k)
+    if whole_row:
+        grid = (BH, nq)
+        q_at = lambda b, iq, offs: (b, iq, _I0)  # noqa: E731
+        kv_spec = pl.BlockSpec((1, Tk, D), lambda b, iq, offs: (b, _I0, _I0))
+    else:
+        grid = (BH, nq, nk)
+
+        def kv_block(iq, ik, offs):
+            """``ik``, or past the diagonal the last key block that q block
+            ``iq`` sees (block 0 where it sees none)."""
+            if not causal:
+                return ik
+            end = iq * block_q + offs[0] + (block_q - 1) - offs[1]
+            seen = lax.min(lax.div(lax.max(end, _I0), np.int32(block_k)),
+                           np.int32(nk - 1))
+            return lax.min(ik, seen)
+
+        q_at = lambda b, iq, ik, offs: (b, iq, _I0)  # noqa: E731
+        kv_spec = pl.BlockSpec((1, block_k, D), lambda b, iq, ik, offs: (
+            b, kv_block(iq, ik, offs), _I0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,   # offs (q/k global offsets) land in SMEM
-        grid=(BH, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, iq, ik, offs: (b, iq, _I0)),
-            pl.BlockSpec((1, block_k, D), lambda b, iq, ik, offs: (b, ik, _I0)),
-            pl.BlockSpec((1, block_k, D), lambda b, iq, ik, offs: (b, ik, _I0)),
-        ],
+        grid=grid,
+        in_specs=[pl.BlockSpec((1, block_q, D), q_at), kv_spec, kv_spec],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, iq, ik, offs: (b, iq, _I0)),
-            pl.BlockSpec((1, block_q, 128),
-                         lambda b, iq, ik, offs: (b, iq, _I0)),
+            pl.BlockSpec((1, block_q, D), q_at),
+            pl.BlockSpec((1, block_q, 128), q_at),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
@@ -194,13 +310,15 @@ def _fa_pallas(q, k, v, scale, causal, q_offset, k_offset,
     out, lse = pl.pallas_call(
         functools.partial(_fa_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, nk_total=nk,
-                          tk_total=Tk),
+                          tk_total=Tk, whole_row=whole_row),
         grid_spec=grid_spec,
         name="flash_attention_fwd",   # the custom call's in a device trace
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tq, D), q.dtype, **_vma_kw(q)),
             jax.ShapeDtypeStruct((BH, Tq, 128), jnp.float32, **_vma_kw(q)),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_FA_VMEM_LIMIT),
         interpret=_interpret(),
     )(offs, q, k, v)
     return out, lse[:, :, 0]
@@ -238,12 +356,15 @@ def flash_forward_tiles(q, k) -> bool:
     return use_pallas() and tile_ok and not interp_in_manual
 
 
-def _fa_fwd_dispatch(q, k, v, scale, causal, q_offset, k_offset, kernel=True):
+def _fa_fwd_dispatch(q, k, v, scale, causal, q_offset, k_offset, kernel=True,
+                     count=False):
     """(out, lse) by the Pallas kernel where the shape tiles
     (``flash_forward_tiles``) and the caller does not keep it off
-    (``kernel=False``: an op that cannot see how its batch is split)."""
+    (``kernel=False``: an op that cannot see how its batch is split);
+    ``count``: the kernel's blocks are counted (``_fa_pallas``)."""
     if kernel and flash_forward_tiles(q, k):
-        return _fa_pallas(q, k, v, scale, causal, q_offset, k_offset)
+        return _fa_pallas(q, k, v, scale, causal, q_offset, k_offset,
+                          count=count)
     return _fa_reference(q, k, v, scale, causal, q_offset, k_offset)
 
 
@@ -256,8 +377,10 @@ def _flash_core(q, k, v, scale, causal, q_offset, k_offset, block_k, kernel):
 
 def _flash_core_fwd(q, k, v, scale, causal, q_offset, k_offset, block_k,
                     kernel):
+    # counted here and not in the primal above, which a recomputed segment
+    # traces a second time: once per differentiated call, as the backward
     out, lse = _fa_fwd_dispatch(q, k, v, scale, causal, q_offset, k_offset,
-                                kernel)
+                                kernel, count=True)
     # named for a recomputed segment's policy (executor._GraphLowering): with
     # both kept, its backward pass does not run the forward kernel again.
     # ``lse`` is no output of the op, so only this rule can name it; outside
@@ -566,7 +689,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
     out, lse = _fa_fwd_dispatch(q.reshape(B * H, Tq, Dh),
                                 k.reshape(B * H, k.shape[2], Dh),
                                 v.reshape(B * H, v.shape[2], Dh),
-                                sc, causal, q_offset, k_offset)
+                                sc, causal, q_offset, k_offset, count=True)
     return out.reshape(B, H, Tq, Dh), lse.reshape(B, H, Tq)
 
 

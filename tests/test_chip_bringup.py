@@ -109,6 +109,29 @@ def test_flash_attention_grad_compiles_for_v5e(one_chip, no_compile_cache,
 
 
 @pytest.mark.parametrize("shape,dtype", [
+    ((16, 4096, 128), jnp.bfloat16),     # ouro_2_6b.train's attention
+    ((16, 8192, 128), jnp.bfloat16),     # zaya1_8b.train's
+    ((1, 32768, 128), jnp.bfloat16),     # the longest row held whole
+    ((1, 16384, 128), jnp.float32),      # the same 32 MiB in float32
+    ((1, 65536, 128), jnp.bfloat16),     # past it: the grid over key blocks
+])
+def test_flash_forward_block_paths_compile_for_v5e(one_chip, no_compile_cache,
+                                                   shape, dtype):
+    """The forward kernel, causal, with offsets that are Python ints and
+    traced: the row's keys and values held in VMEM and the key blocks a
+    loop in the kernel (``_fa_whole_row``), and past that the grid over
+    every key block with the key/value index map clamped."""
+    s = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    i = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    assert pk._fa_whole_row(s, 512) == (shape[1] <= 32768 * 2 //
+                                        jnp.dtype(dtype).itemsize)
+    _compile(lambda q, k, v: pk._fa_pallas(q, k, v, 0.088, True, 0, 0),
+             s, s, s)
+    _compile(lambda q, k, v, a, b: pk._fa_pallas(q, k, v, 0.088, True, a, b),
+             s, s, s, i, i)
+
+
+@pytest.mark.parametrize("shape,dtype", [
     ((256, 1024), jnp.float32),
     ((4096, 10240), jnp.float32),     # 20.25 MiB of scoped VMEM before tiling
     ((4096, 32768), jnp.bfloat16),    # 32.25 MiB before tiling

@@ -697,6 +697,39 @@ def test_flash_attention_kernel_route_under_the_interpreter(monkeypatch, rng):
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+def test_a_capture_counts_the_forward_kernels_blocks(monkeypatch):
+    """A captured step of a toy-width looped decoder (one head of 128 so the
+    kernel runs, here under its interpreter; 1,024 tokens, two 512-row
+    blocks) counts each layer-call's forward once: a full, two masked and a
+    skipped block a head, beside one backward kernel a layer-call; the
+    capture's plain forward and the next step count nothing."""
+    monkeypatch.setenv("MXTPU_PALLAS_INTERPRET", "1")
+    layers, passes, heads = 2, 2, 1
+    mx.random.seed(7)
+    net = tfm.looped_decoder_lm(vocab_size=64, units=128, hidden_size=64,
+                                num_layers=layers, num_heads=heads,
+                                loops=passes)
+    net.initialize(mx.init.Xavier())
+    trainer = parallel.DataParallelTrainer(
+        net, gluon.loss.ExpectedExitCELoss(exits=passes), "adam",
+        {"learning_rate": 1e-3},
+        mesh=parallel.local_mesh("dp", devices=jax.devices()[:1]))
+    x = np.random.RandomState(0).randint(0, 64, (1, 1024))
+    kinds = ("full", "masked", "skipped")
+
+    def counts():
+        return tuple(catalog.FLASH_ATTENTION_FWD_BLOCKS.value(kind=k)
+                     for k in kinds) + (
+            catalog.FLASH_ATTENTION_BWD_LOWERED.value(route="pallas"),)
+
+    calls = layers * passes
+    first = (calls * heads, 2 * calls * heads, calls * heads, calls)
+    for want in (first, (0, 0, 0, 0)):
+        before = counts()
+        assert np.isfinite(float(trainer.step(ids(x), ids(x[:, ::-1]))))
+        assert tuple(a - b for a, b in zip(counts(), before)) == want
+
+
 def test_decoder_cell_is_causal_and_hybridizes(rng):
     cell = tfm.SandwichDecoderCell(32, 64, 2, rotary_theta=1e4)
     cell.initialize(mx.init.Xavier())

@@ -88,6 +88,111 @@ def test_flash_attention_grad_interpret(rng, interp):
                                    rtol=2e-4, atol=2e-4)
 
 
+# (BH, Tq, Tk, causal, q_offset, k_offset), with 512-row blocks
+FORWARD_BLOCKS = [
+    (1, 2048, 2048, True, 0, 0),       # 6 full, 4 masked, 6 skipped
+    (1, 2048, 2048, False, 0, 0),      # 16 full
+    (2, 520, 520, True, 0, 0),         # ragged: every computed block masked
+    (1, 1000, 1000, True, 0, 0),
+    (1, 1000, 1000, False, 0, 0),
+    (1, 1024, 1536, True, 0, 600),     # q block 0 sees no key: whole rows
+    (1, 1536, 1024, True, 512, 0),     # queries later than keys
+]
+# the row held whole where the key axis tiles; the grid over key blocks
+# (what a row too long for VMEM takes) everywhere
+FORWARD_ROUTES = [(c, r) for c in FORWARD_BLOCKS
+                  for r in (["row", "grid"] if c[2] % 512 == 0 else ["grid"])]
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["ints", "traced"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case,route", FORWARD_ROUTES, ids=[
+    "-".join(map(str, c + (r,))) for c, r in FORWARD_ROUTES])
+def test_flash_forward_blocks_equal_the_plain_form(rng, interp, monkeypatch,
+                                                   case, route, dtype,
+                                                   traced):
+    """The forward kernel's block paths (the row's keys held in VMEM and
+    the key blocks a q block sees looped over in the kernel; or the grid
+    over every key block, those above the diagonal naming the last
+    computed one), with offsets that are Python ints and traced under
+    ``jit``, against ``_fa_reference`` in float32: ``out`` and ``lse``,
+    rows that see no key at all included (``lse`` at the mask's value,
+    ``out`` 0)."""
+    if route == "grid":
+        monkeypatch.setattr(pk, "_FA_KV_ROW_BYTES", 0)
+    bh, tq, tk, causal, q_off, k_off = case
+    q = jnp.asarray(rng.randn(bh, tq, 128), dtype)
+    k, v = (jnp.asarray(rng.randn(bh, tk, 128), dtype) for _ in range(2))
+    assert pk._fa_whole_row(k, 512) == (route == "row")
+    scale = 128 ** -0.5
+    if traced:
+        got = jax.jit(lambda q, k, v, a, b: pk._fa_pallas(
+            q, k, v, scale, causal, a, b))(q, k, v, jnp.int32(q_off),
+                                           jnp.int32(k_off))
+    else:
+        got = pk._fa_pallas(q, k, v, scale, causal, q_off, k_off)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    with jax.default_matmul_precision("highest"):
+        want = pk._fa_reference(*f32, scale, causal, q_off, k_off)
+    blind = (np.arange(tq) + q_off < k_off) if causal else np.zeros(tq, bool)
+    assert np.all(np.asarray(want[1])[:, blind] == pk._NEG_INF)
+    assert np.all(np.asarray(got[1])[:, blind] == pk._NEG_INF)
+    assert not np.any(np.asarray(got[0], np.float32)[:, blind])
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+        else:
+            assert np.max(np.abs(a - b)) < 3e-2 * np.max(np.abs(b))
+
+
+def test_flash_attention_grad_through_the_block_paths(rng, interp):
+    """The gradient through ``_flash_core`` where the forward holds a full,
+    a skipped and two masked blocks (1,024 rows of 512-row blocks)."""
+    q, k, v = (jnp.asarray(rng.randn(1, 1, 1024, 128), jnp.float32)
+               for _ in range(3))
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v, causal=True) ** 2)
+
+    got = jax.grad(loss(pk.flash_attention), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(_naive_attn), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_flash_forward_counts_its_blocks_by_kind(interp):
+    """One differentiated trace at T = 2,048 (nq = 4 blocks of 512) counts
+    nq(nq-1)/2 full, nq masked and nq(nq-1)/2 skipped blocks a head; so
+    does a trace of ``flash_attention_with_lse`` with offsets that are
+    Python ints; with traced offsets, or a plain forward trace, none."""
+    from mxnet_tpu.observability import catalog
+    bh, nq = 2, 4
+    q = jnp.zeros((1, bh, 2048, 128), jnp.float32)
+    kinds = ("full", "masked", "skipped")
+
+    def counted(fn, *args):
+        before = [catalog.FLASH_ATTENTION_FWD_BLOCKS.value(kind=k)
+                  for k in kinds]
+        jax.make_jaxpr(fn)(*args)
+        return tuple(catalog.FLASH_ATTENTION_FWD_BLOCKS.value(kind=k) - b
+                     for k, b in zip(kinds, before))
+
+    each = (nq * (nq - 1) // 2, nq, nq * (nq - 1) // 2)
+    want = tuple(bh * n for n in each)
+    loss = lambda q: jnp.sum(pk.flash_attention(q, q, q, causal=True))  # noqa: E731
+    assert counted(jax.grad(loss), q) == want
+    assert counted(lambda q: pk.flash_attention_with_lse(
+        q, q, q, causal=True), q) == want
+    assert counted(lambda q, a, b: pk.flash_attention_with_lse(
+        q, q, q, causal=True, q_offset=a, k_offset=b),
+        q, jnp.int32(0), jnp.int32(0)) == (0, 0, 0)
+    assert counted(lambda q: pk.flash_attention(q, q, q, causal=True),
+                   q) == (0, 0, 0)
+
+
 # 128: one class block; 2176 = 2048 + 128: two blocks with a ragged tail
 @pytest.mark.parametrize("classes", [128, 2176])
 def test_softmax_cross_entropy_interpret(rng, interp, classes):
